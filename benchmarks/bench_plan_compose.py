@@ -21,14 +21,17 @@ Gates: composed per-query results == sequential-sharded per-query results
 sequential-sharded; per-query result counts within 15% (or one sync
 window) of the single-device multi driver.
 
-Needs 8 devices, so the parent re-execs a child with forced host devices
-(same pattern as bench_sharded).
+Needs 8 devices.  Under ``JAX_PLATFORMS=cpu`` the parent re-execs a child
+with 8 virtual CPU devices (same pattern as bench_sharded); on an
+accelerator host it runs in this process and needs 8 devices there.
 """
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
+
+from repro.launch.mesh import virtual_device_env, virtual_devices_allowed
 
 Q_CLASSES = (0, 0, 0, 0, 1, 1, 1, 1)   # two predicates × four users
 SHARDS = 8
@@ -146,9 +149,10 @@ def _child(quick: bool) -> None:
 
 
 def main(quick: bool = False) -> None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={SHARDS}"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    if not virtual_devices_allowed():
+        _child(quick)   # on the host's own devices; needs SHARDS of them
+        return
+    env = virtual_device_env(SHARDS)
     args = [sys.executable, os.path.abspath(__file__), "--child"]
     if quick:
         args.append("--quick")

@@ -6,11 +6,13 @@ the acceptance parity: at 8 shards the sharded driver must find the same
 result count (±5%) as the scanned driver for the same query and frame
 budget on the dashcam config.
 
-Each device count needs its own ``--xla_force_host_platform_device_count``
-flag, which must be set before the first jax import — so the parent
-re-execs this file once per arm and relays each arm's CSV rows when that
-arm finishes (child output is captured, not streamed live).  On a
-CPU host the simulated shards CONTEND for the same cores, so steps/sec
+Under ``JAX_PLATFORMS=cpu`` each device count needs its own
+``--xla_force_host_platform_device_count`` flag, which must be set before
+the first jax import — so the parent re-execs this file once per arm and
+relays each arm's CSV rows when that arm finishes (child output is
+captured, not streamed live).  On an accelerator host every arm runs in
+this process on the devices the host has; an arm wider than the host
+fails.  On a CPU host the simulated shards CONTEND for the same cores, so steps/sec
 here isolates framework/collective overhead, not speedup; the speedup
 story needs real devices where detector compute dominates and shards run
 concurrently (the async model of bench_batched prices that).
@@ -20,6 +22,8 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
+
+from repro.launch.mesh import virtual_device_env, virtual_devices_allowed
 
 DEVICE_COUNTS = (1, 2, 4, 8)
 
@@ -112,10 +116,12 @@ def _child(shards: int, steps: int, parity: bool) -> None:
 def main(quick: bool = False) -> None:
     steps = 256 if quick else 1_024
     print("driver,shards,global_cohorts,sync_every,steps_per_sec")
+    if not virtual_devices_allowed():
+        for n in DEVICE_COUNTS:
+            _child(n, steps, parity=not quick)
+        return
     for n in DEVICE_COUNTS:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = virtual_device_env(n)
         args = [sys.executable, os.path.abspath(__file__),
                 "--child", str(n), "--steps", str(steps)]
         if not quick:

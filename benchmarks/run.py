@@ -20,10 +20,11 @@
   bench_roofline     -- Roofline table from dry-run artifacts
 
 Each section *declares* the ``Execution`` capabilities it exercises
-(DESIGN.md §10); sections that need an in-process mesh the host cannot
-provide are SKIPPED with a logged reason — never silently — while
-subprocess-based sections (``forces_devices``) re-exec children with
-forced host devices and run anywhere.
+(DESIGN.md §10); sections that need a mesh the host cannot provide are
+SKIPPED with a logged reason — never silently.  Under
+``JAX_PLATFORMS=cpu`` the subprocess-based sections (``forces_devices``)
+re-exec children with virtual CPU devices and run anywhere; on an
+accelerator they run in-process on the devices the host has.
 """
 from __future__ import annotations
 
@@ -33,6 +34,7 @@ import time
 from typing import Callable, Optional
 
 from repro.core.plan import Execution
+from repro.launch.mesh import virtual_devices_allowed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,17 +51,18 @@ def should_skip(spec: BenchSpec, available_devices: int) -> str | None:
     """Reason this section cannot run on this host, or None to run it.
 
     A section declaring a mesh (``execution.shards > 1``) needs that many
-    in-process devices unless it forces its own (subprocess re-exec with
-    ``--xla_force_host_platform_device_count``).
+    in-process devices unless it forces its own virtual CPU devices, which
+    it may only do under ``JAX_PLATFORMS=cpu``.
     """
-    if spec.execution is None or spec.forces_devices:
+    if spec.execution is None:
         return None
-    if spec.execution.shards > available_devices:
+    forces = spec.forces_devices and virtual_devices_allowed()
+    if spec.execution.shards > available_devices and not forces:
         return (
             f"needs a {spec.execution.shards}-way "
             f"'{spec.execution.axis}' mesh but the host exposes "
-            f"{available_devices} device(s); set "
-            "--xla_force_host_platform_device_count or run on more devices"
+            f"{available_devices} device(s); run on more devices, or set "
+            "JAX_PLATFORMS=cpu for virtual CPU devices"
         )
     if spec.execution.async_workers > 0 and not _threads_available():
         return (

@@ -1,7 +1,8 @@
 """Distributed ExSample: mesh-sharded and Q×shards-composed search plans.
 
-Runs the §8 mesh-resident lowering for real on an 8-device host mesh
-(this script re-execs itself with the XLA device-count flag): one
+Runs the §8 mesh-resident lowering for real on an 8-device mesh (under
+``JAX_PLATFORMS=cpu`` this script re-execs itself with the XLA
+device-count flag; on an accelerator host it needs 8 devices): one
 ``SearchPlan`` with ``Execution(shards=8)`` places chunk statistics over
 the ``data`` axis, every round each shard processes its slice of the
 globally-consistent Thompson cohort, and per-shard matcher states merge
@@ -12,7 +13,7 @@ shows the sharded statistics land on the same answer, and a composed
 queries through the same mesh while sharing one deduplicated + cached
 detector pass per round per shard.
 
-  PYTHONPATH=src python examples/search_distributed.py
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/search_distributed.py
 """
 import time
 

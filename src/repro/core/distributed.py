@@ -31,35 +31,6 @@ from repro.core.state import SamplerState
 from repro.core.thompson import gamma_params, wilson_hilferty
 
 
-def get_shard_map():
-    """``shard_map`` across JAX versions: newer releases promote it to
-    ``jax.shard_map`` AND rename the ``check_rep`` kwarg to ``check_vma``;
-    older ones only have ``jax.experimental.shard_map``.  Callers keep the
-    old ``check_rep=...`` spelling and the returned wrapper translates (or
-    drops) it when the resolved function doesn't accept it.  Same
-    feature-detect pattern as ``launch/mesh.py`` (AxisType) and
-    ``distributed/compression.py`` (``lax.axis_size``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-    import inspect
-
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # C-level/odd callables: pass through
-        return sm
-
-    def shard_map_compat(f, **kwargs):
-        if "check_rep" in kwargs and "check_rep" not in params:
-            v = kwargs.pop("check_rep")
-            if "check_vma" in params:
-                kwargs["check_vma"] = v
-        return sm(f, **kwargs)
-
-    return shard_map_compat
-
-
 def shard_sampler_state(state: SamplerState, mesh: Mesh, axis: str = "data"):
     """Place chunk-stat arrays sharded over ``axis`` (M must divide evenly;
     pad_chunks() handles ragged M)."""
@@ -216,12 +187,12 @@ def distributed_choose(
         return idx
 
     specs = P(axis)
-    choice = get_shard_map()(
+    choice = jax.shard_map(
         local_choice,
         mesh=mesh,
         in_specs=(P(), specs, specs, specs, specs),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(key, alpha, beta, exhausted, state.n)
     return choice
 

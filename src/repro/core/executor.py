@@ -478,10 +478,7 @@ def _search_multi_sharded_device(
     Q: cross-query dedup and caching change WHICH detector invocations
     happen, never the values a query consumes.
     """
-    from repro.core.distributed import (
-        get_shard_map,
-        local_cohort_winners_batched,
-    )
+    from repro.core.distributed import local_cohort_winners_batched
     from repro.serve.batcher import (
         dedup_first_index,
         sharded_cache_insert,
@@ -823,13 +820,13 @@ def _search_multi_sharded_device(
     cache_spec = rep if cache is None else sh1
     if cache is not None:
         out_specs = out_specs + (sh1,)
-    return get_shard_map()(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(rep, rep, rep, sh2, sh2, sh2, rep, rep, rep, cache_spec,
                   rep, rep),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(keys, step0, results0, n1, n, frames, matcher, chunks, result_limits,
       cache, warm_tag, window_limit)
 

@@ -363,7 +363,7 @@ def _search_sharded_device(
     caveat.  The trace records (step, results) at every sync; the host
     syncs once, after the loop exits.
     """
-    from repro.core.distributed import get_shard_map, local_cohort_winners
+    from repro.core.distributed import local_cohort_winners
     from jax.sharding import PartitionSpec as P
 
     num_shards = mesh.shape[axis]
@@ -572,12 +572,12 @@ def _search_sharded_device(
         return n1_l, n_l, matcher, key, step, results, buf, tn, hw, ov, windows
 
     sh, rep = P(axis), P()
-    return get_shard_map()(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(rep, rep, rep, sh, sh, sh, rep, rep, rep),
         out_specs=(sh, sh, rep, rep, rep, rep, rep, rep, rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )(key, step0, results0, n1, n, frames, matcher, chunks, result_limit)
 
 

@@ -112,6 +112,55 @@ def _process_cohort(
 
 
 @dataclasses.dataclass
+class WorkerFailure:
+    """Put on a results queue in place of a result when a worker raised.
+    The scheduling thread re-raises it, so a failed round (a compile
+    refusal, an out-of-memory, a detector error) fails the whole run
+    instead of silently ending the worker thread."""
+
+    worker_id: int
+    error: Exception
+
+    def reraise(self):
+        raise RuntimeError(
+            f"search worker {self.worker_id} failed: {self.error!r}"
+        ) from self.error
+
+
+# How long ``run()`` waits for any worker result before it gives up on a
+# worker stuck in a device call: far longer than a cold compile.
+STALL_TIMEOUT_S = 600.0
+
+
+def _raise_if_stalled(since: float, inflight) -> None:
+    """TimeoutError naming the in-flight work once no result has arrived
+    for ``STALL_TIMEOUT_S`` seconds since ``since``."""
+    waited = time.monotonic() - since
+    if waited > STALL_TIMEOUT_S:
+        raise TimeoutError(
+            f"no search worker result for {waited:.1f} s (limit "
+            f"{STALL_TIMEOUT_S:g} s); in flight: {sorted(inflight)}"
+        )
+
+
+def _next_result(results: queue.Queue, threads, timeout: float):
+    """Next worker result, or None when ``timeout`` passes with a worker
+    still alive to deliver one.  A worker failure is re-raised here, and
+    so is a timeout after every worker has exited."""
+    try:
+        res = results.get(timeout=timeout)
+    except queue.Empty:
+        if not any(t.is_alive() for t in threads):
+            raise RuntimeError(
+                "every search worker exited with work in flight"
+            ) from None
+        return None
+    if isinstance(res, WorkerFailure):
+        res.reraise()
+    return res
+
+
+@dataclasses.dataclass
 class Cohort:
     cohort_id: int
     chunk_ids: np.ndarray      # i64[B]
@@ -296,7 +345,12 @@ class AsyncSearchDriver:
                 return
             t0 = time.monotonic()
             self.monitor.assign(wid, cohort.cohort_id, now=t0)
-            self._results.put(self._process_one(wid, cohort))
+            try:
+                res = self._process_one(wid, cohort)
+            except Exception as e:  # noqa: BLE001 — re-raised by run()
+                self._results.put(WorkerFailure(wid, e))
+                return
+            self._results.put(res)
             now = time.monotonic()
             self.monitor.heartbeat(wid, now)
             self.monitor.record_completion(wid, now - t0, now=now)
@@ -304,6 +358,9 @@ class AsyncSearchDriver:
     # ---- run loop ----------------------------------------------------------
 
     def run(self) -> ExSampleCarry:
+        """Run to the result limit or the frame budget.  A worker's
+        exception is raised from here, and so is a ``TimeoutError`` when
+        no result arrives for ``STALL_TIMEOUT_S`` seconds."""
         threads = [
             threading.Thread(target=self._worker, args=(w,), daemon=True)
             for w in range(self.num_workers)
@@ -313,15 +370,19 @@ class AsyncSearchDriver:
         # keep the pipeline full: workers+1 outstanding cohorts
         for _ in range(self.num_workers + 1):
             self._issue_cohort()
+        last = time.monotonic()
         try:
             while (
                 int(self.carry.results) < self.result_limit
                 and int(self.carry.step) < self.max_frames
             ):
-                try:
-                    res = self._results.get(timeout=60.0)
-                except queue.Empty:
-                    break
+                res = _next_result(
+                    self._results, threads, timeout=min(60.0, STALL_TIMEOUT_S)
+                )
+                if res is None:
+                    _raise_if_stalled(last, self._inflight)
+                    continue
+                last = time.monotonic()
                 self._merge(res)
                 actions = self.monitor.sweep(time.monotonic())
                 for cid in actions["reissue_cohorts"]:
@@ -908,7 +969,12 @@ class AsyncMultiSearchDriver:
                 return
             t0 = time.monotonic()
             self.monitor.assign(wid, batch.batch_id, now=t0)
-            self._results.put(self._process_batch(wid, batch))
+            try:
+                res = self._process_batch(wid, batch)
+            except Exception as e:  # noqa: BLE001 — re-raised by the scheduler
+                self._results.put(WorkerFailure(wid, e))
+                return
+            self._results.put(res)
             now = time.monotonic()
             self.monitor.heartbeat(wid, now)
             self.monitor.record_completion(wid, now - t0, now=now)
@@ -947,11 +1013,11 @@ class AsyncMultiSearchDriver:
         """One scheduler heartbeat: issue what is issuable, merge at most
         one completed batch, sweep for stragglers.  Returns True if a
         result was merged (False = the wait timed out — callers use this
-        to interleave admission work without busy-spinning)."""
+        to interleave admission work without busy-spinning).  A worker's
+        exception is re-raised here, in the scheduling thread."""
         self._issue_ready()
-        try:
-            res = self._results.get(timeout=timeout)
-        except queue.Empty:
+        res = _next_result(self._results, self._threads, timeout=timeout)
+        if res is None:
             return False
         self._merge(res)
         actions = self.monitor.sweep(time.monotonic())
@@ -963,22 +1029,21 @@ class AsyncMultiSearchDriver:
     def run(self) -> ExSampleCarry:
         """Drive every query to completion; returns the stacked [Q] carry
         (retired rows keep their final state).  Per-query traces are in
-        ``self.traces``, spilled results in ``self.logs``."""
+        ``self.traces``, spilled results in ``self.logs``.  A worker's
+        exception is raised from here, and so is a ``TimeoutError`` when
+        no batch completes for ``STALL_TIMEOUT_S`` seconds; no partial
+        carry is returned."""
         self.start()
+        last = time.monotonic()
         try:
             self._issue_ready()
             while not self.idle():
-                if not self.service_tick(timeout=60.0):
-                    break
+                if self.service_tick(timeout=min(60.0, STALL_TIMEOUT_S)):
+                    last = time.monotonic()
+                else:
+                    _raise_if_stalled(last, self._inflight)
         finally:
             self.stop()
-        # rows still active (abnormal exit) close their trace like the
-        # scan driver's unconditional final checkpoint
-        for row in self.rows:
-            if row.active and not row.inflight:
-                row.trace.append(
-                    (int(row.carry.step), int(row.carry.results))
-                )
         return stack_carries([row.carry for row in self.rows])
 
     @property

@@ -59,12 +59,7 @@ def compressed_psum_leaf(
 
     Returns (mean gradient f32, new residual).
     """
-    # jax.lax.axis_size is absent from older JAX; psum of 1 over the axis
-    # is the version-portable spelling of the same quantity.
-    if hasattr(jax.lax, "axis_size"):
-        npods = jax.lax.axis_size(axis)
-    else:
-        npods = jax.lax.psum(1, axis)
+    npods = jax.lax.axis_size(axis)
     x = g.astype(jnp.float32) + r
     q, scale = _quantize(x, block)
     sent = _dequantize(q, scale, x.shape, block)
@@ -91,20 +86,16 @@ def make_cross_pod_allreduce(mesh: Mesh, *, compress: bool, block: int = 256):
     if "pod" not in mesh.axis_names:
         return lambda grads, ef: (grads, ef)
 
-    from repro.core.distributed import get_shard_map
-
-    shard_map = get_shard_map()
-
     if not compress:
         def plain(grads, ef):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda g: jax.tree.map(
                     lambda x: jax.lax.pmean(x, "pod"), g
                 ),
                 mesh=mesh,
                 in_specs=(P(),),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
             return f(grads), ef
         return plain
@@ -120,9 +111,9 @@ def make_cross_pod_allreduce(mesh: Mesh, *, compress: bool, block: int = 256):
             resid = jax.tree.map(lambda t: t[1], outs, is_leaf=lambda x: isinstance(x, tuple))
             return means, resid
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         means, resid = f(grads, ef.residual)
         return means, ErrorFeedback(residual=resid)

@@ -2,14 +2,22 @@
 
 For M chunks and C cohorts: transform standard normals through the
 Wilson–Hilferty cube approximation of Γ(α, β) draws and reduce to the
-per-cohort argmax — fused so chunk statistics stream through VMEM once
-per cohort row, with no M-sized intermediate ever hitting HBM.
+per-cohort argmax — fused so chunk statistics stream through VMEM once,
+with no M-sized intermediate ever hitting HBM.
 
-Grid ``(C, num_chunk_blocks)``, running (value, index) maximum in VMEM
-scratch.  Rejection samplers (Marsaglia–Tsang) are data-dependent loops —
-hostile to the VPU; WH is branch-free (DESIGN.md §3) and the consumer
-only needs ordinal fidelity.  Exhausted chunks arrive with α < 0 as the
-sentinel and are masked to -inf.
+Grid ``(Q, num_chunk_blocks)``: one program per (query, M-block) holds
+all C cohort rows of that block (a ``[C, bm]`` tile — C is the full
+sublane dimension and ``bm`` the full M or a multiple of 128 lanes, so
+every block shape is legal for the TPU tiling).  The running (value,
+index) maximum lives in the lane-wide ``[C, 128]`` output blocks, which
+stay resident in VMEM across the sequential M axis; every lane of a row
+holds the same value.  The in-block argmax is a max reduction followed by
+a min over the lane iota of the maximal lanes, so there is no dynamic
+lane indexing and no scalar VMEM store.  Rejection samplers
+(Marsaglia–Tsang) are data-dependent loops — hostile to the VPU; WH is
+branch-free (DESIGN.md §3) and the consumer only needs ordinal fidelity.
+Exhausted chunks arrive with α < 0 as the sentinel and are masked to
+-inf; a row with no live chunk returns index -1.
 
 Clamping contract (DESIGN.md §3): callers pass ``alpha`` already clamped
 by ``core.thompson.gamma_params`` (≥ α₀/2 > 0 for live chunks) with the
@@ -29,85 +37,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
-def _thompson_kernel(
-    alpha_ref, beta_ref, z_ref, idx_ref, val_ref,
-    best_scratch,
-    *, block_m: int,
-):
+def _thompson_kernel(alpha_ref, beta_ref, z_ref, idx_ref, val_ref, *,
+                     block_m: int):
     mj = pl.program_id(1)
-    nm = pl.num_programs(1)
 
     @pl.when(mj == 0)
     def _init():
-        best_scratch[0, 0] = NEG_INF          # value
-        best_scratch[0, 1] = -1.0             # index (as f32)
+        val_ref[...] = jnp.full(val_ref.shape, NEG_INF, jnp.float32)
+        idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
 
     alpha = alpha_ref[...].astype(jnp.float32)       # [1, bm]
     beta = beta_ref[...].astype(jnp.float32)
-    z = z_ref[...].astype(jnp.float32)
+    z = z_ref[...].astype(jnp.float32)               # [C, bm]
     live = alpha > 0.0
     a = jnp.maximum(alpha, 1e-6)
     # Wilson-Hilferty: X ≈ α (1 − 1/9α + z/(3√α))³
     c = 1.0 - 1.0 / (9.0 * a) + z / (3.0 * jnp.sqrt(a))
     draw = a * jnp.maximum(c, 0.0) ** 3 / jnp.maximum(beta, 1e-9)
-    score = jnp.where(live, draw, NEG_INF)
+    score = jnp.where(live, draw, NEG_INF)           # [C, bm]
 
-    loc = jnp.argmax(score[0]).astype(jnp.int32)
-    val = score[0, loc]
-    gidx = mj * block_m + loc
-
-    @pl.when(val > best_scratch[0, 0])
-    def _update():
-        best_scratch[0, 0] = val
-        best_scratch[0, 1] = gidx.astype(jnp.float32)
-
-    @pl.when(mj == nm - 1)
-    def _finalize():
-        idx_ref[0, 0] = best_scratch[0, 1].astype(jnp.int32)
-        val_ref[0, 0] = best_scratch[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def thompson_choose(
-    alpha: jax.Array,     # f32[M] — N¹+α₀ per chunk; <0 ⇒ exhausted sentinel
-    beta: jax.Array,      # f32[M] — n+β₀
-    z: jax.Array,         # f32[C, M] — standard normals (one row per cohort)
-    *,
-    block_m: int = 1024,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (idx i32[C], value f32[C])."""
-    c, m = z.shape
-    bm = min(block_m, m)
-    pad = (-m) % bm
-    if pad:
-        alpha = jnp.concatenate([alpha, jnp.full((pad,), -1.0, alpha.dtype)])
-        beta = jnp.concatenate([beta, jnp.ones((pad,), beta.dtype)])
-        z = jnp.concatenate([z, jnp.zeros((c, pad), z.dtype)], axis=1)
-        m += pad
-
-    idx, val = pl.pallas_call(
-        functools.partial(_thompson_kernel, block_m=bm),
-        grid=(c, m // bm),
-        in_specs=[
-            pl.BlockSpec((1, bm), lambda ci, mj: (0, mj)),
-            pl.BlockSpec((1, bm), lambda ci, mj: (0, mj)),
-            pl.BlockSpec((1, bm), lambda ci, mj: (ci, mj)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda ci, mj: (ci, 0)),
-            pl.BlockSpec((1, 1), lambda ci, mj: (ci, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c, 1), jnp.int32),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, 2), jnp.float32)],
-        interpret=interpret,
-    )(alpha.reshape(1, m), beta.reshape(1, m), z)
-    return idx[:, 0], val[:, 0]
+    blk_max = jnp.max(score, axis=1, keepdims=True)  # [C, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    loc = jnp.min(
+        jnp.where(score == blk_max, lane, block_m), axis=1, keepdims=True
+    )                                                # first maximal lane
+    # strict ">" keeps the earliest block on ties: jnp.argmax semantics
+    better = blk_max > val_ref[...]                  # [C, LANES]
+    val_ref[...] = jnp.where(better, blk_max, val_ref[...])
+    idx_ref[...] = jnp.where(better, mj * block_m + loc, idx_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -119,13 +79,12 @@ def thompson_choose_batched(
     block_m: int = 1024,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Multi-query variant (DESIGN.md §9): Q queries × C cohorts reduced in
-    ONE pallas_call.  The cohort rows flatten to a (Q·C, M-blocks) grid and
-    each row's block spec indexes its query's alpha/beta row (``r // C``),
-    so the whole multi-query Thompson decision is a single kernel launch —
-    never a Python loop over queries.  Returns (idx i32[Q, C], val
-    f32[Q, C]); row (q, c) is bit-identical to ``thompson_choose`` on
-    query q's statistics.
+    """Q queries × C cohorts reduced in ONE pallas_call (DESIGN.md §9).
+
+    ``block_m`` must be a multiple of 128 for a TPU compile when M exceeds
+    it; at M ≤ ``block_m`` the block is the whole row.  Returns (idx
+    i32[Q, C], val f32[Q, C]); row (q, c) is the argmax over query q's
+    chunks of cohort c's WH draws, -1 where every chunk is exhausted.
     """
     qn, c, m = z.shape
     bm = min(block_m, m)
@@ -138,23 +97,40 @@ def thompson_choose_batched(
         z = jnp.concatenate([z, jnp.zeros((qn, c, pad), z.dtype)], axis=2)
         m += pad
 
+    row = pl.BlockSpec((None, 1, bm), lambda q, mj: (q, 0, mj))
+    out = pl.BlockSpec((None, c, LANES), lambda q, mj: (q, 0, 0))
     idx, val = pl.pallas_call(
         functools.partial(_thompson_kernel, block_m=bm),
-        grid=(qn * c, m // bm),
+        grid=(qn, m // bm),
         in_specs=[
-            pl.BlockSpec((1, bm), lambda r, mj: (r // c, mj)),
-            pl.BlockSpec((1, bm), lambda r, mj: (r // c, mj)),
-            pl.BlockSpec((1, bm), lambda r, mj: (r, mj)),
+            row,
+            row,
+            pl.BlockSpec((None, c, bm), lambda q, mj: (q, 0, mj)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda r, mj: (r, 0)),
-            pl.BlockSpec((1, 1), lambda r, mj: (r, 0)),
-        ],
+        out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((qn * c, 1), jnp.int32),
-            jax.ShapeDtypeStruct((qn * c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((qn, c, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((qn, c, LANES), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, 2), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(alpha, beta, z.reshape(qn * c, m))
-    return idx[:, 0].reshape(qn, c), val[:, 0].reshape(qn, c)
+    )(alpha.reshape(qn, 1, m), beta.reshape(qn, 1, m), z)
+    return idx[..., 0], val[..., 0]
+
+
+def thompson_choose(
+    alpha: jax.Array,     # f32[M] — N¹+α₀ per chunk; <0 ⇒ exhausted sentinel
+    beta: jax.Array,      # f32[M] — n+β₀
+    z: jax.Array,         # f32[C, M] — standard normals (one row per cohort)
+    *,
+    block_m: int = 1024,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Single-query choice: the batched kernel at Q = 1.
+    Returns (idx i32[C], value f32[C])."""
+    idx, val = thompson_choose_batched(
+        alpha[None], beta[None], z[None], block_m=block_m, interpret=interpret
+    )
+    return idx[0], val[0]

@@ -21,7 +21,8 @@ never combine.  The legacy flag combinations (``--mesh/--sync-every``,
 ``--queries/--cache-frames``, ``--driver``) still work but are deprecated:
 they are translated into the equivalent plan and a ``DeprecationWarning``
 is emitted.  When the plan needs more devices than the host exposes,
-``main()`` re-execs into a child with simulated host devices
+``main()`` re-execs into a child with virtual CPU devices if
+``JAX_PLATFORMS=cpu`` is set, and fails otherwise
 (``launch.mesh.ensure_host_devices``).
 """
 from __future__ import annotations
@@ -45,6 +46,7 @@ from repro.core import (
     init_state,
 )
 from repro.core.baselines import FrameSchedule, run_schedule
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim import generate
 from repro.sim.costmodel import CostRates, sampling_cost
 from repro.sim.oracle import class_select, noisy_detect, oracle_detect
@@ -208,8 +210,9 @@ def main() -> None:
                          "driver; host = per-step reference loop")
     ap.add_argument("--mesh", type=int, default=1,
                     help="[deprecated: use --plan] N>1 shards the search "
-                         "over an N-way data mesh (DESIGN.md §8); simulated "
-                         "host devices are forced automatically")
+                         "over an N-way data mesh (DESIGN.md §8); under "
+                         "JAX_PLATFORMS=cpu virtual host devices are forced "
+                         "automatically")
     ap.add_argument("--sync-every", type=int, default=1,
                     help="[deprecated: use --plan] rounds between "
                          "sampler/matcher merges on the mesh lowerings")
@@ -248,6 +251,7 @@ def main() -> None:
             argv=[sys.executable, "-m", "repro.launch.search"] + sys.argv[1:],
         )
 
+    enable_compile_cache()
     setup = (dashcam if args.dataset == "dashcam" else bdd)(
         seed=args.seed, scale=args.scale
     )

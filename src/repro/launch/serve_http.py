@@ -26,6 +26,7 @@ import json
 import sys
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_search import (
     build_parser,
     build_service,
@@ -87,6 +88,7 @@ def main() -> None:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8080)
     args = ap.parse_args()
+    enable_compile_cache()
 
     service = build_service(args)
     server = make_server(service, host=args.host, port=args.port)

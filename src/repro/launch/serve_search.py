@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from repro.configs.exsample_paper import bdd, dashcam
 from repro.core import init_carry_multi, init_matcher, init_state
 from repro.core.plan import PlanError, SearchPlan
-from repro.serve.service import SearchService
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serve.service import PumpFailure, SearchService
 from repro.sim import generate
 from repro.sim.costmodel import CostRates
 from repro.sim.oracle import class_select, oracle_detect
@@ -119,7 +120,7 @@ def handle_request(service: SearchService, obj: dict) -> dict:
                                       "(submit | stats | drain)"}
     except PlanError as e:
         return {"ok": False, "error": str(e), "field": e.field}
-    except (KeyError, ValueError, TimeoutError) as e:
+    except (KeyError, ValueError, TimeoutError, PumpFailure) as e:
         return {"ok": False, "error": f"{type(e).__name__}: {e}"}
 
 
@@ -178,6 +179,7 @@ def build_parser(ap: Optional[argparse.ArgumentParser] = None
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     service = build_service(args)
     service.start()

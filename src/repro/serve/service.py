@@ -64,6 +64,11 @@ FINISHED = "finished"
 REJECTED = "rejected"
 
 
+class PumpFailure(RuntimeError):
+    """The background pump died (a worker or scheduler exception); the
+    service admits, reports and drains nothing until it is restarted."""
+
+
 @dataclasses.dataclass
 class Tenant:
     """One submitted plan's lifecycle record (QUEUED → RUNNING → FINISHED,
@@ -212,6 +217,7 @@ class SearchService:
         self._lock = threading.Lock()
         self._stop_evt = threading.Event()
         self._pump: Optional[threading.Thread] = None
+        self._pump_error: Optional[Exception] = None
 
     # ---- lifecycle ---------------------------------------------------------
 
@@ -219,6 +225,7 @@ class SearchService:
         self.driver.start()
         if pump and self._pump is None:
             self._stop_evt.clear()
+            self._pump_error = None
             self._pump = threading.Thread(target=self._pump_loop, daemon=True)
             self._pump.start()
 
@@ -230,8 +237,20 @@ class SearchService:
         self.driver.stop()
 
     def _pump_loop(self) -> None:
-        while not self._stop_evt.is_set():
-            self.tick(timeout=0.05)
+        try:
+            while not self._stop_evt.is_set():
+                self.tick(timeout=0.05)
+        except Exception as e:  # noqa: BLE001 — re-raised by _check_pump()
+            self._pump_error = e
+
+    def _check_pump(self) -> None:
+        """Raise the background pump's failure, if it died.  Called by
+        ``submit``, ``drain`` and ``stats``, so no caller is told a tenant
+        is running on a service that no longer runs anything."""
+        if self._pump_error is not None:
+            raise PumpFailure(
+                f"service pump failed: {self._pump_error!r}"
+            ) from self._pump_error
 
     # ---- admission ---------------------------------------------------------
 
@@ -248,6 +267,7 @@ class SearchService:
         one Q-axis row, so service plans are single-query; ``select_id``
         binds the tenant's predicate (e.g. its query class) through the
         driver's ``select`` hook without recompiling anything."""
+        self._check_pump()
         plan.resolve()   # typed PlanErrors surface before any state change
         if plan.queries != 1:
             raise PlanError(
@@ -450,9 +470,11 @@ class SearchService:
 
     def drain(self, deadline_s: float = 120.0) -> None:
         """Block until every queued/running tenant finishes.  With the
-        background pump running this polls; without it, it ticks."""
+        background pump running this polls; without it, it ticks.  A pump
+        or worker failure is raised here, never returned as a drain."""
         t0 = time.monotonic()
         while self.busy():
+            self._check_pump()
             if time.monotonic() - t0 > deadline_s:
                 with self._lock:
                     unfinished = sum(
@@ -503,6 +525,7 @@ class SearchService:
         return 1.0 - self.padding_fraction()
 
     def stats(self) -> dict:
+        self._check_pump()
         with self._lock:
             return {
                 "tenants": {

@@ -1,5 +1,4 @@
 import os
-import sys
 
 # Tests must see exactly ONE device (the dry-run sets its own flag in a
 # separate process).  Sharding tests spawn subprocesses with their own
@@ -9,20 +8,3 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 
 jax.config.update("jax_default_matmul_precision", "highest")
-
-# The property tests want hypothesis (declared in pyproject's test extra);
-# air-gapped environments fall back to the deterministic stub so the suite
-# still collects and exercises the properties.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import importlib.util
-    import pathlib
-
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", pathlib.Path(__file__).parent / "_hypothesis_stub.py"
-    )
-    _stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_stub)
-    sys.modules["hypothesis"] = _stub
-    sys.modules["hypothesis.strategies"] = _stub.strategies

@@ -32,6 +32,7 @@ from repro.core import (
     run_search_scan,
     stack_carries,
 )
+from repro.core import runtime
 from repro.core.plan import Execution, SearchPlan
 from repro.sim import RepoSpec, generate
 from repro.sim.oracle import oracle_detect
@@ -336,3 +337,71 @@ def test_stats_keys_exist_at_construction(world):
         "detector_invocations": 0, "cache_hits": 0, "index_hits": 0,
         "lanes_issued": 0, "lanes_padded": 0,
     }
+
+
+def _failing_detector(det):
+    """``det`` whose every execution raises on the host — the shape of a
+    failure that only shows when a worker runs its round (a compile
+    refusal, an out-of-memory, a detector service error)."""
+
+    def boom(frame):
+        raise RuntimeError("detector down")
+
+    def failing(key, frame):
+        frame = jax.pure_callback(
+            boom, jax.ShapeDtypeStruct((), jnp.int32), frame,
+            vmap_method="sequential",
+        )
+        return det(key, frame)
+
+    return failing
+
+
+def test_worker_failure_fails_run(world):
+    """Regression: a worker exception used to end its daemon thread in
+    silence, and ``run()`` returned the partial carry after one timeout as
+    if the search had finished.  It must raise from ``run()`` instead."""
+    _, chunks, det = world
+    driver = AsyncMultiSearchDriver(
+        _fresh_multi(chunks, 2), chunks, _failing_detector(det),
+        cohorts=2, num_workers=2, result_limits=8, max_steps=1500,
+    )
+    with pytest.raises(RuntimeError, match="search worker .* failed"):
+        driver.run()
+
+
+def _hanging_detector(det, release):
+    """``det`` whose execution blocks until ``release`` is set — a worker
+    stuck in a device call."""
+
+    def hang(frame):
+        release.wait(timeout=60.0)
+        return frame
+
+    def hanging(key, frame):
+        frame = jax.pure_callback(
+            hang, jax.ShapeDtypeStruct((), jnp.int32), frame,
+            vmap_method="sequential",
+        )
+        return det(key, frame)
+
+    return hanging
+
+
+def test_stuck_worker_times_out_run(world, monkeypatch):
+    """A worker that never returns must not hang ``run()``: once no batch
+    completes for the stall limit it raises, naming the in-flight work."""
+    import threading
+
+    _, chunks, det = world
+    release = threading.Event()
+    driver = AsyncMultiSearchDriver(
+        _fresh_multi(chunks, 2), chunks, _hanging_detector(det, release),
+        cohorts=2, num_workers=2, result_limits=8, max_steps=1500,
+    )
+    monkeypatch.setattr(runtime, "STALL_TIMEOUT_S", 1.0)
+    try:
+        with pytest.raises(TimeoutError, match=r"in flight: \[0"):
+            driver.run()
+    finally:
+        release.set()

@@ -3,6 +3,7 @@ import jax
 import pytest
 
 from repro.core import init_carry, init_matcher, init_state
+from repro.core import runtime
 from repro.core.runtime import AsyncSearchDriver
 from repro.sim import RepoSpec, generate
 from repro.sim.oracle import oracle_detect
@@ -173,3 +174,42 @@ def test_async_driver_single_worker_equivalent_semantics(world):
     out = driver.run()
     assert int(out.results) >= 10
     assert driver.stats["reissues"] == 0
+
+
+def test_stuck_worker_times_out_run(world, monkeypatch):
+    """A worker that never returns must not hang ``run()`` (the old loop
+    returned a partial carry after 60 s; a bare retry would wait forever):
+    once no cohort completes for the stall limit it raises, naming the
+    cohorts in flight."""
+    import threading
+
+    import jax.numpy as jnp
+
+    repo, chunks, det = world
+    release = threading.Event()
+
+    def hang(frame):
+        release.wait(timeout=60.0)
+        return frame
+
+    def hanging(key, frame):
+        frame = jax.pure_callback(
+            hang, jax.ShapeDtypeStruct((), jnp.int32), frame,
+            vmap_method="sequential",
+        )
+        return det(key, frame)
+
+    carry = init_carry(
+        init_state(chunks.length), init_matcher(max_results=1024),
+        jax.random.PRNGKey(0),
+    )
+    driver = AsyncSearchDriver(
+        carry, chunks, hanging, cohort_size=4, num_workers=2,
+        result_limit=15, max_frames=3_000,
+    )
+    monkeypatch.setattr(runtime, "STALL_TIMEOUT_S", 1.0)
+    try:
+        with pytest.raises(TimeoutError, match=r"in flight: \[0, 1, 2\]"):
+            driver.run()
+    finally:
+        release.set()

@@ -219,7 +219,7 @@ def test_choose_chunks_batched_parity(method):
 
 
 # ---------------------------------------------------------------------------
-# Dedup + cache properties (run under the hypothesis stub when offline)
+# Dedup + cache properties (hypothesis)
 # ---------------------------------------------------------------------------
 
 

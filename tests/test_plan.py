@@ -3,8 +3,8 @@
 
 Every invalid plan must fail with a *typed* ``PlanError`` whose message
 names the offending option; any VALID plan must survive
-``from_dict(to_dict(plan)) == plan`` exactly (property-tested, runs under
-the hypothesis stub when offline).
+``from_dict(to_dict(plan)) == plan`` exactly (property-tested with
+hypothesis).
 """
 import dataclasses
 import warnings
@@ -239,7 +239,7 @@ def test_from_dict_accepts_json_lists():
 # ---------------------------------------------------------------------------
 
 
-def test_bench_registry_declares_and_skips():
+def test_bench_registry_declares_and_skips(monkeypatch):
     import pathlib
     import sys
 
@@ -252,8 +252,10 @@ def test_bench_registry_declares_and_skips():
     assert "plan_compose(sec10)" in by_name
     compose = by_name["plan_compose(sec10)"]
     assert compose.execution is not None and compose.execution.shards == 8
-    # subprocess-forcing benches never skip; in-process mesh requirements
-    # skip with a logged reason when the host is short on devices
+    # under JAX_PLATFORMS=cpu subprocess-forcing benches never skip;
+    # in-process mesh requirements skip with a logged reason when the host
+    # is short on devices
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     assert should_skip(compose, available_devices=1) is None  # self-forcing
     probe = dataclasses.replace(compose, forces_devices=False)
     reason = should_skip(probe, available_devices=1)
@@ -262,6 +264,12 @@ def test_bench_registry_declares_and_skips():
     for s in SECTIONS:
         if s.execution is None:
             assert should_skip(s, available_devices=1) is None
+    # on an accelerator nothing forces virtual devices: a self-forcing
+    # bench wider than the host skips, naming the count it needs
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    reason = should_skip(compose, available_devices=1)
+    assert reason is not None and "8" in reason and "1" in reason
+    assert should_skip(compose, available_devices=8) is None
     # the async-compose section declares its worker-thread need and only
     # skips when the host cannot start threads (probed, not assumed)
     assert "async_compose(sec11)" in by_name
@@ -347,3 +355,35 @@ def test_plan_run_rejects_mismatched_carry():
     with pytest.raises(PlanError, match="select"):
         SearchPlan().run(single, chunks, detector=det,
                          select=lambda q, d: d.valid)
+
+
+def test_ensure_host_devices_forces_virtual_devices_only_on_cpu(monkeypatch):
+    """A mesh wider than the host re-execs onto virtual CPU devices only
+    when the caller pinned JAX_PLATFORMS=cpu; on any other platform it
+    raises, naming the count needed, instead of moving to the CPU."""
+    import subprocess
+
+    from repro.launch import mesh
+
+    calls = []
+    monkeypatch.setattr(
+        subprocess, "call", lambda argv, env: calls.append(env) or 0
+    )
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    with pytest.raises(SystemExit):
+        mesh.ensure_host_devices(4, argv=["child"])
+    assert "device_count=4" in calls[0]["XLA_FLAGS"]
+    assert calls[0]["JAX_PLATFORMS"] == "cpu"
+    # a forced count that is still short is the repeat guard: no re-exec
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
+    with pytest.raises(RuntimeError, match="need 4"):
+        mesh.ensure_host_devices(4, argv=["child"])
+    assert len(calls) == 1
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("XLA_FLAGS")
+    with pytest.raises(RuntimeError, match="need 4 devices"):
+        mesh.ensure_host_devices(4, argv=["child"])
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        mesh.virtual_device_env(4)
+    assert len(calls) == 1

@@ -615,3 +615,53 @@ def test_front_e2e_four_tenants_one_live_driver():
     assert all(r.vacant for r in service.driver.rows)
     # unknown op is a clean protocol error
     assert not handle_request(service, {"op": "nope"})["ok"]
+
+
+def test_pump_failure_fails_drain(world):
+    """Regression: an exception in the background pump (here a worker
+    failure re-raised by the driver's scheduler tick) used to kill the
+    pump thread in silence.  ``drain()`` must raise it, not time out or
+    report the tenants as drained; the front must answer ``ok: false`` to
+    every later submit and stats request instead of admitting tenants
+    onto a dead pump; and a restart clears the old failure."""
+    from repro.launch.serve_search import handle_request
+    from repro.serve.service import PumpFailure
+
+    _, chunks, det = world
+    calls = []
+
+    def boom(frame):
+        calls.append(frame)
+        if len(calls) == 1:
+            raise RuntimeError("detector down")
+        return frame
+
+    def failing(key, frame):
+        frame = jax.pure_callback(
+            boom, jax.ShapeDtypeStruct((), jnp.int32), frame,
+            vmap_method="sequential",
+        )
+        return det(key, frame)
+
+    svc = _service(chunks, failing)
+    svc.submit("a", _plan(max_steps=600, limit=3), key=_qkey(0))
+    svc.start(pump=True)
+    try:
+        with pytest.raises(PumpFailure, match="service pump failed"):
+            svc.drain(deadline_s=60.0)
+        resp = handle_request(svc, {
+            "op": "submit", "tenant": "b", "class": 0,
+            "plan": _plan(max_steps=600, limit=3).to_dict(),
+        })
+        assert not resp["ok"] and "pump failed" in resp["error"]
+        assert "b" not in svc.tenants
+        resp = handle_request(svc, {"op": "stats"})
+        assert not resp["ok"] and "detector down" in resp["error"]
+    finally:
+        svc.stop()
+    # a new pump starts clean: the old error is not raised again
+    svc.start(pump=True)
+    try:
+        assert handle_request(svc, {"op": "stats"})["ok"]
+    finally:
+        svc.stop()

@@ -74,8 +74,10 @@ def test_interpret_kernel_matches_ref_on_tricky_states(m, bm):
 
 @pytest.mark.parametrize("m,bm", [(130, 64), (300, 128)])
 def test_interpret_batched_kernel_matches_per_query_kernel(m, bm):
-    """Multi-query grid (DESIGN.md §9): one (Q·C, M-blocks) launch must be
-    bit-identical per query row to Q separate ``thompson_choose`` calls."""
+    """Multi-query grid (DESIGN.md §9): one (Q, M-blocks) launch must give,
+    for every query row, what the plain reference ``thompson_ref`` gives on
+    that query's own statistics and normals — no row may read another
+    query's statistics, and each row must be right on its own."""
     from repro.kernels.thompson.kernel import thompson_choose_batched
 
     q_n, cohorts = 3, 4
@@ -91,12 +93,10 @@ def test_interpret_batched_kernel_matches_per_query_kernel(m, bm):
         block_m=bm, interpret=True,
     )
     for q in range(q_n):
-        sidx, sval = thompson_choose(
-            alphas[q], betas[q], zs[q], block_m=bm, interpret=True
-        )
-        np.testing.assert_array_equal(np.asarray(bidx[q]), np.asarray(sidx))
+        ridx, rval = thompson_ref(alphas[q], betas[q], zs[q])
+        np.testing.assert_array_equal(np.asarray(bidx[q]), np.asarray(ridx))
         np.testing.assert_allclose(
-            np.asarray(bval[q]), np.asarray(sval), rtol=1e-6
+            np.asarray(bval[q]), np.asarray(rval), rtol=1e-6
         )
 
 
