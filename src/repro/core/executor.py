@@ -48,6 +48,10 @@ class SearchStats:
     * ``detector_invocations`` / ``cache_hits`` — detector economics: the
       Q-axis lowerings count unique, uncached frames actually detected;
       single-query lowerings pay one invocation per sampled frame.
+    * ``detector_lanes`` — lanes the detector evaluated on the device,
+      padding and already-resolved frames included: rounds × Q × C on the
+      multi lowerings, slot batches × ``slots_per_batch`` × C on the async
+      one, one per cohort slot on the single-query lowerings.
     * ``rounds`` — synchronized choose→detect rounds (Q-axis lowerings).
     * ``frames_sampled`` — Σ per-query steps (what sequential runs pay).
     * ``merge_high_water`` / ``merge_overflow`` — matcher ring pressure
@@ -70,6 +74,7 @@ class SearchStats:
 
     detector_invocations: int = 0
     cache_hits: int = 0
+    detector_lanes: int = 0
     rounds: int = 0
     frames_sampled: int = 0
     merge_high_water: int = 0
@@ -280,8 +285,8 @@ class LoweredPlan:
             )
             step = int(out.step)
             stats = SearchStats(
-                detector_invocations=step, frames_sampled=step,
-                **_matcher_totals(out),
+                detector_invocations=step, detector_lanes=step,
+                frames_sampled=step, **_matcher_totals(out),
             )
             return finish(out, [trace], stats)
 
@@ -296,7 +301,8 @@ class LoweredPlan:
             out = driver.run()
             step = int(out.step)
             stats = SearchStats(
-                detector_invocations=step, frames_sampled=step,
+                detector_invocations=step, detector_lanes=step,
+                frames_sampled=step,
                 merge_high_water=int(driver.stats["merge_high_water"]),
                 merges=int(driver.stats["merges"]),
                 reissues=int(driver.stats["reissues"]),
@@ -321,6 +327,7 @@ class LoweredPlan:
             stats = SearchStats(
                 detector_invocations=int(driver.stats["detector_invocations"]),
                 cache_hits=int(driver.stats["cache_hits"]),
+                detector_lanes=int(driver.stats["detector_lanes"]),
                 rounds=int(driver.stats["rounds"]),
                 frames_sampled=int(np.asarray(out.step).sum()),
                 merge_high_water=int(driver.stats["merge_high_water"]),
@@ -361,7 +368,9 @@ class LoweredPlan:
             )
             step = int(out.step)
             stats = SearchStats(
-                detector_invocations=step, frames_sampled=step,
+                detector_invocations=step,
+                detector_lanes=sh["merges"] * ex.sync_every * p.cohorts,
+                frames_sampled=step,
                 merge_high_water=sh["merge_high_water"],
                 merge_overflow=sh["merge_overflow"],
                 merges=sh["merges"],
@@ -389,6 +398,7 @@ class LoweredPlan:
         stats = SearchStats(
             detector_invocations=ms["detector_invocations"],
             cache_hits=ms["cache_hits"],
+            detector_lanes=ms["rounds"] * p.queries * p.cohorts,
             rounds=ms["rounds"],
             frames_sampled=ms["frames_sampled"],
             merge_high_water=ms.get("merge_high_water", 0),
@@ -402,10 +412,13 @@ class LoweredPlan:
         )
 
     def _package(self, out, traces, stats) -> SearchResult:
-        steps = tuple(int(s) for s in np.atleast_1d(np.asarray(out.step)))
-        results = tuple(
-            int(r) for r in np.atleast_1d(np.asarray(out.results))
-        )
+        with jax.profiler.TraceAnnotation("exsample.readback"):
+            steps = tuple(
+                int(s) for s in np.atleast_1d(np.asarray(out.step))
+            )
+            results = tuple(
+                int(r) for r in np.atleast_1d(np.asarray(out.results))
+            )
         return SearchResult(
             carry=out, steps=steps, results=results, traces=traces,
             stats=stats, plan=self.plan, kind=self.kind,
@@ -526,11 +539,12 @@ def _search_multi_sharded_device(
                 alpha0=alpha0,
                 beta0=beta0,
             )
-            a_l, b_l = thompson.gamma_params(view)
-            c_ids, c_scores, c_n = local_cohort_winners_batched(
-                k_choice, a_l, b_l, view.exhausted(), view.n,
-                axis=axis, cohorts=cohorts,
-            )                                                    # [Q, C]
+            with jax.named_scope("choose"):
+                a_l, b_l = thompson.gamma_params(view)
+                c_ids, c_scores, c_n = local_cohort_winners_batched(
+                    k_choice, a_l, b_l, view.exhausted(), view.n,
+                    axis=axis, cohorts=cohorts,
+                )                                                # [Q, C]
             # §8 within-window random+ rank dedup, per query: occurrence
             # index within the round plus replicated foreign-pick counts
             live_c = jnp.isfinite(c_scores) & active[:, None]    # [Q, C]
@@ -571,7 +585,8 @@ def _search_multi_sharded_device(
             det_keys_flat = det_keys.reshape((b,) + det_keys.shape[2:])
             first_idx = dedup_first_index(flat_frames, flat_live)
             is_rep = (first_idx == jnp.arange(b, dtype=jnp.int32)) & flat_live
-            fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
+            with jax.named_scope("detect"):
+                fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
             if cache is not None:
                 # Hash-sharded cache routing (DESIGN.md §14): frame f lives
                 # ONLY on shard f % S.  Requests are free — the replicated
@@ -672,14 +687,15 @@ def _search_multi_sharded_device(
                     )
                     d1_local = mres.d1 - mres.cross_chunk
                     upd = live.astype(dn1_q.dtype)
-                    dn1_q = dn1_q.at[cids_q[j]].add(
-                        (mres.d0 - d1_local).astype(dn1_q.dtype) * upd
-                    )
-                    dn_q = dn_q.at[cids_q[j]].add(upd)
-                    valid_home = mres.cross_home >= 0
-                    dn1_q = dn1_q.at[
-                        jnp.where(valid_home, mres.cross_home, 0)
-                    ].add(-valid_home.astype(dn1_q.dtype))
+                    with jax.named_scope("update"):
+                        dn1_q = dn1_q.at[cids_q[j]].add(
+                            (mres.d0 - d1_local).astype(dn1_q.dtype) * upd
+                        )
+                        dn_q = dn_q.at[cids_q[j]].add(upd)
+                        valid_home = mres.cross_home >= 0
+                        dn1_q = dn1_q.at[
+                            jnp.where(valid_home, mres.cross_home, 0)
+                        ].add(-valid_home.astype(dn1_q.dtype))
                     return (
                         dn1_q, dn_q, mres.new_state,
                         lstep_q + live.astype(jnp.int32),

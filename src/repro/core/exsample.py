@@ -83,12 +83,14 @@ def _process_frame(
     """Algorithm 1 lines 9-16 for one frame of ``chunk_id``."""
     # line 9: within-chunk random+ sample; the per-chunk counter n doubles
     # as the low-discrepancy rank so no extra state is needed.
-    rank = carry.sampler.n[chunk_id].astype(jnp.int32)
-    frame_id = randomplus_frame(chunks, chunk_id, rank)
+    with jax.named_scope("choose"):
+        rank = carry.sampler.n[chunk_id].astype(jnp.int32)
+        frame_id = randomplus_frame(chunks, chunk_id, rank)
     video_id = chunks.video_id[chunk_id]
 
     # lines 10-11: io + decode + detect (the expensive part)
-    dets = detector(det_key, frame_id)
+    with jax.named_scope("detect"):
+        dets = detector(det_key, frame_id)
 
     # line 12: matcher
     m = match_and_update(
@@ -296,18 +298,20 @@ def _scan_search(
     (they size the trace buffer and the cohort batch); ``result_limit``
     stays dynamic so sweeping recall targets reuses one executable.
     """
-    carry, buf, n = _search_scan_device(
-        carry,
-        chunks,
-        jnp.asarray(result_limit, jnp.int32),
-        detector=detector,
-        cohorts=cohorts,
-        method=method,
-        max_steps=max_steps,
-        trace_every=trace_every,
-    )
-    buf_host = np.asarray(buf)  # the single device→host sync
-    trace = [(int(s), int(r)) for s, r in buf_host[: int(n)]]
+    with jax.profiler.TraceAnnotation("exsample.dispatch"):
+        carry, buf, n = _search_scan_device(
+            carry,
+            chunks,
+            jnp.asarray(result_limit, jnp.int32),
+            detector=detector,
+            cohorts=cohorts,
+            method=method,
+            max_steps=max_steps,
+            trace_every=trace_every,
+        )
+    with jax.profiler.TraceAnnotation("exsample.readback"):
+        buf_host = np.asarray(buf)  # the single device→host sync
+        trace = [(int(s), int(r)) for s, r in buf_host[: int(n)]]
     return carry, trace
 
 
@@ -398,11 +402,12 @@ def _search_sharded_device(
                 alpha0=alpha0,
                 beta0=beta0,
             )
-            a_l, b_l = thompson.gamma_params(view)
-            c_ids, c_scores, c_n = local_cohort_winners(
-                k_choice, a_l, b_l, view.exhausted(), view.n,
-                axis=axis, cohorts=cohorts,
-            )
+            with jax.named_scope("choose"):
+                a_l, b_l = thompson.gamma_params(view)
+                c_ids, c_scores, c_n = local_cohort_winners(
+                    k_choice, a_l, b_l, view.exhausted(), view.n,
+                    axis=axis, cohorts=cohorts,
+                )
             # Within-window random+ rank dedup.  Thompson concentrates on
             # hot chunks, so several cohorts routinely pick the SAME chunk
             # in one round; the owner's view gives them all the same rank
@@ -434,7 +439,8 @@ def _search_sharded_device(
                 # (harmless) detector but gate every state update off
                 live = live_c[g]
                 frame_id = randomplus_frame(chks, cid, ranks[g])
-                dets = detector(jax.random.fold_in(k_det, g), frame_id)
+                with jax.named_scope("detect"):
+                    dets = detector(jax.random.fold_in(k_det, g), frame_id)
                 mres = match_and_update(
                     matcher,
                     dets.boxes,
@@ -449,14 +455,15 @@ def _search_sharded_device(
                 # the delta buffer is full-width [M]
                 d1_local = mres.d1 - mres.cross_chunk
                 upd = live.astype(delta_n1.dtype)
-                delta_n1 = delta_n1.at[cid].add(
-                    (mres.d0 - d1_local).astype(delta_n1.dtype) * upd
-                )
-                delta_n = delta_n.at[cid].add(upd)
-                valid_home = mres.cross_home >= 0
-                delta_n1 = delta_n1.at[
-                    jnp.where(valid_home, mres.cross_home, 0)
-                ].add(-valid_home.astype(delta_n1.dtype))
+                with jax.named_scope("update"):
+                    delta_n1 = delta_n1.at[cid].add(
+                        (mres.d0 - d1_local).astype(delta_n1.dtype) * upd
+                    )
+                    delta_n = delta_n.at[cid].add(upd)
+                    valid_home = mres.cross_home >= 0
+                    delta_n1 = delta_n1.at[
+                        jnp.where(valid_home, mres.cross_home, 0)
+                    ].add(-valid_home.astype(delta_n1.dtype))
                 return (
                     delta_n1,
                     delta_n,
@@ -741,26 +748,27 @@ def multi_round_choose(
     the per-slot detector keys.  Pure function of the carry — bit-for-bit
     the choice ``_multi_round`` used to compute inline."""
     c = cohorts
-    keys = jax.vmap(lambda k: jax.random.split(k, 3))(mc.key)
-    key_next, k_choice, k_det = keys[:, 0], keys[:, 1], keys[:, 2]
+    with jax.named_scope("choose"):
+        keys = jax.vmap(lambda k: jax.random.split(k, 3))(mc.key)
+        key_next, k_choice, k_det = keys[:, 0], keys[:, 1], keys[:, 2]
 
-    chunk_ids = thompson.choose_chunks_batched(
-        k_choice, mc.sampler, cohorts=c, method=method
-    )                                                        # i32[Q, C]
-    # within-round rank advance: cohort j of query q reads n AFTER its own
-    # earlier same-chunk picks incremented it (exsample_batch_step's
-    # sequential _process_frame order), so occ is the per-query count of
-    # earlier cohorts that picked the same chunk
-    eq = chunk_ids[:, :, None] == chunk_ids[:, None, :]      # [Q, C, C]
-    occ = jnp.sum(jnp.tril(eq, -1), axis=-1)                 # [Q, C]
-    n0 = jnp.take_along_axis(mc.sampler.n, chunk_ids, axis=-1)
-    ranks = (n0 + occ.astype(n0.dtype)).astype(jnp.int32)
-    frame_ids = randomplus_frame(chunks, chunk_ids, ranks)   # i32[Q, C]
+        chunk_ids = thompson.choose_chunks_batched(
+            k_choice, mc.sampler, cohorts=c, method=method
+        )                                                    # i32[Q, C]
+        # within-round rank advance: cohort j of query q reads n AFTER its
+        # own earlier same-chunk picks incremented it (exsample_batch_step's
+        # sequential _process_frame order), so occ is the per-query count
+        # of earlier cohorts that picked the same chunk
+        eq = chunk_ids[:, :, None] == chunk_ids[:, None, :]  # [Q, C, C]
+        occ = jnp.sum(jnp.tril(eq, -1), axis=-1)             # [Q, C]
+        n0 = jnp.take_along_axis(mc.sampler.n, chunk_ids, axis=-1)
+        ranks = (n0 + occ.astype(n0.dtype)).astype(jnp.int32)
+        frame_ids = randomplus_frame(chunks, chunk_ids, ranks)  # i32[Q, C]
 
-    if c == 1:
-        det_keys = k_det[:, None]        # exsample_step uses k_det unsplit
-    else:
-        det_keys = jax.vmap(lambda k: jax.random.split(k, c))(k_det)
+        if c == 1:
+            det_keys = k_det[:, None]    # exsample_step uses k_det unsplit
+        else:
+            det_keys = jax.vmap(lambda k: jax.random.split(k, c))(k_det)
     return RoundChoice(
         key_next=key_next, chunk_ids=chunk_ids, ranks=ranks,
         frame_ids=frame_ids, det_keys=det_keys,
@@ -806,22 +814,25 @@ def multi_round_process(
     # ---- cross-query dedup + cache: one detector batch for the union ----
     first_idx = dedup_first_index(flat_frames, flat_valid)
     is_rep = (first_idx == jnp.arange(b, dtype=jnp.int32)) & flat_valid
-    fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
-    if cache is not None:
-        hit, cached = cache_lookup(cache, flat_frames)
-        expand = lambda m, x: m.reshape(m.shape + (1,) * (x.ndim - 1))
-        resolved = jax.tree.map(
-            lambda cv, fv: jnp.where(expand(hit, fv), cv, fv), cached, fresh
-        )
-        need = is_rep & ~hit
-        cache = cache_insert(cache, flat_frames, fresh, need)
-    else:
-        hit = jnp.zeros((b,), bool)
-        resolved = fresh
-        need = is_rep
-    # scatter-back: every slot gathers its representative's detections, so
-    # each query consumes detections of exactly the frame it sampled
-    dets_flat = jax.tree.map(lambda x: x[first_idx], resolved)
+    with jax.named_scope("detect"):
+        fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
+    with jax.named_scope("dedup_cache"):
+        if cache is not None:
+            hit, cached = cache_lookup(cache, flat_frames)
+            expand = lambda m, x: m.reshape(m.shape + (1,) * (x.ndim - 1))
+            resolved = jax.tree.map(
+                lambda cv, fv: jnp.where(expand(hit, fv), cv, fv),
+                cached, fresh,
+            )
+            need = is_rep & ~hit
+            cache = cache_insert(cache, flat_frames, fresh, need)
+        else:
+            hit = jnp.zeros((b,), bool)
+            resolved = fresh
+            need = is_rep
+        # scatter-back: every slot gathers its representative's detections,
+        # so each query consumes detections of exactly the frame it sampled
+        dets_flat = jax.tree.map(lambda x: x[first_idx], resolved)
     fresh_calls = jnp.sum(need).astype(jnp.int32)
     cache_hits = jnp.sum(is_rep & hit).astype(jnp.int32)
 
@@ -1054,43 +1065,46 @@ def _multi_search(
     publish fresh detections into the index.
     """
     q_n = int(carries.step.shape[0])
-    limits = jnp.broadcast_to(
-        jnp.asarray(result_limits, jnp.int32), (q_n,)
-    )
-    if cache is None and cache_frames:
-        from repro.serve.batcher import init_detection_cache
-
-        struct = jax.eval_shape(
-            detector, jax.random.PRNGKey(0), jnp.zeros((), jnp.int32)
+    with jax.profiler.TraceAnnotation("exsample.prepare"):
+        limits = jnp.broadcast_to(
+            jnp.asarray(result_limits, jnp.int32), (q_n,)
         )
-        cache = init_detection_cache(struct, cache_frames)
-    out, cache, buf, n, calls, hits, ihits, rounds = _search_multi_device(
-        carries,
-        chunks,
-        limits,
-        cache,
-        warm_tag,
-        detector=detector,
-        select=select,
-        cohorts=cohorts,
-        method=method,
-        max_steps=max_steps,
-        trace_every=trace_every,
-    )
-    buf_host = np.asarray(buf)  # the single device→host sync
-    n_host = np.asarray(n)
-    traces = [
-        [(int(s), int(r)) for s, r in buf_host[q][: int(n_host[q])]]
-        for q in range(q_n)
-    ]
-    stats = {
-        "detector_invocations": int(calls),
-        "cache_hits": int(hits),
-        "index_hits": int(ihits),
-        "rounds": int(rounds),
-        "frames_sampled": int(np.asarray(out.step).sum()),
-        "final_cache": cache,
-    }
+        if cache is None and cache_frames:
+            from repro.serve.batcher import init_detection_cache
+
+            struct = jax.eval_shape(
+                detector, jax.random.PRNGKey(0), jnp.zeros((), jnp.int32)
+            )
+            cache = init_detection_cache(struct, cache_frames)
+    with jax.profiler.TraceAnnotation("exsample.dispatch"):
+        out, cache, buf, n, calls, hits, ihits, rounds = _search_multi_device(
+            carries,
+            chunks,
+            limits,
+            cache,
+            warm_tag,
+            detector=detector,
+            select=select,
+            cohorts=cohorts,
+            method=method,
+            max_steps=max_steps,
+            trace_every=trace_every,
+        )
+    with jax.profiler.TraceAnnotation("exsample.readback"):
+        buf_host = np.asarray(buf)  # the single device→host sync
+        n_host = np.asarray(n)
+        traces = [
+            [(int(s), int(r)) for s, r in buf_host[q][: int(n_host[q])]]
+            for q in range(q_n)
+        ]
+        stats = {
+            "detector_invocations": int(calls),
+            "cache_hits": int(hits),
+            "index_hits": int(ihits),
+            "rounds": int(rounds),
+            "frames_sampled": int(np.asarray(out.step).sum()),
+            "final_cache": cache,
+        }
     return out, traces, stats
 
 

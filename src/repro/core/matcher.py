@@ -126,6 +126,13 @@ def match_and_update(
       - matched detections bump times_seen of their entry;  d₁ counts
         entries whose times_seen went exactly 1 → 2 this frame.
     """
+    with jax.named_scope("match"):
+        return _match_frame(
+            state, boxes, feats, valid, video_id, frame_id, chunk_id
+        )
+
+
+def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
     occupied = state.times_seen > 0
     iou = pairwise_iou(boxes, state.boxes)
     same_video = state.video[None, :] == video_id

@@ -41,6 +41,7 @@ real deployment each worker is a pod client.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 import queue
@@ -75,6 +76,10 @@ from repro.core.state import SamplerState
 from repro.core.thompson import choose_chunks
 from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.serve.batcher import cache_insert, init_detection_cache
+
+# Slot rounds whose (issued, taken, done, merged) stamps the elastic driver
+# keeps for ``recent_rounds``; older rounds fall off the end.
+ROUND_HISTORY = 256
 
 
 class MatcherRingOverflow(RuntimeError):
@@ -455,16 +460,43 @@ class SlotBatch:
     active: np.ndarray          # bool[B] — False = padding lane
     select_ids: np.ndarray = None   # i32[B] — id handed to select() per lane
     issue_count: int = 0        # >1 ⇒ re-issued (straggler/death)
+    issued_s: float = 0.0       # monotonic stamp at first issue
 
 
 @dataclasses.dataclass
 class SlotResult:
+    """A processed slot batch and the round's ``time.monotonic`` stamps:
+    issued (driver), taken and done (worker), merged (driver, at the end
+    of the merge; 0.0 until then)."""
+
     batch_id: int
     worker_id: int
     carry: ExSampleCarry        # post-round rows, leading [B]
     fresh_calls: int            # unique, uncached frames detected
     cache_hits: int
     aux: RoundAux               # fresh detections for cache publication
+    issued_s: float = 0.0
+    taken_s: float = 0.0
+    done_s: float = 0.0
+    merged_s: float = 0.0
+
+
+def round_summary(rounds) -> dict:
+    """Median round time (issued to merged) and 90th percentile of the
+    time a round sat in queues — for a worker (issued to taken), then for
+    the pump and its merge (done to merged) — over ``recent_rounds()``;
+    None without rounds."""
+    def rank(values, q):
+        v = sorted(values)
+        return v[max(math.ceil(q / 100.0 * len(v)) - 1, 0)] if v else None
+
+    return {
+        "rounds": len(rounds),
+        "round_p50_s": rank([m - i for i, _, _, m in rounds], 50),
+        "slot_wait_p90_s": rank(
+            [(t - i) + (m - d) for i, t, d, m in rounds], 90
+        ),
+    }
 
 
 @dataclasses.dataclass
@@ -498,8 +530,6 @@ class _QueryRow:
     admitted_s: float = 0.0     # monotonic wall-clock at admit/construction
     first_result_s: float = 0.0  # monotonic stamp of the first result merge
     finished_s: float = 0.0     # monotonic stamp at retire
-    result_stamps: list = dataclasses.field(default_factory=list)
-    # ^ (monotonic_s, cumulative_results) per merge that grew results
 
 
 class AsyncMultiSearchDriver:
@@ -656,7 +686,14 @@ class AsyncMultiSearchDriver:
             # over slot lanes): how many lanes of each emitted SlotBatch
             # carried a live query vs sentinel padding
             "lanes_issued": 0, "lanes_padded": 0,
+            # lanes the detector evaluated: slots_per_batch × cohorts for
+            # every processed batch, duplicates and padding included
+            "detector_lanes": 0,
         }
+        # (issued_s, taken_s, done_s, merged_s) of the latest merged rounds
+        self._rounds: collections.deque = collections.deque(
+            maxlen=ROUND_HISTORY
+        )
 
     # ---- row liveness / elasticity ----------------------------------------
 
@@ -786,7 +823,8 @@ class AsyncMultiSearchDriver:
         """Check out a cohort slot for every issuable query (active, live,
         no slot in flight), packed into fixed-shape batches.  Queries that
         are no longer live retire here instead of issuing."""
-        with self._lock:
+        with jax.profiler.TraceAnnotation("exsample.issue") as span, \
+                self._lock:
             issuable = []
             for i, row in enumerate(self.rows):
                 if not row.active or row.inflight:
@@ -822,6 +860,7 @@ class AsyncMultiSearchDriver:
                     choice=choice,
                     active=active,
                     select_ids=select_ids,
+                    issued_s=time.monotonic(),
                 )
                 self._next_batch += 1
                 self.stats["lanes_issued"] += len(group)
@@ -831,6 +870,11 @@ class AsyncMultiSearchDriver:
                 self._inflight[batch.batch_id] = batch
                 self.stats["slots"] += 1
                 batches.append(batch)
+            if batches:
+                # one span a pass: its first batch id, the live lanes issued
+                span.set_metadata(
+                    batch=batches[0].batch_id, lanes=len(issuable)
+                )
         for batch in batches:
             self._work.put(batch)
         return batches
@@ -847,9 +891,13 @@ class AsyncMultiSearchDriver:
         post-round state — sound because that lane's rounds are
         serialized, so the worker's output is the row's unique successor.
         Live ring entries the round evicted spill to the row's host
-        ``ResultLog`` before the replacement lands."""
+        ``ResultLog`` before the replacement lands.  The round's stamps,
+        ``merged_s`` last, go to ``recent_rounds``."""
         now = time.monotonic()
-        with self._lock:
+        with jax.profiler.TraceAnnotation(
+            "exsample.merge", batch=res.batch_id, lanes=self.slots_per_batch
+        ), self._lock:
+            self.stats["detector_lanes"] += self.slots_per_batch * self.cohorts
             batch = self._inflight.pop(res.batch_id, None)
             if batch is None:
                 self.stats["duplicate_drops"] += 1
@@ -908,15 +956,17 @@ class AsyncMultiSearchDriver:
                     if (s1 // self.trace_every) > (s0 // self.trace_every):
                         row.trace.append((s1, int(new_carry.results)))
                 grew = int(new_carry.results) > int(row.carry.results)
-                if grew:
-                    if not row.first_result_s:
-                        row.first_result_s = now
-                    row.result_stamps.append((now, int(new_carry.results)))
+                if grew and not row.first_result_s:
+                    row.first_result_s = now
                 row.carry = new_carry
                 row.rounds += 1
                 row.inflight = False
                 if not self._row_live(row):
                     self._retire(row)
+            res.merged_s = time.monotonic()
+            self._rounds.append(
+                (res.issued_s, res.taken_s, res.done_s, res.merged_s)
+            )
 
     def _reissue(self, batch_id: int) -> None:
         with self._lock:
@@ -935,6 +985,7 @@ class AsyncMultiSearchDriver:
         completions synchronously); reads only the batch's own row
         snapshots plus a cache snapshot — never the live rows, which may
         be mid-merge on another thread."""
+        taken_s = time.monotonic()
         with self._lock:
             cache = self.cache
         # query_ids only feeds ``select(qi, dets)`` in the round body, so a
@@ -959,6 +1010,9 @@ class AsyncMultiSearchDriver:
             fresh_calls=int(fresh_calls),
             cache_hits=int(cache_hits),
             aux=aux,
+            issued_s=batch.issued_s,
+            taken_s=taken_s,
+            done_s=time.monotonic(),
         )
 
     def _worker(self, wid: int) -> None:
@@ -970,7 +1024,11 @@ class AsyncMultiSearchDriver:
             t0 = time.monotonic()
             self.monitor.assign(wid, batch.batch_id, now=t0)
             try:
-                res = self._process_batch(wid, batch)
+                with jax.profiler.TraceAnnotation(
+                    "exsample.process", batch=batch.batch_id,
+                    lanes=len(batch.query_rows),
+                ):
+                    res = self._process_batch(wid, batch)
             except Exception as e:  # noqa: BLE001 — re-raised by the scheduler
                 self._results.put(WorkerFailure(wid, e))
                 return
@@ -1008,6 +1066,12 @@ class AsyncMultiSearchDriver:
             return not self._inflight and not any(
                 r.active for r in self.rows
             )
+
+    def recent_rounds(self) -> list:
+        """``(issued_s, taken_s, done_s, merged_s)`` of the latest
+        ``ROUND_HISTORY`` merged slot rounds, oldest first."""
+        with self._lock:
+            return list(self._rounds)
 
     def service_tick(self, timeout: float = 0.1) -> bool:
         """One scheduler heartbeat: issue what is issuable, merge at most

@@ -80,12 +80,15 @@ def apply_update(
     scatter-add so colliding chunk indices accumulate, preserving
     commutativity.
     """
-    chunk_idx = jnp.atleast_1d(jnp.asarray(chunk_idx))
-    d0 = jnp.broadcast_to(jnp.asarray(d0, state.n1.dtype), chunk_idx.shape)
-    d1 = jnp.broadcast_to(jnp.asarray(d1, state.n1.dtype), chunk_idx.shape)
-    samples = jnp.broadcast_to(jnp.asarray(samples, state.n.dtype), chunk_idx.shape)
-    n1 = state.n1.at[chunk_idx].add(d0 - d1)
-    n = state.n.at[chunk_idx].add(samples)
+    with jax.named_scope("update"):
+        chunk_idx = jnp.atleast_1d(jnp.asarray(chunk_idx))
+        d0 = jnp.broadcast_to(jnp.asarray(d0, state.n1.dtype), chunk_idx.shape)
+        d1 = jnp.broadcast_to(jnp.asarray(d1, state.n1.dtype), chunk_idx.shape)
+        samples = jnp.broadcast_to(
+            jnp.asarray(samples, state.n.dtype), chunk_idx.shape
+        )
+        n1 = state.n1.at[chunk_idx].add(d0 - d1)
+        n = state.n.at[chunk_idx].add(samples)
     return dataclasses.replace(state, n1=n1, n=n)
 
 
@@ -94,9 +97,13 @@ def apply_cross_chunk_decrement(
 ) -> SamplerState:
     """§3.4: a result first seen in chunk ``home_chunk`` was re-found in a
     *different* chunk — its contribution leaves N¹ of the home chunk."""
-    home_chunk = jnp.atleast_1d(jnp.asarray(home_chunk))
-    count = jnp.broadcast_to(jnp.asarray(count, state.n1.dtype), home_chunk.shape)
-    return dataclasses.replace(state, n1=state.n1.at[home_chunk].add(-count))
+    with jax.named_scope("update"):
+        home_chunk = jnp.atleast_1d(jnp.asarray(home_chunk))
+        count = jnp.broadcast_to(
+            jnp.asarray(count, state.n1.dtype), home_chunk.shape
+        )
+        n1 = state.n1.at[home_chunk].add(-count)
+    return dataclasses.replace(state, n1=n1)
 
 
 def merge_states(a: SamplerState, b: SamplerState) -> SamplerState:

@@ -74,22 +74,23 @@ def choose_chunks(
     method: str = "exact",
 ) -> jax.Array:
     """Algorithm 1 lines 5-8, batched (§3.7.1).  Returns i32[cohorts]."""
-    if method == "exact":
-        scores = draw_scores(key, state, cohorts=cohorts)
-    elif method == "wilson_hilferty":
-        scores = draw_scores_wilson_hilferty(key, state, cohorts=cohorts)
-    elif method == "pallas":
-        # deferred import: kernels.thompson.ref imports this module
-        from repro.kernels.thompson.ops import choose
+    with jax.named_scope("choose"):
+        if method == "exact":
+            scores = draw_scores(key, state, cohorts=cohorts)
+        elif method == "wilson_hilferty":
+            scores = draw_scores_wilson_hilferty(key, state, cohorts=cohorts)
+        elif method == "pallas":
+            # deferred import: kernels.thompson.ref imports this module
+            from repro.kernels.thompson.ops import choose
 
-        alpha, beta = gamma_params(state)  # already clamped ≥ alpha0/2 > 0
-        alpha = jnp.where(state.exhausted(), -1.0, alpha)
-        z = jax.random.normal(key, (cohorts, alpha.shape[0]), dtype=alpha.dtype)
-        idx, _ = choose(alpha, beta, z)
-        return idx
-    else:
-        raise ValueError(f"unknown Thompson method: {method!r}")
-    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+            alpha, beta = gamma_params(state)  # already clamped ≥ alpha0/2 > 0
+            alpha = jnp.where(state.exhausted(), -1.0, alpha)
+            z = jax.random.normal(key, (cohorts, alpha.shape[0]), dtype=alpha.dtype)
+            idx, _ = choose(alpha, beta, z)
+            return idx
+        else:
+            raise ValueError(f"unknown Thompson method: {method!r}")
+        return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("cohorts", "method"))
@@ -111,21 +112,22 @@ def choose_chunks_batched(
     meaningful.  The pallas path stays ONE kernel launch (per-query alpha
     rows, grid [Q·C, M-blocks]) rather than Q serial kernel calls.
     """
-    if method in ("exact", "wilson_hilferty"):
-        f = partial(choose_chunks, cohorts=cohorts, method=method)
-        return jax.vmap(f)(keys, state)
-    if method == "pallas":
-        from repro.kernels.thompson.ops import choose_batched
+    with jax.named_scope("choose"):
+        if method in ("exact", "wilson_hilferty"):
+            f = partial(choose_chunks, cohorts=cohorts, method=method)
+            return jax.vmap(f)(keys, state)
+        if method == "pallas":
+            from repro.kernels.thompson.ops import choose_batched
 
-        alpha, beta = gamma_params(state)            # [Q, M], pre-clamped
-        alpha = jnp.where(state.exhausted(), -1.0, alpha)
-        m = alpha.shape[-1]
-        z = jax.vmap(
-            lambda k: jax.random.normal(k, (cohorts, m), dtype=alpha.dtype)
-        )(keys)
-        idx, _ = choose_batched(alpha, beta, z)
-        return idx
-    raise ValueError(f"unknown Thompson method: {method!r}")
+            alpha, beta = gamma_params(state)            # [Q, M], pre-clamped
+            alpha = jnp.where(state.exhausted(), -1.0, alpha)
+            m = alpha.shape[-1]
+            z = jax.vmap(
+                lambda k: jax.random.normal(k, (cohorts, m), dtype=alpha.dtype)
+            )(keys)
+            idx, _ = choose_batched(alpha, beta, z)
+            return idx
+        raise ValueError(f"unknown Thompson method: {method!r}")
 
 
 def greedy_chunks(state: SamplerState, *, cohorts: int = 1) -> jax.Array:

@@ -129,11 +129,14 @@ def dedup_first_index(frame_ids: jax.Array, valid: jax.Array) -> jax.Array:
     detected, or counted, twice in a batch).  O(B²) compare — B = Q·C
     cohort slots, small by construction.
     """
-    b = frame_ids.shape[0]
-    idx = jnp.arange(b, dtype=jnp.int32)
-    same = (frame_ids[:, None] == frame_ids[None, :]) & valid[None, :]
-    first = jnp.min(jnp.where(same, idx[None, :], b), axis=1).astype(jnp.int32)
-    return jnp.where(valid & (first < b), first, idx)
+    with jax.named_scope("dedup_cache"):
+        b = frame_ids.shape[0]
+        idx = jnp.arange(b, dtype=jnp.int32)
+        same = (frame_ids[:, None] == frame_ids[None, :]) & valid[None, :]
+        first = jnp.min(
+            jnp.where(same, idx[None, :], b), axis=1
+        ).astype(jnp.int32)
+        return jnp.where(valid & (first < b), first, idx)
 
 
 @jax.tree_util.register_dataclass
@@ -172,9 +175,10 @@ def cache_lookup(cache: DetectionCache, frame_ids: jax.Array):
     of -1 maps to slot ``capacity-1`` and would compare equal to the
     empty-slot tag -1, reporting a phantom hit whose gathered "detections"
     are garbage (zeros or whatever real frame lives there)."""
-    slot = frame_ids % cache.capacity
-    hit = (frame_ids >= 0) & (cache.tag[slot] == frame_ids)
-    vals = jax.tree.map(lambda x: x[slot], cache.store)
+    with jax.named_scope("dedup_cache"):
+        slot = frame_ids % cache.capacity
+        hit = (frame_ids >= 0) & (cache.tag[slot] == frame_ids)
+        vals = jax.tree.map(lambda x: x[slot], cache.store)
     return hit, vals
 
 
@@ -187,16 +191,17 @@ def cache_insert(
     Sentinel frames (``frame_ids < 0``) never insert, whatever ``mask``
     says: a -1 padding id would otherwise tag slot ``capacity-1`` with -1
     and poison every later lookup of a real frame in that slot."""
-    s = cache.capacity
-    slot = (frame_ids % s).astype(jnp.int32)
-    valid = mask & (frame_ids >= 0)
-    first = dedup_first_index(slot, valid)
-    keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
-    tgt = jnp.where(keep, slot, s)
-    tag = cache.tag.at[tgt].set(frame_ids, mode="drop")
-    store = jax.tree.map(
-        lambda st, v: st.at[tgt].set(v, mode="drop"), cache.store, dets
-    )
+    with jax.named_scope("dedup_cache"):
+        s = cache.capacity
+        slot = (frame_ids % s).astype(jnp.int32)
+        valid = mask & (frame_ids >= 0)
+        first = dedup_first_index(slot, valid)
+        keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
+        tgt = jnp.where(keep, slot, s)
+        tag = cache.tag.at[tgt].set(frame_ids, mode="drop")
+        store = jax.tree.map(
+            lambda st, v: st.at[tgt].set(v, mode="drop"), cache.store, dets
+        )
     return DetectionCache(tag=tag, store=store)
 
 
@@ -307,11 +312,12 @@ def sharded_cache_lookup(
     ``shard_map``: serve exactly the probes homed here (``frame % S ==
     shard_id``); everything else — sentinels included — reports a miss
     with unread gathered values.  ``frame_ids`` may be any shape."""
-    local = cache_local.capacity
-    mine = (frame_ids >= 0) & (frame_ids % num_shards == shard_id)
-    slot = (frame_ids // num_shards) % local
-    hit = mine & (cache_local.tag[slot] == frame_ids)
-    vals = jax.tree.map(lambda x: x[slot], cache_local.store)
+    with jax.named_scope("dedup_cache"):
+        local = cache_local.capacity
+        mine = (frame_ids >= 0) & (frame_ids % num_shards == shard_id)
+        slot = (frame_ids // num_shards) % local
+        hit = mine & (cache_local.tag[slot] == frame_ids)
+        vals = jax.tree.map(lambda x: x[slot], cache_local.store)
     return hit, vals
 
 
@@ -328,16 +334,18 @@ def sharded_cache_insert(
     slots, first-write-wins on within-batch slot collisions in batch
     order — the same winner the direct-mapped :func:`cache_insert` picks
     over the equivalent global batch."""
-    local = cache_local.capacity
-    valid = (
-        mask & (frame_ids >= 0) & (frame_ids % num_shards == shard_id)
-    )
-    slot = ((frame_ids // num_shards) % local).astype(jnp.int32)
-    first = dedup_first_index(slot, valid)
-    keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
-    tgt = jnp.where(keep, slot, local)
-    tag = cache_local.tag.at[tgt].set(frame_ids, mode="drop")
-    store = jax.tree.map(
-        lambda st, v: st.at[tgt].set(v, mode="drop"), cache_local.store, dets
-    )
+    with jax.named_scope("dedup_cache"):
+        local = cache_local.capacity
+        valid = (
+            mask & (frame_ids >= 0) & (frame_ids % num_shards == shard_id)
+        )
+        slot = ((frame_ids // num_shards) % local).astype(jnp.int32)
+        first = dedup_first_index(slot, valid)
+        keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
+        tgt = jnp.where(keep, slot, local)
+        tag = cache_local.tag.at[tgt].set(frame_ids, mode="drop")
+        store = jax.tree.map(
+            lambda st, v: st.at[tgt].set(v, mode="drop"),
+            cache_local.store, dets,
+        )
     return DetectionCache(tag=tag, store=store)

@@ -50,7 +50,7 @@ import numpy as np
 
 from repro.core.executor import SearchStats, tenant_stats_from_row
 from repro.core.plan import PlanError, SearchPlan, ServiceConfig
-from repro.core.runtime import AsyncMultiSearchDriver
+from repro.core.runtime import AsyncMultiSearchDriver, round_summary
 from repro.sim.costmodel import (
     CostBudget,
     CostRates,
@@ -422,8 +422,10 @@ class SearchService:
         """One service heartbeat: merge at most one driver batch, harvest
         retired tenants, admit queued plans into freed capacity."""
         merged = self.driver.service_tick(timeout=timeout)
-        self._reap()
-        self._admit_queued()
+        with jax.profiler.TraceAnnotation("exsample.reap"):
+            self._reap()
+        with jax.profiler.TraceAnnotation("exsample.admit"):
+            self._admit_queued()
         return merged
 
     def _reap(self) -> None:
@@ -544,6 +546,7 @@ class SearchService:
                     "lanes_padded": self.driver.stats["lanes_padded"],
                 },
                 "driver": dict(self.driver.stats),
+                "rounds": round_summary(self.driver.recent_rounds()),
                 "index": (
                     dict(self.index.stats, entries=len(self.index))
                     if self.index is not None else None

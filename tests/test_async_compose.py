@@ -335,8 +335,37 @@ def test_stats_keys_exist_at_construction(world):
         "slots": 0, "merges": 0, "reissues": 0, "duplicate_drops": 0,
         "merge_high_water": 0, "rounds": 0, "spilled": 0,
         "detector_invocations": 0, "cache_hits": 0, "index_hits": 0,
-        "lanes_issued": 0, "lanes_padded": 0,
+        "lanes_issued": 0, "lanes_padded": 0, "detector_lanes": 0,
     }
+
+
+@pytest.mark.parametrize("pump", ["synchronous", "threads"])
+def test_round_stamps_ordered_and_history_bounded(world, monkeypatch, pump):
+    """Every merged round leaves its ``time.monotonic`` stamps in order —
+    issued ≤ taken ≤ done ≤ merged — and the driver keeps only the latest
+    ``ROUND_HISTORY`` of them.  ``detector_lanes`` counts every lane of
+    every processed batch, padding included."""
+    _, chunks, det = world
+    monkeypatch.setattr(runtime, "ROUND_HISTORY", 3)
+    driver = AsyncMultiSearchDriver(
+        _fresh_multi(chunks, 3), chunks, det, cohorts=2, num_workers=2,
+        result_limits=5, max_steps=120, slots_per_batch=2,
+    )
+    if pump == "synchronous":
+        _pump_to_completion(driver)
+    else:
+        driver.run()
+    rounds = driver.recent_rounds()
+    assert driver.stats["merges"] > 3 and len(rounds) == 3
+    for issued, taken, done, merged in rounds:
+        assert 0.0 < issued <= taken <= done <= merged
+    assert [r[3] for r in rounds] == sorted(r[3] for r in rounds)
+    summary = runtime.round_summary(rounds)
+    assert summary["rounds"] == 3
+    assert summary["round_p50_s"] > 0.0 and summary["slot_wait_p90_s"] >= 0.0
+    assert runtime.round_summary([])["round_p50_s"] is None
+    processed = driver.stats["merges"] + driver.stats["duplicate_drops"]
+    assert driver.stats["detector_lanes"] == processed * 2 * 2
 
 
 def _failing_detector(det):

@@ -25,6 +25,7 @@ from repro.core import (
     run_search_scan,
     stack_carries,
 )
+from repro.core.plan import Execution, SearchPlan
 from repro.core.thompson import choose_chunks, choose_chunks_batched
 from repro.serve.batcher import (
     cache_insert,
@@ -364,3 +365,60 @@ def test_cache_masked_insert_is_noop():
     )
     hit, _ = cache_lookup(cache, jnp.asarray([3], jnp.int32))
     assert not bool(hit[0])
+
+
+# ---------------------------------------------------------------------------
+# What a profile and the stats can see of a round
+# ---------------------------------------------------------------------------
+
+STAGES = ("choose", "detect", "dedup_cache", "match", "update")
+
+
+@pytest.mark.parametrize("program", ["resident", "slots"])
+def test_lowered_round_programs_name_every_stage(world, program):
+    """Every stage of a multi-query round carries its ``jax.named_scope``
+    in the lowered program: the resident loop holds all five, the slot
+    runtime's process half all but ``choose`` (issued on the driver)."""
+    from repro.core.exsample import _search_multi_device, multi_round_choose
+    from repro.core.runtime import _process_slots
+
+    _, chunks, det = world
+    mc = _fresh_multi(chunks, jax.vmap(_qkey)(jnp.arange(2)))
+    struct = jax.eval_shape(det, _qkey(0), jnp.zeros((), jnp.int32))
+    cache = init_detection_cache(struct, 64)
+    if program == "resident":
+        lowered = _search_multi_device.lower(
+            mc, chunks, jnp.full((2,), 5, jnp.int32), cache, None,
+            detector=det, select=None, cohorts=3, method="exact",
+            max_steps=60, trace_every=0,
+        )
+        want = set(STAGES)
+    else:
+        choice = multi_round_choose(mc, chunks, cohorts=3, method="exact")
+        lowered = _process_slots.lower(
+            mc, cache, chunks, jnp.arange(2, dtype=jnp.int32),
+            jnp.ones((2,), bool), choice, detector=det, select=None,
+        )
+        want = set(STAGES) - {"choose"}
+    text = lowered.as_text(debug_info=True)
+    # a scope leads an op's location name, or sits inside its path
+    assert {s for s in STAGES if f'"{s}/' in text or f"/{s}/" in text} == want
+
+
+@pytest.mark.parametrize("cache", [None, -1])
+def test_detector_lanes_count_every_evaluated_lane(world, cache):
+    """``detector_lanes`` is rounds × Q × C on the resident lowering: the
+    detector runs on every lane, so the fresh calls are a share of it."""
+    _, chunks, det = world
+    plan = SearchPlan(
+        queries=3, result_limit=10, max_steps=400, cohorts=4,
+        execution=Execution(queries_axis=True, cache=cache),
+    )
+    res = plan.run(
+        _fresh_multi(chunks, jax.vmap(_qkey)(jnp.arange(3))), chunks,
+        detector=det,
+    )
+    st = res.stats
+    assert res.kind == "multi" and st.rounds > 0
+    assert st.detector_lanes == st.rounds * 3 * 4
+    assert 0 < st.detector_invocations <= st.detector_lanes

@@ -152,7 +152,7 @@ def test_uniform_stats_fields():
         "detector_invocations", "cache_hits", "rounds", "frames_sampled",
         "merge_high_water", "merge_overflow", "merges", "reissues",
         "duplicate_drops", "results_spilled", "matcher_inserted",
-        "matcher_capacity",
+        "matcher_capacity", "detector_lanes",
     ):
         assert hasattr(s, field)
     assert s.cache_hit_rate == 0.0
@@ -355,6 +355,56 @@ def test_plan_run_rejects_mismatched_carry():
     with pytest.raises(PlanError, match="select"):
         SearchPlan().run(single, chunks, detector=det,
                          select=lambda q, d: d.valid)
+
+
+@pytest.mark.parametrize("queries", [1, 2])
+def test_batch_run_marks_its_host_phases(tmp_path, queries):
+    """A plan's host wrapper leaves ``exsample.*`` spans a profile reads:
+    the set-up of the Q-axis carry and cache, the dispatch of the device
+    program, then the readback of its results, in that order."""
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.core import init_carry, init_carry_multi, init_matcher, \
+        init_state
+    from repro.sim import RepoSpec, generate
+    from repro.sim.oracle import oracle_detect
+
+    repo, chunks = generate(RepoSpec(
+        video_lengths=[2_000], num_instances=20, chunk_frames=500, seed=3))
+    det = lambda key, frame: oracle_detect(repo, frame, query_class=0)
+    state, matcher = init_state(chunks.length), init_matcher(max_results=64)
+    if queries == 1:
+        plan = SearchPlan(result_limit=3, max_steps=40, cohorts=2)
+        carry = init_carry(state, matcher, jax.random.PRNGKey(0))
+        want = ["exsample.dispatch", "exsample.readback"]
+    else:
+        plan = SearchPlan(
+            queries=2, result_limit=3, max_steps=40, cohorts=2,
+            execution=Execution(queries_axis=True, cache=-1),
+        )
+        carry = init_carry_multi(
+            state, matcher, jnp.stack([jax.random.PRNGKey(q) for q in (0, 1)])
+        )
+        want = ["exsample.prepare", "exsample.dispatch", "exsample.readback"]
+    plan.run(carry, chunks, detector=det)   # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        plan.run(carry, chunks, detector=det)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = sorted(
+        (e.start_ns, e.name)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("exsample.")
+    )
+    names = [n for _, n in spans]
+    # the packaging of the result reads back once more at the end
+    assert names[:len(want)] == want and names[-1] == "exsample.readback"
 
 
 def test_ensure_host_devices_forces_virtual_devices_only_on_cpu(monkeypatch):

@@ -124,3 +124,20 @@ def test_scan_trace_disabled_returns_final_only(world):
         _fresh(chunks), chunks, detector=det, result_limit=5, max_steps=200,
     )
     assert trace == [(int(final.step), int(final.results))]
+
+
+def test_lowered_scan_program_names_its_stages(world):
+    """The scanned program's ops carry the stage scopes a profile reads
+    (``jax.named_scope``); a single query has no dedup or cache stage."""
+    from repro.core.exsample import _search_scan_device
+
+    _, chunks, det = world
+    text = _search_scan_device.lower(
+        _fresh(chunks), chunks, jnp.asarray(10, jnp.int32), detector=det,
+        cohorts=4, method="exact", max_steps=100, trace_every=0,
+    ).as_text(debug_info=True)
+    stages = ("choose", "detect", "dedup_cache", "match", "update")
+    # a scope leads an op's location name, or sits inside its path
+    assert {s for s in stages if f'"{s}/' in text or f"/{s}/" in text} == {
+        "choose", "detect", "match", "update",
+    }

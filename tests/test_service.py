@@ -536,6 +536,51 @@ def test_per_tenant_stats_and_occupancy(world):
         assert s.results_spilled == len(t.row_obj.log)
 
 
+def test_rounds_leave_spans_and_stamps_a_profile_can_read(world, tmp_path):
+    """Under the profiler the pump thread's ``exsample.issue``/``merge``/
+    ``reap``/``admit`` spans and the worker's ``exsample.process`` spans
+    land on their own threads, each merged round's spans share its batch
+    id, and ``stats()`` reports the rounds' stamps."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    _, chunks, det = world
+    svc = _service(chunks, det)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.start(pump=True)
+        svc.submit("a", _plan(max_steps=80, limit=3), key=_qkey(0))
+        svc.submit("b", _plan(max_steps=80, limit=3), key=_qkey(1))
+        svc.drain(deadline_s=60.0)
+    finally:
+        svc.stop()
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    threads = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("exsample."):
+                    threads.setdefault((plane.name, i), []).append(
+                        (e.name, dict(e.stats)))
+    names = {t: {n for n, _ in evs} for t, evs in threads.items()}
+    pump = [t for t, n in names.items() if "exsample.merge" in n]
+    workers = [t for t, n in names.items() if "exsample.process" in n]
+    assert len(pump) == 1 and workers and pump[0] not in workers
+    assert {"exsample.issue", "exsample.merge", "exsample.reap",
+            "exsample.admit"} <= names[pump[0]]
+
+    def batches(span):
+        return sorted(a["batch"] for evs in threads.values()
+                      for n, a in evs if n == span)
+
+    assert batches("exsample.merge") == batches("exsample.process")
+    rounds = svc.stats()["rounds"]
+    assert rounds["rounds"] == len(batches("exsample.merge")) > 0
+    assert 0.0 < rounds["round_p50_s"] and 0.0 <= rounds["slot_wait_p90_s"]
+
+
 # ---------------------------------------------------------------------------
 # E2E: four tenants over the front onto one live driver
 # ---------------------------------------------------------------------------
