@@ -37,7 +37,7 @@ from repro.core.exsample import (
 )
 from repro.core.matcher import MatcherState, match_and_update, merge_matcher
 from repro.core.plan import PlanError, SearchPlan
-from repro.core.state import SamplerState
+from repro.core.state import SamplerState, decrement_homes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -692,10 +692,7 @@ def _search_multi_sharded_device(
                             (mres.d0 - d1_local).astype(dn1_q.dtype) * upd
                         )
                         dn_q = dn_q.at[cids_q[j]].add(upd)
-                        valid_home = mres.cross_home >= 0
-                        dn1_q = dn1_q.at[
-                            jnp.where(valid_home, mres.cross_home, 0)
-                        ].add(-valid_home.astype(dn1_q.dtype))
+                        dn1_q = decrement_homes(dn1_q, mres.cross_home)
                     return (
                         dn1_q, dn_q, mres.new_state,
                         lstep_q + live.astype(jnp.int32),

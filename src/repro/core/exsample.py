@@ -43,6 +43,7 @@ from repro.core.state import (
     SamplerState,
     apply_cross_chunk_decrement,
     apply_update,
+    decrement_homes,
 )
 
 if TYPE_CHECKING:  # avoid core ↔ sim import cycle; Detections is a pytree
@@ -107,12 +108,7 @@ def _process_frame(
     # a different chunk decrement *that* chunk's N¹, not this one's.
     d1_local = m.d1 - m.cross_chunk
     sampler = apply_update(carry.sampler, chunk_id, m.d0, d1_local)
-    valid_home = m.cross_home >= 0
-    sampler = apply_cross_chunk_decrement(
-        sampler,
-        jnp.where(valid_home, m.cross_home, 0),
-        valid_home.astype(sampler.n1.dtype),
-    )
+    sampler = apply_cross_chunk_decrement(sampler, m.cross_home)
     return dataclasses.replace(
         carry,
         sampler=sampler,
@@ -460,10 +456,7 @@ def _search_sharded_device(
                         (mres.d0 - d1_local).astype(delta_n1.dtype) * upd
                     )
                     delta_n = delta_n.at[cid].add(upd)
-                    valid_home = mres.cross_home >= 0
-                    delta_n1 = delta_n1.at[
-                        jnp.where(valid_home, mres.cross_home, 0)
-                    ].add(-valid_home.astype(delta_n1.dtype))
+                    delta_n1 = decrement_homes(delta_n1, mres.cross_home)
                 return (
                     delta_n1,
                     delta_n,
@@ -857,12 +850,7 @@ def multi_round_process(
                 sampler, cids[j], mres.d0, d1_local,
                 samples=act.astype(sampler.n.dtype),
             )
-            valid_home = mres.cross_home >= 0
-            sampler = apply_cross_chunk_decrement(
-                sampler,
-                jnp.where(valid_home, mres.cross_home, 0),
-                valid_home.astype(sampler.n1.dtype),
-            )
+            sampler = apply_cross_chunk_decrement(sampler, mres.cross_home)
             return sampler, mres.new_state, results + mres.d0
 
         return jax.lax.fori_loop(0, c, bodyj, (sampler, matcher, results))

@@ -102,7 +102,7 @@ class MatchResult(NamedTuple):
     d0: jax.Array           # i32[] — detections matching nothing (new results)
     d1: jax.Array           # i32[] — results transitioning seen-once → seen-twice
     cross_chunk: jax.Array  # i32[] — of d1, how many were first seen elsewhere (§3.4)
-    cross_home: jax.Array   # i32[R_pad] — home chunks to decrement (padded, -1 = none)
+    cross_home: jax.Array   # i32[D] — per detection, the home chunk to decrement (-1 = none)
     is_new: jax.Array       # bool[D] — per-detection novelty flag
     new_state: "MatcherState"
 
@@ -170,7 +170,17 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
     # N¹ must be decremented instead of this one's.
     crossed = went_twice & (state.chunk != chunk_id)
     cross_chunk = jnp.sum(crossed).astype(jnp.int32)
-    cross_home = jnp.where(crossed, state.chunk, -1)
+    # Only an entry some detection matched can go seen-once → seen-twice, so
+    # the homes travel one lane per detection (D), not per entry (R): lane d
+    # carries best[d]'s home iff d is the first detection matching it.
+    lane = jnp.arange(best.shape[0])
+    earlier_same = (
+        has_match[None, :]
+        & (best[None, :] == best[:, None])
+        & (lane[None, :] < lane[:, None])
+    )
+    first = has_match & ~jnp.any(earlier_same, axis=1)
+    cross_home = jnp.where(first & crossed[best], state.chunk[best], -1)
 
     # --- insert new results into ring buffer slots ---
     d0 = jnp.sum(is_new).astype(jnp.int32)

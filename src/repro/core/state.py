@@ -92,17 +92,24 @@ def apply_update(
     return dataclasses.replace(state, n1=n1, n=n)
 
 
+def decrement_homes(n1: jax.Array, home_chunk: jax.Array) -> jax.Array:
+    """``n1[home_chunk[d]] -= 1`` for every lane d; a lane whose home is -1
+    changes nothing.  A one-hot compare-and-sum over [D, M] rather than a
+    scatter: the TPU runs a scatter's updates one by one, and N¹ holds
+    whole numbers, so the sum gives the scatter's result bit for bit."""
+    home_chunk = jnp.atleast_1d(jnp.asarray(home_chunk))
+    hit = home_chunk[:, None] == jnp.arange(n1.shape[-1])
+    return n1 - jnp.sum(hit, axis=0).astype(n1.dtype)
+
+
 def apply_cross_chunk_decrement(
-    state: SamplerState, home_chunk: jax.Array, count: jax.Array
+    state: SamplerState, home_chunk: jax.Array
 ) -> SamplerState:
     """§3.4: a result first seen in chunk ``home_chunk`` was re-found in a
-    *different* chunk — its contribution leaves N¹ of the home chunk."""
+    *different* chunk — its contribution leaves N¹ of the home chunk
+    (home -1: no such result)."""
     with jax.named_scope("update"):
-        home_chunk = jnp.atleast_1d(jnp.asarray(home_chunk))
-        count = jnp.broadcast_to(
-            jnp.asarray(count, state.n1.dtype), home_chunk.shape
-        )
-        n1 = state.n1.at[home_chunk].add(-count)
+        n1 = decrement_homes(state.n1, home_chunk)
     return dataclasses.replace(state, n1=n1)
 
 

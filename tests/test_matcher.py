@@ -1,6 +1,8 @@
 """Matcher semantics: d0/d1 counting, dedup, cross-chunk, ring buffer."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.matcher import (
     init_matcher,
@@ -148,3 +150,66 @@ def test_merge_clobber_counts_live_dst_overwrites():
     assert not bool(stats.overflow)
     # slots [3, 0, 1): wraps onto dst's live entries 0 and 1
     assert int(stats.clobbered) == 2
+
+
+def _dense_cross_home(state, boxes, valid, video_id, frame_id, chunk_id):
+    """Per-entry §3.4 homes, ``where(crossed, chunk, -1)`` over all R ring
+    entries, recomputed in numpy from the pre-frame state, and how many
+    detections matched each entry."""
+    seen = np.asarray(state.times_seen)
+    occupied = seen > 0
+    iou = np.asarray(pairwise_iou(jnp.asarray(boxes), state.boxes))
+    eligible = (
+        occupied[None, :]
+        & (np.asarray(state.video)[None, :] == video_id)
+        & (np.abs(np.asarray(state.frame)[None, :] - frame_id) <= state.time_gate)
+        & (iou >= state.iou_thresh)
+    )
+    scores = np.where(eligible, iou, -1e9)
+    best = scores.argmax(axis=1)
+    has_match = eligible[np.arange(len(best)), best] & valid
+    bump = np.zeros_like(seen)
+    np.add.at(bump, best, has_match.astype(seen.dtype))
+    went_twice = occupied & (seen == 1) & (seen + bump >= 2)
+    chunk = np.asarray(state.chunk)
+    return np.where(went_twice & (chunk != chunk_id), chunk, -1), bump
+
+
+@pytest.mark.parametrize("capacity", [16, 256])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_cross_home_per_detection_matches_dense_entries(seed, capacity):
+    """§3.4 homes travel one lane per detection: over random frames that
+    repeat a few box positions across chunks, the [D] lanes hold exactly
+    the homes of the dense per-entry rule, with the same multiplicity."""
+    rng = np.random.default_rng(seed)
+    d_n, num_chunks, frames = 16, 4, 300
+    anchors = np.asarray([_box(0.1 + 0.2 * i, 0.1 + 0.15 * (i % 3)) for i in range(5)])
+    step = jax.jit(match_and_update)
+    m = init_matcher(max_results=capacity, time_gate=300)
+    crossings = doubled = 0
+    for t in range(frames):
+        pick = rng.integers(0, len(anchors), d_n)
+        boxes = (anchors[pick] + rng.uniform(-0.005, 0.005, (d_n, 4))).astype(np.float32)
+        valid = rng.random(d_n) < 0.3
+        video_id, chunk_id = int(rng.integers(0, 2)), int(rng.integers(0, num_chunks))
+        frame_id = 50 * t
+        dense, bump = _dense_cross_home(
+            m, boxes, valid, video_id, frame_id, chunk_id
+        )
+        r = step(
+            m, jnp.asarray(boxes), jnp.zeros((d_n, 8), jnp.float32),
+            jnp.asarray(valid), jnp.int32(video_id), jnp.int32(frame_id),
+            jnp.int32(chunk_id),
+        )
+        homes = np.asarray(r.cross_home)
+        assert homes.shape == (d_n,)
+        assert int((homes >= 0).sum()) == int(r.cross_chunk)
+        np.testing.assert_array_equal(
+            np.bincount(homes[homes >= 0], minlength=num_chunks),
+            np.bincount(dense[dense >= 0], minlength=num_chunks),
+        )
+        crossings += int((dense >= 0).sum())
+        doubled += int(((dense >= 0) & (bump >= 2)).sum())
+        m = r.new_state
+    assert crossings > 0
+    assert doubled > 0   # an entry two detections moved crossed: one lane

@@ -151,6 +151,54 @@ def test_multi_q4_each_query_matches_sequential(world, cache):
     assert stats["detector_invocations"] <= stats["frames_sampled"]
 
 
+def test_multi_cross_chunk_decrements_match_single_query_path():
+    """§3.4 through the Q-batched fold with a ring (R = 1,024) far wider
+    than a frame's D = 16 detection lanes: on instances that span chunks,
+    each query's final N¹ and n equal, bit for bit, those of its own
+    single-query ``_process_frame`` run (``exsample_step``, one frame a
+    step), and that run moved at least one result's first sighting out of
+    another chunk."""
+    from repro.core import exsample_step
+
+    spec = RepoSpec(
+        video_lengths=[3_000] * 2, num_instances=20, chunk_frames=500,
+        duration_mu=6.5, duration_sigma=0.3, num_classes=1, seed=5,
+    )
+    repo, chunks = generate(spec)
+    det = lambda key, frame: oracle_detect(repo, frame, query_class=0)
+    matcher = init_matcher(max_results=1024, time_gate=10**9, feat_thresh=0.9)
+    q_n, limit, max_steps = 3, 20, 120
+    keys = jnp.stack([_qkey(q) for q in range(q_n)])
+    multi, _, _ = run_search_multi(
+        init_carry_multi(init_state(chunks.length), matcher, keys), chunks,
+        detector=det, result_limits=limit, max_steps=max_steps,
+    )
+    crossings = 0
+    for q in range(q_n):
+        c = init_carry(init_state(chunks.length), matcher, keys[q])
+        while (
+            int(c.results) < limit and int(c.step) < max_steps
+            and not bool(jnp.all(c.sampler.exhausted()))
+        ):
+            prev = c
+            c = exsample_step(c, chunks, detector=det)
+            cid = int(np.argmax(np.asarray(c.sampler.n - prev.sampler.n)))
+            seen0 = np.asarray(prev.matcher.times_seen)
+            seen1 = np.asarray(c.matcher.times_seen)
+            home = np.asarray(prev.matcher.chunk)
+            crossings += int(((seen0 == 1) & (seen1 >= 2) & (home != cid)).sum())
+        assert (int(c.step), int(c.results)) == (
+            int(multi.step[q]), int(multi.results[q])
+        ), f"query {q} diverged"
+        np.testing.assert_array_equal(
+            np.asarray(c.sampler.n1), np.asarray(multi.sampler.n1[q])
+        )
+        np.testing.assert_array_equal(
+            np.asarray(c.sampler.n), np.asarray(multi.sampler.n[q])
+        )
+    assert crossings > 0
+
+
 def test_stack_carries_matches_init_multi(world):
     _, chunks, _ = world
     keys = [_qkey(q) for q in range(3)]

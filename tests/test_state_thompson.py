@@ -58,8 +58,14 @@ def test_merge_equals_sequential():
 
 def test_cross_chunk_decrement():
     s = apply_update(_state(), 0, 2, 0)
-    s = apply_cross_chunk_decrement(s, jnp.array([0]), jnp.array([1.0]))
+    s = apply_cross_chunk_decrement(s, jnp.array([0]))
     assert float(s.n1[0]) == 1.0
+    # one lane per detection: -1 lanes carry no home, a repeated home
+    # loses one a lane
+    s = apply_update(s, 1, 3, 0)
+    s = apply_cross_chunk_decrement(s, jnp.array([1, -1, 1, -1]))
+    assert s.n1[:2].tolist() == [1.0, 1.0]
+    assert float(jnp.sum(s.n1)) == 2.0
 
 
 def test_exhausted_chunks_never_chosen():
