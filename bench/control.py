@@ -35,13 +35,18 @@ def control_pairs(cfg: dict, mix: dict, seed: int):
         classes, tseeds = load.tenant_set(k, ncls, mix["zipf_s"], seed)
         keys = [np.asarray(jax.random.PRNGKey(int(t))) for t in tseeds]
         cohorts, method, all_classes = mix["service"]["cohorts"], "exact", True
+        shards = sync_every = 1
     else:
         root = jax.random.PRNGKey(load.POOL_KEY)
         classes = [i % ncls for i in range(k)]
         keys = [np.asarray(jax.random.fold_in(
             root, load.pool_index(seed, mix["pool_per_class"], ncls, i))) for i in range(k)]
         cohorts = plan["cohorts"]
-        method = "exact" if plan.get("method", "auto") == "auto" else plan["method"]
+        ex = plan.get("execution", {})
+        shards, sync_every = ex.get("shards", 1), ex.get("sync_every", 1)
+        method = plan.get("method", "auto")
+        if method == "auto":   # the mesh path is Wilson-Hilferty only (section 8)
+            method = "exact" if shards == 1 else "wilson_hilferty"
         all_classes = mix["detector"] == "all_classes"
     return [
         reference.Query(
@@ -49,7 +54,8 @@ def control_pairs(cfg: dict, mix: dict, seed: int):
             result_limit=int(plan["result_limit"]), max_steps=int(plan["max_steps"]),
             method=method, all_classes=all_classes,
             max_dets=cfg["detector"]["max_dets"], iou_thresh=m["iou_thresh"],
-            time_gate=m["time_gate"], alpha0=s["alpha0"], beta0=s["beta0"])
+            time_gate=m["time_gate"], alpha0=s["alpha0"], beta0=s["beta0"],
+            shards=shards, sync_every=sync_every)
         for i in range(k)
     ]
 

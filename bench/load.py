@@ -203,7 +203,7 @@ class BatchLoad:
         keys = np.asarray(self._fold(self.window_key, np.asarray(
             [self._key_index(p["index"]) for p in picked], np.uint32)))
         m, det = self.cfg["matcher"], self.cfg["detector"]
-        s = self.cfg["sampler"]
+        s, ex = self.cfg["sampler"], self.plan.execution
         return [
             (reference.Query(
                 key=keys[j], query_class=p["cls"], cohorts=self.plan.cohorts,
@@ -211,7 +211,8 @@ class BatchLoad:
                 max_steps=self.plan.max_steps, method=self.method,
                 all_classes=self.all_classes, max_dets=det["max_dets"],
                 iou_thresh=m["iou_thresh"], time_gate=m["time_gate"],
-                alpha0=s["alpha0"], beta0=s["beta0"]), p)
+                alpha0=s["alpha0"], beta0=s["beta0"],
+                shards=ex.shards, sync_every=ex.sync_every), p)
             for j, p in enumerate(picked)
         ]
 

@@ -17,12 +17,20 @@ marks a query *ambiguous* at the first such step and stops: an ambiguous
 query is left out of the comparison rather than guessed at.  ``TIE`` is
 that rounding margin.
 
+A query with ``shards > 1`` is replayed under the merge schedule of
+DESIGN.md section 8 instead (``_replay_mesh``): each shard draws its own
+normals for its slice of the chunks, every shard folds its share of the
+round's cohorts against its own copy of the result memory, and every
+``sync_every`` rounds the statistics add up, the duplicate d1
+decrements are added back and the copies merge.
+
 ``precision="bfloat16"`` computes the Thompson choice in bfloat16 (its
 statistics, the Gamma shapes or the normals, and the scores): the
 control that the comparison has to reject.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from functools import partial
 
@@ -49,6 +57,8 @@ class Query:
     time_gate: int = 900
     alpha0: float = 0.1
     beta0: float = 1.0
+    shards: int = 1          # chips the statistics are sharded over (section 8)
+    sync_every: int = 1      # rounds between two merges of the shards
 
 
 @dataclasses.dataclass
@@ -70,6 +80,19 @@ def _draws(key, alpha, *, cohorts: int, method: str):
     else:
         d = jax.random.normal(k_choice, shape, alpha.dtype)
     return key_next, d
+
+
+@partial(jax.jit, static_argnames=("cohorts", "shards"))
+def _draws_sharded(key, alpha, *, cohorts: int, shards: int):
+    """(next key, normals [C, M]) of a round on ``shards`` shards: shard s
+    draws ``[C, M/S]`` for its own slice of the chunks from
+    ``fold_in(k_choice, s)``, and the slices lie side by side in shard
+    order, so chunk c's column is its owner's draw."""
+    key_next, k_choice, _ = jax.random.split(key, 3)
+    local = alpha.shape[0] // shards
+    d = [jax.random.normal(jax.random.fold_in(k_choice, s), (cohorts, local), alpha.dtype)
+         for s in range(shards)]
+    return key_next, jnp.concatenate(d, axis=1)
 
 
 def _bit_reverse(x: int, bits: int) -> int:
@@ -189,6 +212,8 @@ def _match(q: Query, mem: _Results, boxes, video: int, f: int, c: int):
 
 def replay(a, q: Query, precision: str = "float32") -> Outcome:
     """Run query ``q`` over repository ``a`` (``data.repository.Arrays``)."""
+    if q.shards > 1:
+        return _replay_mesh(a, q, precision)
     m = a.num_chunks
     frames = a.chunk_length.astype(np.int64)
     n1 = np.zeros(m, np.int64)
@@ -232,6 +257,133 @@ def replay(a, q: Query, precision: str = "float32") -> Outcome:
                 n1[h] -= 1
             results += d0
             step += 1
+    return out()
+
+
+def _add_back(snap: _Results, mems, size: int) -> np.ndarray:
+    """N1 to give back per chunk at a merge: where k shards each took one
+    snapshot entry from seen once to seen twice, its home chunk was
+    decremented k times for one transition, so k - 1 go back."""
+    r0 = snap.seen.size
+    k = sum(((snap.seen == 1) & (mem.seen[:r0] >= 2)).astype(np.int64) for mem in mems)
+    back = np.zeros(size, np.int64)
+    np.add.at(back, snap.chunk[k > 0], k[k > 0] - 1)
+    return back
+
+
+def _merge(snap: _Results, mems) -> _Results:
+    """The shards' copies merged: the snapshot's entries with every copy's
+    sightings since the snapshot added up, then each copy's new entries,
+    in shard order.  Two shards that inserted one object both keep it."""
+    r0 = snap.seen.size
+    out = _Results()
+    seen = snap.seen + sum(mem.seen[:r0] - snap.seen for mem in mems)
+    out.seen = np.concatenate([seen] + [mem.seen[r0:] for mem in mems])
+    for name in ("boxes", "video", "frame", "chunk"):
+        setattr(out, name, np.concatenate(
+            [getattr(snap, name)] + [getattr(mem, name)[r0:] for mem in mems]))
+    return out
+
+
+def _rival(q: Query, n1, n, draws, s, choice, live) -> bool:
+    """Whether a live cohort's winner has a rival within ``TIE`` whose
+    inputs differ from its own.  A rival with the same float32 statistics
+    and the same draw scores the same in any precision, and both the
+    program's argmax and the replay's take the lower index: float32
+    normals lie on a grid in their tails, so among thousands of chunks
+    that are yet unsampled such exact ties are common and decided."""
+    rows = np.arange(s.shape[0])
+    top = s[rows, choice]
+    close = s >= (top - TIE * np.abs(top))[:, None]
+    alpha, beta = _params(q, n1, n, np.float32)
+    same = ((draws == draws[rows, choice][:, None]) & (alpha == alpha[choice][:, None])
+            & (beta == beta[choice][:, None]))
+    return bool(np.any(close[live] & ~same[live]))
+
+
+def _replay_mesh(a, q: Query, precision: str) -> Outcome:
+    """``replay`` under the section 8 merge schedule on ``q.shards`` shards.
+
+    The chunks are padded with exhausted ones to a multiple of the shard
+    count; shard s owns chunks ``[s·M/S, (s+1)·M/S)`` and folds cohorts
+    ``[s·C/S, (s+1)·C/S)`` of each round.  Within a window of
+    ``q.sync_every`` rounds a chunk's statistics, as its owner sees them,
+    are the window's start plus the owner's own updates; a pick's random+
+    rank is its owner's n plus the earlier picks of the window made on
+    other shards plus its occurrence within the round.  The query stops,
+    at the end of a window, as ``replay`` stops at the end of a round.
+    The global choice is the argmax of every shard's scores side by side,
+    so a choice is undecided by ``_rival`` over the whole row.
+    """
+    shards, m = q.shards, a.num_chunks
+    per = q.cohorts // shards
+    mp = -(-m // shards) * shards
+    owner = np.arange(mp) // (mp // shards)
+    cols = np.arange(mp)
+    frames = np.zeros(mp, np.int64)
+    frames[:m] = a.chunk_length
+    n1 = np.zeros(mp, np.int64)
+    n = np.zeros(mp, np.int64)
+    n[m:] = 1                      # padding: sampled once, no frames: exhausted
+    step = results = 0
+    snap = _Results()
+    key = jnp.asarray(q.key, jnp.uint32)
+    if precision == "bfloat16":
+        import ml_dtypes
+
+        pdt = ml_dtypes.bfloat16
+    else:
+        pdt = np.float32
+
+    def out(why=""):
+        return Outcome(step, results, n[:m].copy(), n1[:m].copy(), why)
+
+    while results < q.result_limit and step < q.max_steps and not np.all(n[:m] >= frames[:m]):
+        dn1 = np.zeros((shards, mp), np.int64)
+        dn = np.zeros((shards, mp), np.int64)
+        foreign = np.zeros(mp, np.int64)
+        mems = [copy.copy(snap) for _ in range(shards)]
+        w_step = w_results = 0
+        for _ in range(q.sync_every):
+            vn1, vn = n1 + dn1[owner, cols], n + dn[owner, cols]
+            alpha = jnp.asarray(_params(q, vn1, vn, pdt)[0])
+            key, draws = _draws_sharded(key, alpha, cohorts=q.cohorts, shards=shards)
+            draws = np.asarray(draws)
+            s = _scores(q, vn1, vn, frames, draws, precision)
+            choice = np.argmax(s, axis=1)
+            live = np.isfinite(s[np.arange(q.cohorts), choice])
+            if precision == "float32" and _rival(q, vn1, vn, draws, s, choice, live):
+                return out(f"Thompson draws within rounding in round at step {step}")
+            rank = np.zeros(q.cohorts, np.int64)
+            for g, c in enumerate(choice):
+                occ = int(np.sum(live[:g] & (choice[:g] == c)))
+                rank[g] = vn[c] + foreign[c] + occ
+            for g, c in enumerate(choice):
+                if live[g] and g // per != owner[c]:
+                    foreign[c] += 1
+            for sh in range(shards):
+                for g in range(sh * per, (sh + 1) * per):
+                    if not live[g]:
+                        continue
+                    c = int(choice[g])
+                    f = randomplus_frame(a, c, int(rank[g]))
+                    boxes = _detect(a, q, f)
+                    d0, d1_local, homes, why = _match(
+                        q, mems[sh], boxes, int(a.chunk_video[c]), f, c
+                    )
+                    if why:
+                        return out(why)
+                    dn1[sh, c] += d0 - d1_local
+                    dn[sh, c] += 1
+                    for h in homes:
+                        dn1[sh, h] -= 1
+                    w_results += d0
+                    w_step += 1
+        n1 = n1 + dn1.sum(axis=0) + _add_back(snap, mems, mp)
+        n = n + dn.sum(axis=0)
+        snap = _merge(snap, mems)
+        step += w_step
+        results += w_results
     return out()
 
 

@@ -48,3 +48,42 @@ def test_thompson_work_and_roofline_share():
     assert mod.value(ctx) == pytest.approx(50.0)
     red["ops_n"] = {}
     assert mod.value(ctx) is None   # nothing to read: no number, never 0
+
+
+def test_collective_exposed_share_counts_only_what_nothing_overlaps():
+    from bench import trace
+
+    ms = 1e6
+    # op names as a v5e 2x2 trace of the mesh path gives them
+    gather = "%all-gather.70 = f32[4,8,48]{2,1,0:T(8,128)S(1)} all-gather(f32[1,8,48] %x)"
+    a2a = "%all_to_all.210 = pred[4,1,96]{2,1,0} all-to-all(pred[4,1,96] %y), channel_id=1"
+    loop = "%while.2 = (s32[]) while((s32[]) %t), condition=%c, body=%b"
+    ev = {
+        "devices": {
+            # overlapped by a fusion: counts nothing
+            "/device:TPU:0": [["fusion.1", 0, 4 * ms], [gather, 1 * ms, 3 * ms]],
+            # alone for 2 ms; the loop around it is not another op
+            "/device:TPU:1": [[loop, 0, 10 * ms], [a2a, 2 * ms, 4 * ms]],
+            # half overlapped: 1 ms alone
+            "/device:TPU:2": [["fusion.2", 0, 3 * ms], [gather, 2 * ms, 4 * ms]],
+            # no collective here
+            "/device:TPU:3": [["fusion.3", 0, 5 * ms]],
+        },
+        "spans": [["bench.window", 0, 10 * ms]],
+    }
+    mod = harness.metric_module("collective_exposed_share")
+    red = trace.reduce(ev)
+    # (0 + 2 + 1 + 0) ms over 4 devices, of a 10 ms window
+    assert mod.value({"trace": red}) == pytest.approx(100.0 * 0.75 / 10)
+    ev["devices"]["/device:TPU:1"] = [[loop, 0, 10 * ms]]
+    ev["devices"]["/device:TPU:2"] = [["fusion.2", 0, 3 * ms]]
+    assert mod.value({"trace": trace.reduce(ev)}) == 0.0      # all overlapped
+    # a device whose ops are named only by their region says nothing
+    ev["devices"]["/device:TPU:1"] = [[a2a, 2 * ms, 4 * ms], ["region.730", 5 * ms, 6 * ms]]
+    assert mod.value({"trace": trace.reduce(ev)}) == 0.0
+    ev["devices"]["/device:TPU:1"] = [[a2a, 2 * ms, 4 * ms]]
+    assert mod.value({"trace": trace.reduce(ev)}) == pytest.approx(100.0 * 0.5 / 10)
+    del ev["devices"]["/device:TPU:0"]
+    ev["devices"]["/device:TPU:1"] = [[loop, 0, 10 * ms]]
+    assert mod.value({"trace": trace.reduce(ev)}) is None     # nothing to read
+    assert mod.value({"trace": None}) is None

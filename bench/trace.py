@@ -24,6 +24,9 @@ WINDOW_SPAN = "bench.window"
 # counted in busy time, left out of the op totals
 CONTAINER = re.compile(r" (while|conditional|call)\(")
 NAME_CHARS = 160   # of an op's HLO text, kept in the breakdown
+# an op event named only by its region, with no HLO text: on a v5e 2x2
+# trace of the mesh path, device 0 names most of its loop ops so
+UNNAMED = re.compile(r"^region\.\d+$")
 
 
 def xplane_file(log_dir: str) -> str:
@@ -123,7 +126,52 @@ def reduce(ev: dict, top: int = 10) -> dict:
                        for k, v in ops.most_common(top)],
         "idle_gaps": [[k, v] for k, v in gaps_by_span.most_common(top)],
         "devices": len(busy),
+        "window": [lo, hi],
+        "events": ev["devices"],
     }
+
+
+def _length(intervals) -> float:
+    return sum(e - s for s, e in intervals)
+
+
+def _overlap(a, b) -> float:
+    """Length of the intersection of two sorted disjoint interval lists."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        total += max(min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]), 0.0)
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def exposed_seconds(red: dict, pattern: str):
+    """Seconds of the traced window (averaged over devices) in which an op
+    whose name matches ``pattern`` runs on a device and no other op does;
+    loops that contain other ops count as neither.  A device whose trace
+    leaves ops in the window unnamed cannot tell which match, and is left
+    out of the average.  None where no op matches: nothing to read."""
+    rx = re.compile(pattern)
+    lo, hi = red["window"]
+    total, read, found = 0.0, 0, False
+    for evs in red["events"].values():
+        inside = [x for x in evs if x[2] > lo and x[1] < hi]
+        if any(UNNAMED.match(name) for name, _, _ in inside):
+            continue
+        mine, other = [], []
+        for name, s, e in inside:
+            if CONTAINER.search(name):
+                continue
+            (mine if rx.search(name) else other).append([s, e])
+        found = found or bool(mine)
+        mine = _union(_clip(mine, lo, hi))
+        other = _union(_clip(other, lo, hi))
+        total += (_length(mine) - _overlap(mine, other)) * 1e-9
+        read += 1
+    return total / read if found else None
 
 
 def op_seconds(red: dict, pattern: str) -> float:
