@@ -148,9 +148,10 @@ def local_cohort_winners_batched(
     )[..., 0]                                                   # [Q, C]
     global_idx = shard_id * local_m + local_best
     local_n = jnp.take_along_axis(n_l, local_best, axis=-1)
-    all_scores = jax.lax.all_gather(local_score, axis)          # [S, Q, C]
-    all_idx = jax.lax.all_gather(global_idx, axis)
-    all_n = jax.lax.all_gather(local_n, axis)
+    with jax.named_scope("collective"):
+        all_scores = jax.lax.all_gather(local_score, axis)      # [S, Q, C]
+        all_idx = jax.lax.all_gather(global_idx, axis)
+        all_n = jax.lax.all_gather(local_n, axis)
     win = jnp.argmax(all_scores, axis=0)                        # [Q, C]
     pick = lambda a: jnp.take_along_axis(a, win[None], axis=0)[0]
     return (

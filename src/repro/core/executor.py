@@ -434,7 +434,7 @@ class LoweredPlan:
     jax.jit,
     static_argnames=(
         "mesh", "axis", "detector", "select", "cohorts", "sync_every",
-        "max_steps", "alpha0", "beta0",
+        "max_steps", "alpha0", "beta0", "empty",
     ),
 )
 def _search_multi_sharded_device(
@@ -465,6 +465,7 @@ def _search_multi_sharded_device(
     max_steps: int,
     alpha0: float,
     beta0: float,
+    empty=None,
 ):
     """Mesh-resident multi-query loop: the §9 Q-axis round (per-query
     Thompson choice, cross-query dedup + detection cache, per-query
@@ -480,7 +481,11 @@ def _search_multi_sharded_device(
     ``[s·C/S, (s+1)·C/S)`` of EVERY query, whose Q·C/S frames dedup — and
     miss-check the HASH-SHARDED :class:`DetectionCache` (frame f homed on
     shard ``f % S``, DESIGN.md §14; lookups and inserts route over
-    ``all_to_all``) — into one detector batch.  Per-query liveness is evaluated at sync boundaries (the §8
+    ``all_to_all``) — into one detector batch.  With no ``cache`` and
+    ``empty = (layout, slots)`` each shard builds its own empty part of
+    the cache (``slots`` of them) inside the program, so no device ever
+    holds the whole logical cache.  Per-query liveness is evaluated at
+    sync boundaries (the §8
     overshoot caveat, per query); a finished query freezes exactly like the
     §9 masking contract (key/step/sampler gated, slots leave the dedup).
 
@@ -494,6 +499,7 @@ def _search_multi_sharded_device(
     from repro.core.distributed import local_cohort_winners_batched
     from repro.serve.batcher import (
         dedup_first_index,
+        empty_cache,
         sharded_cache_insert,
         sharded_cache_lookup,
     )
@@ -501,6 +507,7 @@ def _search_multi_sharded_device(
 
     q_n = step0.shape[0]
     num_shards = mesh.shape[axis]
+    has_cache = cache is not None or empty is not None
     m = n1.shape[-1]
     local_m = m // num_shards
     per_shard = cohorts // num_shards
@@ -512,6 +519,9 @@ def _search_multi_sharded_device(
     def shard_fn(keys, step0, results0, n1_l, n_l, frames_l, matcher0,
                  chks, rlimits, cache0, wtag, wlimit):
         shard_id = jax.lax.axis_index(axis)
+        if cache0 is None and empty is not None:
+            with jax.named_scope("cache_init"):
+                cache0 = empty_cache(*empty, shards=num_shards)
         fdt = n_l.dtype
         qi = jnp.arange(q_n, dtype=jnp.int32)
         my_slice = lambda full: jax.lax.dynamic_slice(
@@ -522,7 +532,8 @@ def _search_multi_sharded_device(
             exh_l = jnp.all(
                 n_loc >= frames_l.astype(fdt), axis=-1
             ).astype(jnp.int32)                                  # [Q]
-            exhausted = jax.lax.psum(exh_l, axis) == num_shards
+            with jax.named_scope("collective"):
+                exhausted = jax.lax.psum(exh_l, axis) == num_shards
             return (results < rlimits) & (step < max_steps) & ~exhausted
 
         def one_round(base_n1, base_n, active, rstate):
@@ -587,40 +598,41 @@ def _search_multi_sharded_device(
             is_rep = (first_idx == jnp.arange(b, dtype=jnp.int32)) & flat_live
             with jax.named_scope("detect"):
                 fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
-            if cache is not None:
+            if has_cache:
                 # Hash-sharded cache routing (DESIGN.md §14): frame f lives
                 # ONLY on shard f % S.  Requests are free — the replicated
                 # [Q, C] frame matrix lets every home shard compute every
                 # requester's probes locally — so one round costs two
-                # all_to_alls out (hit flags + values, rows = requesters)
-                # and two back in (routed fresh inserts).  Per-link volume
-                # matches the all-gathers this replaces, but each shard now
-                # stores and scans 1/S of one logical cache instead of a
-                # full replica.
+                # all_to_alls out (hit flags + packed rows, rows =
+                # requesters) and two back in (routed fresh inserts).  Each
+                # shard stores and scans 1/S of one logical cache; only the
+                # [b] rows a shard consumes are unpacked.
+                layout = cache.layout
                 req = jnp.where(live_c, fids_all, -1)            # [Q, C]
                 req = req.reshape(q_n, num_shards, per_shard)
                 req = req.transpose(1, 0, 2).reshape(num_shards, b)
-                r_hit, r_vals = sharded_cache_lookup(
+                r_hit, r_rows = sharded_cache_lookup(
                     cache, req, shard_id, num_shards
                 )                                                # [S, b]
-                a_hit = jax.lax.all_to_all(r_hit, axis, 0, 0)
-                a_vals = jax.tree.map(
-                    lambda x: jax.lax.all_to_all(x, axis, 0, 0), r_vals
-                )
+                with jax.named_scope("collective"):
+                    a_hit = jax.lax.all_to_all(r_hit, axis, 0, 0)
+                    a_rows = jax.lax.all_to_all(r_rows, axis, 0, 0)
                 # row h of a_* is home shard h's answer for MY b slots
                 home = jnp.where(
                     flat_frames >= 0, flat_frames % num_shards, 0
                 )
                 bi = jnp.arange(b, dtype=jnp.int32)
                 hit = a_hit[home, bi]
-                cached = jax.tree.map(lambda x: x[home, bi], a_vals)
-                expand = lambda mk, x: mk.reshape(
-                    mk.shape + (1,) * (x.ndim - 1)
-                )
-                resolved = jax.tree.map(
-                    lambda cv, fv: jnp.where(expand(hit, fv), cv, fv),
-                    cached, fresh,
-                )
+                with jax.named_scope("dedup_cache"):
+                    cached = layout.unpack(a_rows[home, bi])
+                    expand = lambda mk, x: mk.reshape(
+                        mk.shape + (1,) * (x.ndim - 1)
+                    )
+                    resolved = jax.tree.map(
+                        lambda cv, fv: jnp.where(expand(hit, fv), cv, fv),
+                        cached, fresh,
+                    )
+                    fresh_rows = layout.pack(fresh)              # [b, W]
                 need = is_rep & ~hit
                 # route fresh detections to their home shards; flattening
                 # the received rows requester-major reproduces the exact
@@ -632,23 +644,18 @@ def _search_multi_sharded_device(
                     (home[None, :] == dest) & need[None, :],
                     flat_frames[None, :], -1,
                 )                                                # [S, b]
-                ins_vals = jax.tree.map(
-                    lambda x: jnp.broadcast_to(
-                        x[None], (num_shards,) + x.shape
-                    ),
-                    fresh,
+                ins_rows = jnp.broadcast_to(
+                    fresh_rows[None], (num_shards,) + fresh_rows.shape
                 )
-                g_frames = jax.lax.all_to_all(
-                    ins_frames, axis, 0, 0
-                ).reshape(-1)
-                g_vals = jax.tree.map(
-                    lambda x: jax.lax.all_to_all(x, axis, 0, 0).reshape(
-                        (-1,) + x.shape[2:]
-                    ),
-                    ins_vals,
-                )
+                with jax.named_scope("collective"):
+                    g_frames = jax.lax.all_to_all(
+                        ins_frames, axis, 0, 0
+                    ).reshape(-1)
+                    g_rows = jax.lax.all_to_all(
+                        ins_rows, axis, 0, 0
+                    ).reshape(-1, layout.width)
                 cache = sharded_cache_insert(
-                    cache, g_frames, g_vals, g_frames >= 0,
+                    cache, g_frames, g_rows, g_frames >= 0,
                     shard_id, num_shards,
                 )
             else:
@@ -738,13 +745,14 @@ def _search_multi_sharded_device(
                     rst,
                 )
             # ---- sampler sync: one [Q, M] psum (exact, additive) ----
-            n1_l = n1_l + my_slice(jax.lax.psum(dn1, axis))
-            n_l = n_l + my_slice(jax.lax.psum(dn, axis))
-            # ---- matcher sync: per-query §8 fold + exact k−1 add-back of
-            # cross-shard duplicate d₁ decrements ----
-            stacked = jax.tree.map(
-                lambda x: jax.lax.all_gather(x, axis), matcher
-            )                                                    # [S, Q, ..]
+            with jax.named_scope("collective"):
+                n1_l = n1_l + my_slice(jax.lax.psum(dn1, axis))
+                n_l = n_l + my_slice(jax.lax.psum(dn, axis))
+                # ---- matcher sync: per-query §8 fold + exact k−1
+                # add-back of cross-shard duplicate d₁ decrements ----
+                stacked = jax.tree.map(
+                    lambda x: jax.lax.all_gather(x, axis), matcher
+                )                                                # [S, Q, ..]
             same_e = (stacked.video == snap.video[None]) & (
                 stacked.frame == snap.frame[None]
             )
@@ -773,8 +781,9 @@ def _search_multi_sharded_device(
             hw = jnp.maximum(hw, jnp.max(inserted))
             ov = ov | jnp.any(inserted >= cap_r)
             # ---- counters / per-query trace / continue flag ----
-            step = step + jax.lax.psum(lstep, axis)
-            results = results + jax.lax.psum(lres, axis)
+            with jax.named_scope("collective"):
+                step = step + jax.lax.psum(lstep, axis)
+                results = results + jax.lax.psum(lres, axis)
             entry = jnp.stack([step, results], axis=-1)          # [Q, 2]
             idx = jnp.where(active, tn, cap)
             buf = jax.vmap(lambda bq, i, e: bq.at[i].set(e, mode="drop"))(
@@ -811,17 +820,16 @@ def _search_multi_sharded_device(
             buf, idx, jnp.stack([step, results], axis=-1)
         )
         tn = jnp.clip(tn, 1, cap)
-        calls = jax.lax.psum(wcalls, axis)
-        hits = jax.lax.psum(whits, axis)
-        ihits = jax.lax.psum(wihits, axis)
+        with jax.named_scope("collective"):
+            calls = jax.lax.psum(wcalls, axis)
+            hits = jax.lax.psum(whits, axis)
+            ihits = jax.lax.psum(wihits, axis)
         outs = (n1_l, n_l, matcher, keys, step, results, buf, tn, calls,
                 hits, ihits, hw, ov, windows)
         if cache_f is not None:
             # each shard returns only its 1/S of the hash-sharded logical
-            # cache; concatenating over the sharded out-spec reproduces
-            # the global shard-major layout, and the host wrapper's
-            # unshard_cache_layout turns it back into the direct-mapped
-            # cache the index publish path understands
+            # cache; concatenating over the sharded out-spec gives the
+            # global shard-major layout, which stays split over the mesh
             outs = outs + (cache_f,)
         return outs
 
@@ -831,7 +839,7 @@ def _search_multi_sharded_device(
         rep,
     )
     cache_spec = rep if cache is None else sh1
-    if cache is not None:
+    if has_cache:
         out_specs = out_specs + (sh1,)
     return jax.shard_map(
         shard_fn,
@@ -842,6 +850,29 @@ def _search_multi_sharded_device(
         check_vma=False,
     )(keys, step0, results0, n1, n, frames, matcher, chunks, result_limits,
       cache, warm_tag, window_limit)
+
+
+def _place_cache(cache, mesh, axis: str):
+    """``cache`` in the hash-sharded layout of ``mesh``'s ``axis``, each
+    shard's part placed on its own device.  A cache already in that layout
+    (a resumed window's ``final_cache``) is returned as it is; any other is
+    brought to the host, permuted there and handed out shard by shard, so
+    no device ever holds the whole of it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.serve.batcher import host_direct_mapped, shard_cache_layout
+
+    num_shards = mesh.shape[axis]
+    if cache.shards == num_shards:
+        return cache
+    host = shard_cache_layout(host_direct_mapped(cache), num_shards)
+    sharding = NamedSharding(mesh, P(axis))
+    return jax.tree.map(
+        lambda x: jax.make_array_from_callback(
+            x.shape, sharding, lambda idx: x[idx]
+        ),
+        host,
+    )
 
 
 def run_search_multi_sharded(
@@ -875,9 +906,14 @@ def run_search_multi_sharded(
 
     ``cache`` overrides internal cache construction (a repository-index
     preload, DESIGN.md §13); ``warm_tag`` — the preload's tag snapshot —
-    splits ``index_hits`` out of ``cache_hits``.  Whenever a cache is in
-    play its final state rides back in ``stats["final_cache"]``
-    (direct-mapped layout; the hash-sharded device layout is internal).
+    splits ``index_hits`` out of ``cache_hits``.  A cache given in the
+    direct-mapped layout (a host copy) is permuted on the host and placed
+    shard by shard; one already in this mesh's layout goes in as it is.
+    Without one, each shard builds its own empty part inside the program.
+    Whenever a cache is in play its final state rides back in
+    ``stats["final_cache"]``, in the hash-sharded layout, split over the
+    mesh (``DetectionCache.shards``); ``serve.batcher.host_direct_mapped``
+    gives the direct-mapped view where a consumer needs it.
 
     ``window_limit`` caps how many sync windows THIS call executes
     (default: unbounded).  A capped call returns at a sync boundary with a
@@ -903,8 +939,9 @@ def run_search_multi_sharded(
     padded = pad_chunks(carries.sampler, num_shards)
     n1, n, frames = padded.n1, padded.n, padded.frames
 
+    empty = None
     if cache is None and cache_frames:
-        from repro.serve.batcher import init_detection_cache
+        from repro.serve.batcher import RowLayout
 
         # the hash-sharded placement needs capacity % shards == 0 to be a
         # pure transposition of the direct-mapped slot map; padding the
@@ -913,11 +950,9 @@ def run_search_multi_sharded(
         struct = jax.eval_shape(
             detector, jax.random.PRNGKey(0), jnp.zeros((), jnp.int32)
         )
-        cache = init_detection_cache(struct, cache_frames)
-    if cache is not None:
-        from repro.serve.batcher import shard_cache_layout
-
-        cache = shard_cache_layout(cache, num_shards)
+        empty = (RowLayout.of(struct), cache_frames // num_shards)
+    elif cache is not None:
+        cache = _place_cache(cache, mesh, axis)
 
     outs = _search_multi_sharded_device(
         carries.key,
@@ -947,14 +982,11 @@ def run_search_multi_sharded(
         max_steps=max_steps,
         alpha0=carries.sampler.alpha0,
         beta0=carries.sampler.beta0,
+        empty=empty,
     )
     (n1_out, n_out, matcher, keys, step, results, buf, tn, calls, hits,
      ihits, hw, ov, windows) = outs[:14]
-    final_cache = None
-    if cache is not None:
-        from repro.serve.batcher import unshard_cache_layout
-
-        final_cache = unshard_cache_layout(outs[14], num_shards)
+    final_cache = outs[14] if len(outs) > 14 else None
     out = ExSampleCarry(
         sampler=dataclasses.replace(
             carries.sampler,

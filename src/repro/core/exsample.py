@@ -926,6 +926,7 @@ def _multi_round(
     jax.jit,
     static_argnames=(
         "detector", "select", "cohorts", "method", "max_steps", "trace_every",
+        "empty",
     ),
 )
 def _search_multi_device(
@@ -941,11 +942,15 @@ def _search_multi_device(
     method: str,
     max_steps: int,
     trace_every: int,
+    empty=None,
 ):
     """Device-resident multi-query loop: runs rounds until EVERY query is
     finished; per query the continue / trace semantics mirror
     ``_search_scan_device`` exactly (same cap formula, boundary-crossing
-    checkpoints, unconditional final entry).
+    checkpoints, unconditional final entry).  With no ``cache`` and
+    ``empty = (layout, slots)`` the program builds its empty cache
+    itself, so the device holds one copy of it, not an input and a loop
+    copy.
 
     ``warm_tag`` is a snapshot of the cache tag as the repository index
     preloaded it (DESIGN.md §13): a cache hit whose slot still tags the
@@ -956,6 +961,11 @@ def _search_multi_device(
     q_n = mc.step.shape[0]
     cap = (max_steps + cohorts - 1) // trace_every + 1 if trace_every else 1
     buf0 = jnp.zeros((q_n, cap, 2), jnp.int32)
+    if cache is None and empty is not None:
+        from repro.serve.batcher import empty_cache
+
+        with jax.named_scope("cache_init"):
+            cache = empty_cache(*empty)
     n0 = jnp.zeros((q_n,), jnp.int32)
     z32 = jnp.zeros((), jnp.int32)
 
@@ -1057,13 +1067,14 @@ def _multi_search(
         limits = jnp.broadcast_to(
             jnp.asarray(result_limits, jnp.int32), (q_n,)
         )
+        empty = None
         if cache is None and cache_frames:
-            from repro.serve.batcher import init_detection_cache
+            from repro.serve.batcher import RowLayout
 
             struct = jax.eval_shape(
                 detector, jax.random.PRNGKey(0), jnp.zeros((), jnp.int32)
             )
-            cache = init_detection_cache(struct, cache_frames)
+            empty = (RowLayout.of(struct), cache_frames)
     with jax.profiler.TraceAnnotation("exsample.dispatch"):
         out, cache, buf, n, calls, hits, ihits, rounds = _search_multi_device(
             carries,
@@ -1077,6 +1088,7 @@ def _multi_search(
             method=method,
             max_steps=max_steps,
             trace_every=trace_every,
+            empty=empty,
         )
     with jax.profiler.TraceAnnotation("exsample.readback"):
         buf_host = np.asarray(buf)  # the single device→host sync

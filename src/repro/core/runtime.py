@@ -665,9 +665,8 @@ class AsyncMultiSearchDriver:
                 # preload the device tier from the repository index — an
                 # empty tier yields a cache bit-identical to
                 # init_detection_cache (the cold-path contract, §13)
-                self.cache, self._warm_frames = index.warm(
-                    struct, cache_frames
-                )
+                warm, self._warm_frames = index.warm(struct, cache_frames)
+                self.cache = jax.device_put(warm)
             else:
                 self.cache = init_detection_cache(struct, cache_frames)
         else:
@@ -1125,7 +1124,8 @@ class ElasticShardedRunner:
 
     Runs ``run_search_multi_sharded`` in bounded slices of ``sync_windows``
     sync windows.  Every slice returns a fully resumable state (carry +
-    hash-sharded cache in direct-mapped layout), so between slices the
+    the hash-sharded cache, still split over the mesh, which the next
+    slice takes back as it is), so between slices the
     runner heartbeats the live workers and sweeps the
     :class:`~repro.distributed.fault_tolerance.HeartbeatMonitor`.  When a
     sweep returns a dead verdict the runner *drains at the boundary it is
@@ -1141,11 +1141,13 @@ class ElasticShardedRunner:
          :func:`repro.distributed.elastic.resize_chunk_stats` (strip the
          old shard padding, re-pad for ``k`` — padding never stacks
          across successive shrinks);
-      3. re-place the detection cache: the direct-mapped snapshot is
-         carried forward as-is when its capacity already divides by ``k``,
-         otherwise :func:`repro.serve.batcher.reshard_cache_host` re-hashes
-         it to the padded capacity (memoization state — a collision under
-         the new modulus costs a future detector call, never correctness);
+      3. re-place the detection cache: it is copied to the host in the
+         direct-mapped order, carried forward as-is when its capacity
+         already divides by ``k``, otherwise
+         :func:`repro.serve.batcher.reshard_cache_host` re-hashes it to the
+         padded capacity (memoization state — a collision under the new
+         modulus costs a future detector call, never correctness), and the
+         next slice places it shard by shard;
          ``warm_tag`` is left untouched (its index-hit check uses its own
          capacity modulus);
       4. rebuild a ``("data",)`` mesh over the first ``k`` devices and
@@ -1210,7 +1212,7 @@ class ElasticShardedRunner:
         now = self.clock()
         for w in sorted(self.alive):
             self.monitor.register(w, now)
-        self._cache = cache          # direct-mapped snapshot between slices
+        self._cache = cache          # hash-sharded between slices
         if cache is not None:
             from repro.serve.batcher import reshard_cache_host
 
@@ -1287,7 +1289,6 @@ class ElasticShardedRunner:
         if self._cache is not None:
             from repro.serve.batcher import reshard_cache_host
 
-            self._cache = jax.tree.map(np.asarray, self._cache)
             cap = int(self._cache.tag.shape[0])
             self._cache = reshard_cache_host(
                 self._cache, cap + (-cap) % new_shards
@@ -1310,6 +1311,10 @@ class ElasticShardedRunner:
         live queries remain."""
         from repro.core.executor import run_search_multi_sharded
 
+        # a query already stopped takes no window in this slice; its trace
+        # ended in the slice that stopped it (or, stopped from the start,
+        # in the first slice), as in one unbounded call
+        traced = self._live_queries() | self._first_call
         out, traces, stats = run_search_multi_sharded(
             self.carry,
             self.chunks,
@@ -1329,7 +1334,8 @@ class ElasticShardedRunner:
         self.carry = out
         self._cache = stats["final_cache"]
         for q, t in enumerate(traces):
-            self.traces[q].extend(t)
+            if traced[q]:
+                self.traces[q].extend(t)
         self.stats["detector_invocations"] += stats["detector_invocations"]
         self.stats["cache_hits"] += stats["cache_hits"]
         self.stats["index_hits"] += stats["index_hits"]

@@ -124,18 +124,27 @@ class RepositoryIndex:
 
     def publish_cache(self, cache) -> int:
         """Persist every occupied slot of a search's final
-        :class:`DetectionCache` (one device→host sync for the whole
-        cache); returns the count of newly persisted frames."""
-        if cache is None:
+        :class:`DetectionCache`, in either slot layout (one device→host
+        copy of the whole cache; only occupied rows are unpacked, on the
+        host); returns the count of newly persisted frames."""
+        if cache is None or self.read_only:
             return 0
-        return self.publish(cache.tag, cache.store, cache.tag >= 0)
+        from repro.serve.batcher import host_direct_mapped
+
+        host = host_direct_mapped(cache)
+        occupied = np.flatnonzero(host.tag >= 0)
+        return self.publish(
+            host.tag[occupied], host.layout.unpack(host.store[occupied])
+        )
 
     # ---- device tier -------------------------------------------------------
 
     def warm(self, det_struct: Any, capacity: int):
-        """Preload a device cache from the host tier; returns
-        ``(DetectionCache, warm_frames)`` where ``warm_frames`` is the
-        frozenset of frame ids actually resident after the preload.
+        """Preload a cache from the host tier; returns ``(DetectionCache,
+        warm_frames)`` where ``warm_frames`` is the frozenset of frame ids
+        actually resident after the preload.  The cache is a host copy
+        (numpy leaves, direct-mapped): the caller places it, on one device
+        or shard by shard over a mesh.
 
         Deterministic fill: frames map to ``frame % capacity`` in
         ascending frame-id order, first occupant of a slot wins (so a
@@ -143,17 +152,11 @@ class RepositoryIndex:
         depending on dict order).  An EMPTY tier produces a cache
         bit-identical to ``init_detection_cache(det_struct, capacity)``.
         """
-        import jax
-        import jax.numpy as jnp
+        from repro.serve.batcher import DetectionCache, RowLayout
 
-        from repro.serve.batcher import DetectionCache
-
-        leaves_s, treedef = jax.tree.flatten(det_struct)
+        layout = RowLayout.of(det_struct)
         tag = np.full((capacity,), -1, np.int32)
-        stores = [
-            np.zeros((capacity,) + tuple(s.shape), s.dtype)
-            for s in leaves_s
-        ]
+        store = np.zeros((capacity, layout.width), np.int32)
         warm_frames = set()
         tier = self._tiers.get(self.detector_version, {})
         for f in sorted(tier):
@@ -161,14 +164,16 @@ class RepositoryIndex:
             if tag[slot] != -1:
                 continue
             tag[slot] = f
-            for k, leaf in enumerate(tier[f]):
-                stores[k][slot] = leaf
             warm_frames.add(f)
-        store = jax.tree.unflatten(
-            treedef, [jnp.asarray(s) for s in stores]
-        )
+        occupied = np.flatnonzero(tag >= 0)
+        if len(occupied):
+            leaves = [
+                np.stack([tier[int(tag[s])][k] for s in occupied])
+                for k in range(len(layout.shapes))
+            ]
+            store[occupied] = layout.pack(layout.treedef.unflatten(leaves))
         return (
-            DetectionCache(tag=jnp.asarray(tag), store=store),
+            DetectionCache(tag=tag, store=store, layout=layout),
             frozenset(warm_frames),
         )
 

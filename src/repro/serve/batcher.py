@@ -15,8 +15,11 @@ frames into one detector batch without dropping any slot, and
 ``DetectionCache`` is a direct-mapped, device-resident cache of raw
 detector output so a frame decoded+detected for one query is reused by
 every later query that samples it (the Focus/EKO shared-ingest
-economics).  The composed driver HASH-SHARDS one logical cache over the
-mesh (DESIGN.md §14): frame ``f`` lives only on shard ``f % S`` at local
+economics).  A slot's detections are packed into one lane-dense row of
+32-bit words (``RowLayout``), so the store is a 2-D array whose row
+gathers and scatters never relay it.  The composed driver HASH-SHARDS
+one logical cache over the mesh (DESIGN.md §14): frame ``f`` lives only
+on shard ``f % S`` at local
 slot ``(f // S) % (capacity // S)``, and per-round lookups/inserts route
 between requester and home shard with ``all_to_all`` collectives.  With
 ``capacity % S == 0`` that placement is a pure transposition of the
@@ -139,33 +142,139 @@ def dedup_first_index(frame_ids: jax.Array, valid: jax.Array) -> jax.Array:
         return jnp.where(valid & (first < b), first, idx)
 
 
+LANES = 128   # a row of the store is a whole number of vector lanes
+
+
+def _words(x, xp):
+    """``x`` as int32 words, one per element, bit for bit: 32-bit leaves
+    bit-cast, bools as 0/1."""
+    dt = np.dtype(x.dtype)
+    if dt == np.bool_:
+        return x.astype(np.int32)
+    if dt.itemsize != 4:
+        raise TypeError(f"a cache row holds 32-bit words; {dt} does not pack")
+    return _bitcast(x, np.int32, xp)
+
+
+def _unwords(w, dt, xp):
+    """Inverse of :func:`_words` for a leaf of dtype ``dt``."""
+    return w != 0 if dt == np.bool_ else _bitcast(w, dt, xp)
+
+
+def _bitcast(x, dt, xp):
+    if xp is np:
+        return np.ascontiguousarray(x).view(dt)
+    return jax.lax.bitcast_convert_type(x, dt)
+
+
+@dataclasses.dataclass(frozen=True)
+class RowLayout:
+    """How one slot's detections pack into a row of 32-bit words.
+
+    Every leaf of the detector's single-frame output is flattened, taken
+    word for word (32-bit leaves bit-cast, bools as 0/1), and the leaves
+    are laid side by side; the row is padded to a whole number of
+    128-word lanes.  The store is then a 2-D ``i32[S, width]`` array
+    whose minor axis is dense, so gathering or scattering
+    slots moves whole rows and never relays the store (a leaf such as
+    ``f32[S, 16, 8]`` has a minor axis of 8, which the TPU pads to 128
+    lanes and relays on every row gather).  The oracle's ``Detections``
+    (16 × (4 + 8 + 1 + 1) words) take 224 words in a 256-word row.
+    Round trips are bit-exact; the layout is hashable, so jitted programs
+    take it as static structure.
+    """
+
+    treedef: Any
+    shapes: tuple      # per leaf, its single-frame shape
+    dtypes: tuple      # per leaf, its numpy dtype
+    width: int         # words in a row, a multiple of LANES
+
+    @classmethod
+    def of(cls, det_struct: Any) -> "RowLayout":
+        """Layout for a detector whose single-frame output shapes are
+        ``det_struct`` (e.g. from ``jax.eval_shape(detector, key, frame)``)."""
+        leaves, treedef = jax.tree.flatten(det_struct)
+        shapes = tuple(tuple(s.shape) for s in leaves)
+        dtypes = tuple(np.dtype(s.dtype) for s in leaves)
+        for dt in dtypes:
+            _words(np.zeros((), dt), np)      # refuses what cannot pack
+        words = sum(int(np.prod(s, dtype=np.int64)) for s in shapes)
+        return cls(treedef, shapes, dtypes, max(-(-words // LANES), 1) * LANES)
+
+    @property
+    def words(self) -> int:
+        """Words of a row that hold data (the rest is padding)."""
+        return sum(int(np.prod(s, dtype=np.int64)) for s in self.shapes)
+
+    def pack(self, dets: Any):
+        """Detections with any leading axes ``[...]`` → rows ``i32[..., width]``
+        (numpy in, numpy out; otherwise jax)."""
+        leaves = self.treedef.flatten_up_to(dets)
+        xp = np if isinstance(leaves[0], np.ndarray) else jnp
+        lead = tuple(leaves[0].shape[: leaves[0].ndim - len(self.shapes[0])])
+        cols = [
+            xp.reshape(_words(leaf, xp), lead + (-1,)) for leaf in leaves
+        ]
+        pad = self.width - self.words
+        if pad:
+            cols.append(xp.zeros(lead + (pad,), np.int32))
+        return xp.concatenate(cols, axis=-1)
+
+    def unpack(self, rows):
+        """Rows ``i32[..., width]`` → detections with leading axes ``[...]``."""
+        xp = np if isinstance(rows, np.ndarray) else jnp
+        lead = tuple(rows.shape[:-1])
+        leaves, off = [], 0
+        for shape, dt in zip(self.shapes, self.dtypes):
+            n = int(np.prod(shape, dtype=np.int64))
+            w = rows[..., off:off + n]
+            leaves.append(xp.reshape(_unwords(w, dt, xp), lead + shape))
+            off += n
+        return self.treedef.unflatten(leaves)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class DetectionCache:
     """Direct-mapped device-resident cache of raw detector output.
 
     ``tag[s]`` holds the frame id cached in slot ``s`` (-1 = empty);
-    ``store`` is the detector's output pytree with a leading [capacity]
-    axis.  Frames map to slots by ``frame % capacity``, so a capacity ≥
-    the repository's frame count is exact while smaller capacities trade
-    memory for evictions — the production knob.
+    ``store[s]`` is that frame's detections packed into one row of
+    ``layout`` (:class:`RowLayout`).  Frames map to slots by ``frame %
+    capacity``, so a capacity ≥ the repository's frame count is exact
+    while smaller capacities trade memory for evictions — the production
+    knob.
+
+    ``shards`` says how the slots are ordered: 1 is the direct-mapped
+    order; S > 1 is the hash-sharded order of DESIGN.md §14 (shard ``s``'s
+    ``capacity / S`` slots contiguous, :func:`shard_cache_layout`), which
+    a mesh keeps split over its shards.
     """
 
-    tag: jax.Array   # i32[S] — cached frame id, -1 = empty
-    store: Any       # detection pytree, each leaf [S, ...]
+    tag: jax.Array    # i32[S] — cached frame id, -1 = empty
+    store: jax.Array  # i32[S, layout.width] — packed detections
+    layout: RowLayout = dataclasses.field(metadata=dict(static=True))
+    shards: int = dataclasses.field(default=1, metadata=dict(static=True))
 
     @property
     def capacity(self) -> int:
         return self.tag.shape[0]
 
 
+def empty_cache(layout: RowLayout, capacity: int, shards: int = 1) -> DetectionCache:
+    """A cache of ``capacity`` empty slots (built wherever it is traced:
+    inside a ``shard_map`` program it is one shard's part)."""
+    return DetectionCache(
+        tag=jnp.full((capacity,), -1, jnp.int32),
+        store=jnp.zeros((capacity, layout.width), jnp.int32),
+        layout=layout, shards=shards,
+    )
+
+
 def init_detection_cache(det_struct: Any, capacity: int) -> DetectionCache:
     """Empty cache for a detector whose (single-frame) output shapes are
     ``det_struct`` (e.g. from ``jax.eval_shape(detector, key, frame)``)."""
-    store = jax.tree.map(
-        lambda s: jnp.zeros((capacity,) + tuple(s.shape), s.dtype), det_struct
-    )
-    return DetectionCache(tag=jnp.full((capacity,), -1, jnp.int32), store=store)
+    return empty_cache(RowLayout.of(det_struct), capacity)
 
 
 def cache_lookup(cache: DetectionCache, frame_ids: jax.Array):
@@ -174,11 +283,12 @@ def cache_lookup(cache: DetectionCache, frame_ids: jax.Array):
     Sentinel/padding slots (``frame_ids < 0``) NEVER hit: a padded frame id
     of -1 maps to slot ``capacity-1`` and would compare equal to the
     empty-slot tag -1, reporting a phantom hit whose gathered "detections"
-    are garbage (zeros or whatever real frame lives there)."""
+    are garbage (zeros or whatever real frame lives there).  Only the
+    ``[B]`` gathered rows are unpacked."""
     with jax.named_scope("dedup_cache"):
         slot = frame_ids % cache.capacity
-        hit = (frame_ids >= 0) & (cache.tag[slot] == frame_ids)
-        vals = jax.tree.map(lambda x: x[slot], cache.store)
+        hit = (frame_ids >= 0) & (jnp.asarray(cache.tag)[slot] == frame_ids)
+        vals = cache.layout.unpack(jnp.asarray(cache.store)[slot])
     return hit, vals
 
 
@@ -198,11 +308,11 @@ def cache_insert(
         first = dedup_first_index(slot, valid)
         keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
         tgt = jnp.where(keep, slot, s)
-        tag = cache.tag.at[tgt].set(frame_ids, mode="drop")
-        store = jax.tree.map(
-            lambda st, v: st.at[tgt].set(v, mode="drop"), cache.store, dets
+        tag = jnp.asarray(cache.tag).at[tgt].set(frame_ids, mode="drop")
+        store = jnp.asarray(cache.store).at[tgt].set(
+            cache.layout.pack(dets), mode="drop"
         )
-    return DetectionCache(tag=tag, store=store)
+    return dataclasses.replace(cache, tag=tag, store=store)
 
 
 # ---------------------------------------------------------------------------
@@ -217,7 +327,10 @@ def cache_insert(
 # frames collide under the sharded placement iff f1 ≡ f2 (mod S·L), the
 # same collision classes as the direct-mapped cache, so per-slot contents,
 # evictions, and hit/miss outcomes are bit-identical at equal capacity —
-# only WHERE each slot physically lives changes.
+# only WHERE each slot physically lives changes.  A mesh builds, keeps and
+# returns the cache in this order; only consumers that need the
+# direct-mapped view (the index publish, an elastic reshard) convert it,
+# on the host (:func:`host_direct_mapped`).
 
 
 def _cache_local_cap(capacity: int, num_shards: int) -> int:
@@ -230,61 +343,66 @@ def _cache_local_cap(capacity: int, num_shards: int) -> int:
     return capacity // num_shards
 
 
+def _transpose_slots(cache: DetectionCache, rows: int, cols: int, shards: int):
+    """Slot array viewed ``[rows, cols]``, transposed (numpy or jax alike)."""
+    cap = cache.capacity
+    perm = lambda x: (
+        x.reshape((rows, cols) + x.shape[1:])
+        .swapaxes(0, 1)
+        .reshape((cap,) + x.shape[1:])
+    )
+    return dataclasses.replace(
+        cache, tag=perm(cache.tag), store=perm(cache.store), shards=shards
+    )
+
+
 def shard_cache_layout(cache: DetectionCache, num_shards: int) -> DetectionCache:
     """Permute a direct-mapped cache into the hash-sharded global layout:
     index ``s·L + j`` of the result holds direct-mapped slot ``j·S + s``,
     so sharding the leading axis over the mesh hands shard ``s`` exactly
     its home entries (frames with ``f % S == s``) at local slot
     ``(f // S) % L``.  A pure transposition — bit-exact inverse of
-    :func:`unshard_cache_layout`."""
-    cap = cache.capacity
-    local = _cache_local_cap(cap, num_shards)
-    perm = lambda x: (
-        x.reshape((local, num_shards) + x.shape[1:])
-        .swapaxes(0, 1)
-        .reshape((cap,) + x.shape[1:])
-    )
-    return DetectionCache(
-        tag=perm(cache.tag), store=jax.tree.map(perm, cache.store)
-    )
+    :func:`unshard_cache_layout`.  Host (numpy) caches stay on the host."""
+    if cache.shards != 1:
+        raise ValueError(
+            f"cache is already in a {cache.shards}-shard layout")
+    local = _cache_local_cap(cache.capacity, num_shards)
+    return _transpose_slots(cache, local, num_shards, num_shards)
 
 
-def unshard_cache_layout(cache: DetectionCache, num_shards: int) -> DetectionCache:
+def unshard_cache_layout(cache: DetectionCache) -> DetectionCache:
     """Inverse of :func:`shard_cache_layout`: back to the direct-mapped
     layout every host-side consumer (``cache_lookup``, index publish,
     parity tests) understands."""
-    cap = cache.capacity
-    local = _cache_local_cap(cap, num_shards)
-    perm = lambda x: (
-        x.reshape((num_shards, local) + x.shape[1:])
-        .swapaxes(0, 1)
-        .reshape((cap,) + x.shape[1:])
-    )
-    return DetectionCache(
-        tag=perm(cache.tag), store=jax.tree.map(perm, cache.store)
-    )
+    s = cache.shards
+    local = _cache_local_cap(cache.capacity, s)
+    return _transpose_slots(cache, s, local, 1)
+
+
+def host_direct_mapped(cache: DetectionCache) -> DetectionCache:
+    """``cache`` on the host (numpy leaves) in the direct-mapped layout:
+    one device→host copy, and the permutation done there, so a cache too
+    large for one chip is never gathered onto one."""
+    host = jax.device_get(cache)
+    return unshard_cache_layout(host) if host.shards > 1 else host
 
 
 def reshard_cache_host(cache: DetectionCache, new_capacity: int) -> DetectionCache:
-    """Re-place a direct-mapped cache into a NEW capacity (host-side,
-    eager): occupied entries re-map to ``frame % new_capacity`` in
-    ascending frame-id order, first occupant wins — the same deterministic
-    fill convention as ``RepositoryIndex.warm``, so an elastic mesh shrink
+    """Re-place a cache into a NEW capacity (host-side, numpy out):
+    occupied entries re-map to ``frame % new_capacity`` in ascending
+    frame-id order, first occupant wins — the same deterministic fill
+    convention as ``RepositoryIndex.warm``, so an elastic mesh shrink
     that changes the divisibility-padded capacity replays identically on
-    every survivor.  A no-op (same object) when the capacity already
-    matches."""
+    every survivor.  Returns the direct-mapped host copy when the capacity
+    already matches."""
+    cache = host_direct_mapped(cache)
     if new_capacity == cache.capacity:
         return cache
     if new_capacity < 1:
         raise ValueError(f"new_capacity must be >= 1, got {new_capacity}")
     tag_h = np.asarray(cache.tag)
-    leaves, treedef = jax.tree.flatten(cache.store)
-    leaves_h = [np.asarray(leaf) for leaf in leaves]
     new_tag = np.full((new_capacity,), -1, np.int32)
-    new_leaves = [
-        np.zeros((new_capacity,) + leaf.shape[1:], leaf.dtype)
-        for leaf in leaves_h
-    ]
+    new_store = np.zeros((new_capacity,) + cache.store.shape[1:], np.int32)
     occupied = np.flatnonzero(tag_h >= 0)
     for src in occupied[np.argsort(tag_h[occupied], kind="stable")]:
         f = int(tag_h[src])
@@ -292,14 +410,8 @@ def reshard_cache_host(cache: DetectionCache, new_capacity: int) -> DetectionCac
         if new_tag[slot] != -1:
             continue
         new_tag[slot] = f
-        for k, leaf in enumerate(leaves_h):
-            new_leaves[k][slot] = leaf[src]
-    return DetectionCache(
-        tag=jnp.asarray(new_tag),
-        store=jax.tree.unflatten(
-            treedef, [jnp.asarray(x) for x in new_leaves]
-        ),
-    )
+        new_store[slot] = cache.store[src]
+    return dataclasses.replace(cache, tag=new_tag, store=new_store)
 
 
 def sharded_cache_lookup(
@@ -311,29 +423,31 @@ def sharded_cache_lookup(
     """Home-shard half of the routed lookup, run per shard inside
     ``shard_map``: serve exactly the probes homed here (``frame % S ==
     shard_id``); everything else — sentinels included — reports a miss
-    with unread gathered values.  ``frame_ids`` may be any shape."""
+    with unread gathered rows.  ``frame_ids`` may be any shape ``[...]``;
+    returns ``(hit bool[...], rows i32[..., width])`` — rows still packed,
+    so routing them takes one collective, and the requester unpacks."""
     with jax.named_scope("dedup_cache"):
         local = cache_local.capacity
         mine = (frame_ids >= 0) & (frame_ids % num_shards == shard_id)
         slot = (frame_ids // num_shards) % local
         hit = mine & (cache_local.tag[slot] == frame_ids)
-        vals = jax.tree.map(lambda x: x[slot], cache_local.store)
-    return hit, vals
+        rows = cache_local.store[slot]
+    return hit, rows
 
 
 def sharded_cache_insert(
     cache_local: DetectionCache,
     frame_ids: jax.Array,
-    dets: Any,
+    rows: jax.Array,
     mask: jax.Array,
     shard_id: jax.Array,
     num_shards: int,
 ) -> DetectionCache:
-    """Home-shard half of the routed insert (flat [B] batch, already
-    routed here): store masked frames homed on this shard at their local
-    slots, first-write-wins on within-batch slot collisions in batch
-    order — the same winner the direct-mapped :func:`cache_insert` picks
-    over the equivalent global batch."""
+    """Home-shard half of the routed insert (flat [B] batch of packed
+    ``rows``, already routed here): store masked frames homed on this
+    shard at their local slots, first-write-wins on within-batch slot
+    collisions in batch order — the same winner the direct-mapped
+    :func:`cache_insert` picks over the equivalent global batch."""
     with jax.named_scope("dedup_cache"):
         local = cache_local.capacity
         valid = (
@@ -344,8 +458,5 @@ def sharded_cache_insert(
         keep = valid & (first == jnp.arange(slot.shape[0], dtype=jnp.int32))
         tgt = jnp.where(keep, slot, local)
         tag = cache_local.tag.at[tgt].set(frame_ids, mode="drop")
-        store = jax.tree.map(
-            lambda st, v: st.at[tgt].set(v, mode="drop"),
-            cache_local.store, dets,
-        )
-    return DetectionCache(tag=tag, store=store)
+        store = cache_local.store.at[tgt].set(rows, mode="drop")
+    return dataclasses.replace(cache_local, tag=tag, store=store)

@@ -169,6 +169,64 @@ def test_cache_within_batch_first_write_wins():
     assert not bool(hit[1]), "second colliding write must lose, not race"
 
 
+def _bits(x):
+    return np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32", "uint32", "bool"])
+def test_cache_rows_round_trip_bit_exact(dtype):
+    """A slot's detections packed into a row of 32-bit words and back give
+    the same bits, on the host (numpy) and on the device (jax) alike:
+    float NaN payloads, signed zeros and infinities, bools and integers
+    at their extremes included, with leading axes of any rank; a leaf
+    that is not 32 bits wide is refused, not truncated."""
+    from repro.serve.batcher import RowLayout
+
+    dt = np.dtype(dtype)
+    rng = np.random.default_rng(3)
+    shape = (2, 5, 16, 3)
+    if dt == np.bool_:
+        x = rng.random(shape) < 0.5
+    else:   # every bit pattern, NaNs with payloads among them
+        x = rng.integers(0, 2**32, shape, np.uint32).view(dt)
+    special = np.array([np.nan, -0.0, 0.0, np.inf, -np.inf, 1e-45, -1.5],
+                       np.float32)
+    dets = {"a": x, "b": np.resize(special, shape[:2] + (16,))}
+    struct = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape[2:], v.dtype), dets)
+    layout = RowLayout.of(struct)
+    assert layout.width % 128 == 0 and layout.words == 16 * 3 + 16
+    rows = layout.pack(dets)
+    assert isinstance(rows, np.ndarray)
+    assert rows.shape == shape[:2] + (layout.width,) and rows.dtype == np.int32
+    rows_dev = layout.pack(jax.tree.map(jnp.asarray, dets))
+    np.testing.assert_array_equal(np.asarray(rows_dev), rows)
+    for back in (layout.unpack(rows), layout.unpack(rows_dev)):
+        for k in dets:
+            assert back[k].dtype == dets[k].dtype
+            assert back[k].shape == dets[k].shape
+            np.testing.assert_array_equal(_bits(back[k]), _bits(dets[k]))
+    with pytest.raises(TypeError, match="32-bit"):
+        RowLayout.of(jax.ShapeDtypeStruct((4,), jnp.bfloat16))
+
+
+def test_oracle_rows_are_lane_dense_and_near_logical_size():
+    """The oracle's ``Detections`` (16 × (4 + 8) f32, 16 bool, 16 i32: 852 B
+    with the tag) pack into 224 words of a 256-word row: 1,028 B a slot,
+    within 1.25× of the logical bytes."""
+    from repro.serve.batcher import RowLayout
+    from repro.sim.oracle import Detections
+
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    layout = RowLayout.of(Detections(
+        boxes=f(16, 4), feats=f(16, 8),
+        valid=jax.ShapeDtypeStruct((16,), jnp.bool_),
+        inst_id=jax.ShapeDtypeStruct((16,), jnp.int32),
+    ))
+    assert (layout.words, layout.width) == (224, 256)
+    assert (layout.width * 4 + 4) / 852 <= 1.25
+
+
 def test_shard_cache_layout_roundtrip_and_divisibility():
     from repro.serve.batcher import shard_cache_layout, unshard_cache_layout
 
@@ -178,7 +236,7 @@ def test_shard_cache_layout_roundtrip_and_divisibility():
         cache, frames, frames.astype(jnp.float32), jnp.ones(5, bool)
     )
     for s in (1, 2, 3, 4, 6):
-        back = unshard_cache_layout(shard_cache_layout(cache, s), s)
+        back = unshard_cache_layout(shard_cache_layout(cache, s))
         np.testing.assert_array_equal(
             np.asarray(back.tag), np.asarray(cache.tag))
         np.testing.assert_array_equal(
@@ -231,12 +289,13 @@ def test_sharded_cache_bit_identical_to_direct_mapped(
         mask = jnp.asarray([m for _, m in batch])
         vals = frames.astype(jnp.float32)
         direct = cache_insert(direct, frames, vals, mask)
+        rows = direct.layout.pack(vals)
         locals_ = [
-            sharded_cache_insert(c, frames, vals, mask, s, num_shards)
+            sharded_cache_insert(c, frames, rows, mask, s, num_shards)
             for s, c in enumerate(locals_)
         ]
     assembled = unshard_cache_layout(
-        jax.tree.map(lambda *xs: jnp.concatenate(xs), *locals_), num_shards
+        jax.tree.map(lambda *xs: jnp.concatenate(xs), *locals_)
     )
     np.testing.assert_array_equal(
         np.asarray(assembled.tag), np.asarray(direct.tag))
@@ -255,7 +314,8 @@ def test_sharded_cache_bit_identical_to_direct_mapped(
     for i in range(len(probes)):
         if bool(d_hit[i]):
             s = int(probes[i]) % num_shards
-            assert float(s_vals[s][i]) == float(d_vals[i])
+            got = direct.layout.unpack(s_vals[s][i])
+            assert float(got) == float(d_vals[i])
 
 
 def test_cache_sentinel_never_hits_nor_inserts():
