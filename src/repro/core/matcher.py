@@ -8,11 +8,12 @@ by IoU in frame-space plus temporal gating (SORT-style) and optional
 appearance-feature cosine similarity.
 
 Everything is statically shaped so the whole match-update step jits; the
-result memory is a ring buffer of capacity ``max_results``.
-
-The pairwise-IoU inner product is the compute hot spot for crowded scenes
-(D × R box pairs) and is backed by the ``repro.kernels.iou_match`` Pallas
-kernel; the pure-jnp path here doubles as its reference.
+result memory is a ring buffer of capacity ``max_results``, stored with
+the entry axis R last (boxes ``[4, R]``, features ``[F, R]``) so that R
+lies on the TPU's 128 lanes.  Every frame reads the ring lane-dense and
+writes only the lanes that change: a new result lands in its slot by a
+select against a lane iota, never by a pad, a slice or a scatter along
+the ring (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -28,10 +29,10 @@ NEG = -1e9
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class MatcherState:
-    """Ring-buffer result memory (capacity R)."""
+    """Ring-buffer result memory (capacity R), entry axis last."""
 
-    boxes: jax.Array        # f32[R, 4]  — (x0, y0, x1, y1) of first sighting
-    feats: jax.Array        # f32[R, F]  — appearance feature of first sighting
+    boxes: jax.Array        # f32[4, R]  — (x0, y0, x1, y1) of first sighting
+    feats: jax.Array        # f32[F, R]  — appearance feature of first sighting
     video: jax.Array        # i32[R]     — video id of first sighting
     frame: jax.Array        # i32[R]     — global frame id of first sighting
     chunk: jax.Array        # i32[R]     — chunk of first sighting (§3.4)
@@ -44,7 +45,7 @@ class MatcherState:
 
     @property
     def capacity(self) -> int:
-        return self.boxes.shape[0]
+        return self.times_seen.shape[-1]
 
 
 def init_matcher(
@@ -56,8 +57,8 @@ def init_matcher(
     feat_thresh: float = -1.0,
 ) -> MatcherState:
     return MatcherState(
-        boxes=jnp.zeros((max_results, 4), jnp.float32),
-        feats=jnp.zeros((max_results, feat_dim), jnp.float32),
+        boxes=jnp.zeros((4, max_results), jnp.float32),
+        feats=jnp.zeros((feat_dim, max_results), jnp.float32),
         video=jnp.full((max_results,), -1, jnp.int32),
         frame=jnp.full((max_results,), -(10**9), jnp.int32),
         chunk=jnp.full((max_results,), -1, jnp.int32),
@@ -88,12 +89,21 @@ def init_matcher_multi(num_queries: int, **kwargs) -> MatcherState:
 
 def pairwise_iou(a: jax.Array, b: jax.Array) -> jax.Array:
     """IoU matrix f32[D, R] for boxes a f32[D,4], b f32[R,4] (x0,y0,x1,y1)."""
+    return ring_iou(a, b.T)
+
+
+def ring_iou(a: jax.Array, b: jax.Array) -> jax.Array:
+    """IoU matrix f32[D, R] for boxes a f32[D,4] against a coordinate-major
+    ring b f32[4,R]: each coordinate is one lane-dense row of b."""
     area_a = jnp.maximum(a[:, 2] - a[:, 0], 0.0) * jnp.maximum(a[:, 3] - a[:, 1], 0.0)
-    area_b = jnp.maximum(b[:, 2] - b[:, 0], 0.0) * jnp.maximum(b[:, 3] - b[:, 1], 0.0)
-    lt = jnp.maximum(a[:, None, :2], b[None, :, :2])
-    rb = jnp.minimum(a[:, None, 2:], b[None, :, 2:])
-    wh = jnp.maximum(rb - lt, 0.0)
-    inter = wh[..., 0] * wh[..., 1]
+    area_b = jnp.maximum(b[2] - b[0], 0.0) * jnp.maximum(b[3] - b[1], 0.0)
+    w = jnp.maximum(
+        jnp.minimum(a[:, 2, None], b[2]) - jnp.maximum(a[:, 0, None], b[0]), 0.0
+    )
+    h = jnp.maximum(
+        jnp.minimum(a[:, 3, None], b[3]) - jnp.maximum(a[:, 1, None], b[1]), 0.0
+    )
+    inter = w * h
     union = area_a[:, None] + area_b[None, :] - inter
     return inter / jnp.maximum(union, 1e-9)
 
@@ -133,8 +143,9 @@ def match_and_update(
 
 
 def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
+    cap = state.capacity
     occupied = state.times_seen > 0
-    iou = pairwise_iou(boxes, state.boxes)
+    iou = ring_iou(boxes, state.boxes)
     same_video = state.video[None, :] == video_id
     in_gate = jnp.abs(state.frame[None, :] - frame_id) <= state.time_gate
     match_ok = iou >= state.iou_thresh
@@ -146,9 +157,9 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
         # the role the paper's tracker-based matcher plays.
         an = feats / jnp.maximum(jnp.linalg.norm(feats, axis=-1, keepdims=True), 1e-9)
         bn = state.feats / jnp.maximum(
-            jnp.linalg.norm(state.feats, axis=-1, keepdims=True), 1e-9
+            jnp.linalg.norm(state.feats, axis=0, keepdims=True), 1e-9
         )
-        sim = an @ bn.T
+        sim = an @ bn
         match_ok = match_ok | (sim >= state.feat_thresh)
         score_val = jnp.maximum(iou, sim)
     eligible = occupied[None, :] & same_video & in_gate & match_ok
@@ -159,9 +170,10 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
     has_match = has_match & valid
     is_new = valid & ~has_match
 
-    # --- bump times_seen for matched entries (scatter-add over entries) ---
-    bump = jnp.zeros((state.capacity,), jnp.int32).at[best].add(
-        has_match.astype(jnp.int32)
+    # --- bump times_seen for matched entries ---
+    ring_lane = jnp.arange(cap, dtype=jnp.int32)
+    bump = jnp.sum(
+        (best[:, None] == ring_lane) & has_match[:, None], axis=0, dtype=jnp.int32
     )
     new_seen = state.times_seen + jnp.where(occupied, bump, 0)
     went_twice = occupied & (state.times_seen == 1) & (new_seen >= 2)
@@ -182,22 +194,30 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
     first = has_match & ~jnp.any(earlier_same, axis=1)
     cross_home = jnp.where(first & crossed[best], state.chunk[best], -1)
 
-    # --- insert new results into ring buffer slots ---
+    # --- insert new results at cursor, cursor+1, ... (ring) ---
     d0 = jnp.sum(is_new).astype(jnp.int32)
     num_new = d0
-    # Target slots: cursor, cursor+1, ... (ring).  Build per-detection slot
-    # ids via exclusive cumsum over is_new.
+    # per-detection slot ids via exclusive cumsum over is_new; -1 = none
     order = jnp.cumsum(is_new.astype(jnp.int32)) - is_new.astype(jnp.int32)
-    slot = (state.cursor + order) % state.capacity
-    slot = jnp.where(is_new, slot, state.capacity)  # dump non-new to OOB pad
-    pad = lambda arr, fill: jnp.concatenate([arr, jnp.full((1,) + arr.shape[1:], fill, arr.dtype)], 0)
+    slot = jnp.where(is_new, (state.cursor + order) % cap, -1)
+    # In place: ring lane r takes the detection whose slot is r (src[r],
+    # -1 = none), else keeps its value — elementwise over the lane-dense ring.
+    src = jnp.max(
+        jnp.where(slot[:, None] == ring_lane, lane[:, None], -1), axis=0
+    )
+    written = src >= 0
 
-    boxes_mem = pad(state.boxes, 0.0).at[slot].set(boxes)[:-1]
-    feats_mem = pad(state.feats, 0.0).at[slot].set(feats)[:-1]
-    video_mem = pad(state.video, -1).at[slot].set(jnp.broadcast_to(video_id, slot.shape))[:-1]
-    frame_mem = pad(state.frame, 0).at[slot].set(jnp.broadcast_to(frame_id, slot.shape))[:-1]
-    chunk_mem = pad(state.chunk, -1).at[slot].set(jnp.broadcast_to(chunk_id, slot.shape))[:-1]
-    seen_mem = pad(new_seen, 0).at[slot].set(1)[:-1]
+    def put(mem, rows):         # mem [k, R] ← rows [D, k]
+        for d in range(rows.shape[0]):
+            mem = jnp.where(src == d, rows[d, :, None], mem)
+        return mem
+
+    boxes_mem = put(state.boxes, boxes)
+    feats_mem = put(state.feats, feats)
+    video_mem = jnp.where(written, video_id, state.video)
+    frame_mem = jnp.where(written, frame_id, state.frame)
+    chunk_mem = jnp.where(written, chunk_id, state.chunk)
+    seen_mem = jnp.where(written, 1, new_seen)
 
     new_state = dataclasses.replace(
         state,
@@ -207,7 +227,7 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
         frame=frame_mem,
         chunk=chunk_mem,
         times_seen=seen_mem,
-        cursor=(state.cursor + num_new) % state.capacity,
+        cursor=(state.cursor + num_new) % cap,
         total_inserted=state.total_inserted + num_new,
     )
     return MatchResult(
@@ -218,6 +238,11 @@ def _match_frame(state, boxes, feats, valid, video_id, frame_id, chunk_id):
         is_new=is_new,
         new_state=new_state,
     )
+
+
+def _ring_window(cursor, n, cap: int) -> jax.Array:
+    """bool[R] — the ring slots ``[cursor, cursor + n) mod cap``."""
+    return (jnp.arange(cap, dtype=jnp.int32) - cursor) % cap < n
 
 
 def num_results(state: MatcherState) -> jax.Array:
@@ -245,9 +270,7 @@ def merge_stats(dst: MatcherState, src: MatcherState, snap: MatcherState) -> Mer
     ``repro.core.runtime.AsyncSearchDriver._merge``)."""
     cap = dst.capacity
     inserted = src.total_inserted - snap.total_inserted
-    n_new = inserted % cap
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    dst_slot_hit = (idx - dst.cursor) % cap < n_new
+    dst_slot_hit = _ring_window(dst.cursor, inserted % cap, cap)
     clobbered = jnp.sum(dst_slot_hit & (dst.times_seen > 0)).astype(jnp.int32)
     return MergeStats(
         inserted=inserted, overflow=inserted >= cap, clobbered=clobbered
@@ -276,8 +299,7 @@ def eviction_mask(dst: MatcherState, n_new) -> jax.Array:
     the source ring itself wrapped and the entries are unrecoverable —
     ``MergeStats.overflow``)."""
     cap = dst.capacity
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    window = (idx - dst.cursor) % cap < jnp.minimum(n_new, cap)
+    window = _ring_window(dst.cursor, jnp.minimum(n_new, cap), cap)
     return window & (dst.times_seen > 0)
 
 
@@ -308,8 +330,9 @@ class ResultLog:
         mask_np = np.asarray(mask)
         k = int(mask_np.sum())
         if k:
+            # ring leaves keep the entry axis last; host rows put it first
             self._chunks.append({
-                f: np.asarray(getattr(matcher, f))[mask_np]
+                f: np.asarray(getattr(matcher, f))[..., mask_np].T
                 for f in self._FIELDS
             })
             self.count += k
@@ -359,14 +382,13 @@ def merge_matcher(
     detectable via ``merge_stats``/``merge_matcher_checked`` (overflow
     flag + high-water insertion count) rather than silently wrapping."""
     cap = dst.capacity
-    idx = jnp.arange(cap, dtype=jnp.int32)
     n_new = (src.cursor - snap.cursor) % cap
-    src_slot = (snap.cursor + idx) % cap
-    valid = idx < n_new
-    dst_slot = jnp.where(valid, (dst.cursor + idx) % cap, cap)  # OOB ⇒ drop
+    src_inserted = _ring_window(snap.cursor, n_new, cap)
+    window = _ring_window(dst.cursor, n_new, cap)
+    # dst slot dst.cursor + i takes src slot snap.cursor + i: a rotation
+    shift = (snap.cursor - dst.cursor) % cap
 
     # --- additive seen-count bumps for entries that existed at snapshot ---
-    src_inserted = jnp.zeros((cap,), bool).at[src_slot].set(valid, mode="drop")
     same_as_snap = (
         (dst.video == snap.video)
         & (dst.frame == snap.frame)
@@ -377,10 +399,13 @@ def merge_matcher(
     )
     times = dst.times_seen + bump
 
-    # --- append src's new entries at dst's cursor --------------------------
-    put = lambda d, s: jnp.concatenate(
-        [d, jnp.zeros((1,) + d.shape[1:], d.dtype)], 0
-    ).at[dst_slot].set(s[src_slot], mode="drop")[:-1]
+    # --- append src's new entries at dst's cursor, in place ---------------
+    def put(d, s):
+        rotated = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([s, s], axis=-1), shift, cap, axis=-1
+        )
+        return jnp.where(window, rotated, d)
+
     return dataclasses.replace(
         dst,
         boxes=put(dst.boxes, src.boxes),

@@ -307,6 +307,15 @@ def test_tiny_ring_spills_without_loss(world):
     arrs = driver.logs[0].as_arrays()
     assert arrs["frame"].shape[0] == len(driver.logs[0])
     assert np.all(arrs["times_seen"] >= 1)
+    # host rows [k, 4] / [k, F], each the detection that first sighted it
+    k = len(driver.logs[0])
+    assert arrs["boxes"].shape == (k, 4) and arrs["feats"].shape == (k, 8)
+    for box, feat, frame in zip(arrs["boxes"], arrs["feats"], arrs["frame"]):
+        d = det(None, jnp.int32(frame))
+        same = np.asarray(d.valid) & np.all(
+            np.isclose(np.asarray(d.boxes), box, rtol=0, atol=1e-6), axis=1)
+        assert same.any(), (frame, box)
+        assert np.any(np.all(np.asarray(d.feats)[same] == feat, axis=1))
 
 
 def test_overflow_impossible_by_construction(world):

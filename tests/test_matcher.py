@@ -1,14 +1,21 @@
 """Matcher semantics: d0/d1 counting, dedup, cross-chunk, ring buffer."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.matcher import (
+    ResultLog,
+    broadcast_leading,
+    eviction_mask,
     init_matcher,
     match_and_update,
+    merge_matcher,
     merge_matcher_checked,
     pairwise_iou,
+    ring_iou,
 )
 
 
@@ -158,7 +165,7 @@ def _dense_cross_home(state, boxes, valid, video_id, frame_id, chunk_id):
     detections matched each entry."""
     seen = np.asarray(state.times_seen)
     occupied = seen > 0
-    iou = np.asarray(pairwise_iou(jnp.asarray(boxes), state.boxes))
+    iou = np.asarray(pairwise_iou(jnp.asarray(boxes), state.boxes.T))
     eligible = (
         occupied[None, :]
         & (np.asarray(state.video)[None, :] == video_id)
@@ -213,3 +220,274 @@ def test_cross_home_per_detection_matches_dense_entries(seed, capacity):
         m = r.new_state
     assert crossings > 0
     assert doubled > 0   # an entry two detections moved crossed: one lane
+
+
+# ---------------------------------------------------------------------------
+# The coordinate-major ring against a numpy model of the row-major ring:
+# entries on rows, new results written at cursor, cursor + 1, ... in
+# detection order, appends of a merge copied slot by slot.
+# ---------------------------------------------------------------------------
+
+_LEAVES = ("boxes", "feats", "video", "frame", "chunk", "times_seen")
+
+
+def _np_iou(a, b):
+    """float32 IoU [D, R] of a [D, 4] against row-major b [R, 4]."""
+    area_a = np.maximum(a[:, 2] - a[:, 0], 0) * np.maximum(a[:, 3] - a[:, 1], 0)
+    area_b = np.maximum(b[:, 2] - b[:, 0], 0) * np.maximum(b[:, 3] - b[:, 1], 0)
+    lt = np.maximum(a[:, None, :2], b[None, :, :2])
+    rb = np.minimum(a[:, None, 2:], b[None, :, 2:])
+    wh = np.maximum(rb - lt, np.float32(0))
+    inter = wh[..., 0] * wh[..., 1]
+    union = area_a[:, None] + area_b[None, :] - inter
+    return inter / np.maximum(union, np.float32(1e-9))
+
+
+def _rows(state):
+    """A (unbatched) device ring as the row-major numpy model holds it."""
+    ring = {f: np.array(getattr(state, f)).T for f in _LEAVES}
+    ring["cursor"] = int(state.cursor)
+    ring["total"] = int(state.total_inserted)
+    return ring
+
+
+def _assert_ring_equals(state, ring):
+    for f in _LEAVES:
+        np.testing.assert_array_equal(np.asarray(getattr(state, f)).T, ring[f], f)
+    assert int(state.cursor) == ring["cursor"]
+    assert int(state.total_inserted) == ring["total"]
+
+
+def _np_match(ring, boxes, feats, valid, video_id, frame_id, chunk_id,
+              *, iou_thresh=0.5, time_gate=300):
+    """One frame on the row-major model; updates ``ring`` in place and
+    returns (d0, d1, cross_chunk, cross_home, is_new)."""
+    cap = ring["times_seen"].shape[0]
+    seen = ring["times_seen"]
+    occupied = seen > 0
+    iou = _np_iou(boxes, ring["boxes"])
+    eligible = (
+        occupied[None, :]
+        & (ring["video"][None, :] == video_id)
+        & (np.abs(ring["frame"][None, :] - frame_id) <= time_gate)
+        & (iou >= iou_thresh)
+    )
+    best = np.where(eligible, iou, -1e9).argmax(axis=1)
+    has_match = eligible[np.arange(len(best)), best] & valid
+    is_new = valid & ~has_match
+    bump = np.zeros(cap, seen.dtype)
+    np.add.at(bump, best, has_match.astype(seen.dtype))
+    new_seen = seen + np.where(occupied, bump, 0)
+    went_twice = occupied & (seen == 1) & (new_seen >= 2)
+    crossed = went_twice & (ring["chunk"] != chunk_id)
+    cross_home = np.full(len(best), -1)
+    for d in np.flatnonzero(has_match):
+        if crossed[best[d]] and not np.any(has_match[:d] & (best[:d] == best[d])):
+            cross_home[d] = ring["chunk"][best[d]]
+    ring["times_seen"] = new_seen
+    for d in np.flatnonzero(is_new):
+        r = ring["cursor"]
+        ring["boxes"][r], ring["feats"][r] = boxes[d], feats[d]
+        ring["video"][r], ring["frame"][r] = video_id, frame_id
+        ring["chunk"][r], ring["times_seen"][r] = chunk_id, 1
+        ring["cursor"] = (r + 1) % cap
+        ring["total"] += 1
+    return (int(is_new.sum()), int(went_twice.sum()), int(crossed.sum()),
+            cross_home, is_new)
+
+
+def _np_merge(dst, src, snap):
+    """``merge_matcher`` on row-major models, slot by slot."""
+    cap = dst["times_seen"].shape[0]
+    out = {f: dst[f].copy() for f in _LEAVES}
+    n_new = (src["cursor"] - snap["cursor"]) % cap
+    src_inserted = np.zeros(cap, bool)
+    for i in range(n_new):
+        src_inserted[(snap["cursor"] + i) % cap] = True
+    same = ((dst["video"] == snap["video"]) & (dst["frame"] == snap["frame"])
+            & (snap["times_seen"] > 0))
+    out["times_seen"] = dst["times_seen"] + np.where(
+        same & ~src_inserted, src["times_seen"] - snap["times_seen"], 0)
+    for i in range(n_new):
+        r, s = (dst["cursor"] + i) % cap, (snap["cursor"] + i) % cap
+        for f in _LEAVES:
+            out[f][r] = src[f][s]
+    out["cursor"] = (dst["cursor"] + n_new) % cap
+    out["total"] = dst["total"] + src["total"] - snap["total"]
+    return out
+
+
+_ANCHORS = np.asarray(
+    [_box(0.1 + 0.2 * i, 0.1 + 0.15 * (i % 3)) for i in range(5)], np.float32
+)
+
+
+def _frame(rng, t, d_n=16, f_n=8):
+    """Detections around a few anchors, so frames both insert and match."""
+    boxes = (_ANCHORS[rng.integers(0, len(_ANCHORS), d_n)]
+             + rng.uniform(-0.005, 0.005, (d_n, 4))).astype(np.float32)
+    feats = rng.normal(size=(d_n, f_n)).astype(np.float32)
+    valid = rng.random(d_n) < 0.4
+    return (boxes, feats, valid, int(rng.integers(0, 2)), 50 * t,
+            int(rng.integers(0, 4)))
+
+
+def _ring_at(capacity, cursor):
+    """An empty ring whose cursor starts at ``cursor``."""
+    return dataclasses.replace(
+        init_matcher(max_results=capacity, time_gate=300),
+        cursor=jnp.int32(cursor),
+    )
+
+
+def test_ring_iou_is_pairwise_iou_bit_for_bit():
+    rng = np.random.default_rng(0)
+    a = rng.uniform(0, 1, (16, 4)).astype(np.float32)
+    b = rng.uniform(0, 1, (300, 4)).astype(np.float32)
+    a[:, 2:] += a[:, :2]
+    b[:, 2:] = np.where(rng.random((300, 2)) < 0.5, b[:, :2] + 0.1, b[:, 2:])
+    np.testing.assert_array_equal(
+        np.asarray(ring_iou(jnp.asarray(a), jnp.asarray(b.T))),
+        np.asarray(pairwise_iou(jnp.asarray(a), jnp.asarray(b))),
+    )
+    np.testing.assert_allclose(
+        np.asarray(pairwise_iou(jnp.asarray(a), jnp.asarray(b))),
+        _np_iou(a, b), rtol=1e-6, atol=1e-7,
+    )
+
+
+@pytest.mark.parametrize("capacity", [16, 256])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_lane_dense_ring_matches_row_major_model(seed, capacity):
+    """Frame by frame from a cursor near R: the same d₀, d₁, §3.4 homes and
+    novelty flags, and the ring's contents are the model's rows
+    transposed, through many wraps."""
+    rng = np.random.default_rng(seed)
+    m = _ring_at(capacity, capacity - 3)
+    ring = _rows(m)
+    step = jax.jit(match_and_update)
+    wraps = 0
+    for t in range(120):
+        boxes, feats, valid, vid, fid, cid = _frame(rng, t)
+        d0, d1, cross, homes, is_new = _np_match(
+            ring, boxes, feats, valid, vid, fid, cid)
+        r = step(m, jnp.asarray(boxes), jnp.asarray(feats), jnp.asarray(valid),
+                 jnp.int32(vid), jnp.int32(fid), jnp.int32(cid))
+        assert (int(r.d0), int(r.d1), int(r.cross_chunk)) == (d0, d1, cross)
+        np.testing.assert_array_equal(np.asarray(r.cross_home), homes)
+        np.testing.assert_array_equal(np.asarray(r.is_new), is_new)
+        wraps += int(r.new_state.cursor) < int(m.cursor)
+        m = r.new_state
+        assert m.boxes.shape == (4, capacity) and m.feats.shape == (8, capacity)
+        _assert_ring_equals(m, ring)
+    assert wraps >= 1
+
+
+@pytest.mark.parametrize("capacity", [16, 256])
+def test_lane_dense_ring_under_vmap_matches_model_per_query(capacity):
+    """Q = 3 rings with different cursors, vmapped as the multi-query fold
+    runs them: each query's ring tracks its own model."""
+    q_n = 3
+    rng = np.random.default_rng(capacity)
+    cursors = [capacity - 1, capacity - 7, 2]
+    m = broadcast_leading(init_matcher(max_results=capacity, time_gate=300), q_n)
+    m = dataclasses.replace(m, cursor=jnp.asarray(cursors, jnp.int32))
+    assert m.boxes.shape == (q_n, 4, capacity) and m.capacity == capacity
+    rings = [_rows(jax.tree.map(lambda x, q=q: x[q], m)) for q in range(q_n)]
+    step = jax.jit(jax.vmap(match_and_update))
+    for t in range(80):
+        frames = [_frame(rng, t) for _ in range(q_n)]
+        want = [_np_match(rings[q], *frames[q]) for q in range(q_n)]
+        cols = list(zip(*frames))
+        r = step(m, jnp.asarray(np.stack(cols[0])), jnp.asarray(np.stack(cols[1])),
+                 jnp.asarray(np.stack(cols[2])), *(jnp.asarray(c, jnp.int32)
+                                                   for c in cols[3:]))
+        m = r.new_state
+        for q in range(q_n):
+            d0, d1, cross, homes, is_new = want[q]
+            assert (int(r.d0[q]), int(r.d1[q]), int(r.cross_chunk[q])) == (
+                d0, d1, cross)
+            np.testing.assert_array_equal(np.asarray(r.cross_home[q]), homes)
+            np.testing.assert_array_equal(np.asarray(r.is_new[q]), is_new)
+            _assert_ring_equals(jax.tree.map(lambda x, q=q: x[q], m), rings[q])
+
+
+def _advance(m, rng, frames, t0):
+    step = jax.jit(match_and_update)
+    for t in range(t0, t0 + frames):
+        boxes, feats, valid, vid, fid, cid = _frame(rng, t)
+        m = step(m, jnp.asarray(boxes), jnp.asarray(feats), jnp.asarray(valid),
+                 jnp.int32(vid), jnp.int32(fid), jnp.int32(cid)).new_state
+    return m
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("capacity", [16, 256])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_merge_matches_row_major_model_over_wrapping_windows(seed, capacity,
+                                                             batched):
+    """Appends land at dst's cursor in src's insertion order and bumps to
+    snapshot entries add, exactly as the slot-by-slot model does, with
+    both the source window and the destination window wrapping."""
+    rng = np.random.default_rng(seed)
+    snap = _advance(_ring_at(capacity, 0), rng, 6, 0)
+    snap = dataclasses.replace(snap, cursor=jnp.int32(capacity - 3))
+    src, t = snap, 100
+    while (int(src.cursor) - int(snap.cursor)) % capacity < 4:
+        src, t = _advance(src, rng, 1, t), t + 1
+    dst = _advance(snap, rng, 3, 200)
+    dst = dataclasses.replace(dst, cursor=jnp.int32(capacity - 2))
+    want = _np_merge(_rows(dst), _rows(src), _rows(snap))
+    n_new = (want["cursor"] - int(dst.cursor)) % capacity
+    assert 4 <= n_new < capacity and want["cursor"] < int(dst.cursor)
+    assert int(src.cursor) < int(snap.cursor)          # both windows wrap
+    if batched:
+        stack = lambda *ms: jax.tree.map(lambda *x: jnp.stack(x), *ms)
+        other = _ring_at(capacity, 1)
+        got = jax.jit(jax.vmap(merge_matcher))(
+            stack(dst, other), stack(src, other), stack(snap, other))
+        _assert_ring_equals(jax.tree.map(lambda x: x[1], got), _rows(other))
+        got = jax.tree.map(lambda x: x[0], got)
+    else:
+        got = merge_matcher(dst, src, snap)
+    _assert_ring_equals(got, want)
+
+
+def test_merge_window_wraps_in_both_rings():
+    """Hand-built: src inserted slots 6, 7, 0 of an 8-ring since the
+    snapshot, dst's cursor is 7, so they land in dst slots 7, 0, 1."""
+    cap = 8
+    snap = _ring_at(cap, 6)
+    src = _insert_n(snap, 3)
+    dst = _ring_at(cap, 7)
+    merged = merge_matcher(dst, src, snap)
+    np.testing.assert_array_equal(
+        np.asarray(merged.frame)[[7, 0, 1]], np.asarray(src.frame)[[6, 7, 0]])
+    np.testing.assert_array_equal(
+        np.asarray(merged.boxes)[:, [7, 0, 1]], np.asarray(src.boxes)[:, [6, 7, 0]])
+    assert int((merged.times_seen > 0).sum()) == 3 and int(merged.cursor) == 2
+
+
+def test_result_log_rows_are_first_sightings():
+    """Spilled entries come back as host rows boxes [k, 4], feats [k, F]:
+    each the box and feature of the detection that first found it."""
+    cap, f_n = 4, 8
+    rng = np.random.default_rng(3)
+    m = init_matcher(max_results=cap, feat_dim=f_n)
+    log, first = ResultLog(), {}
+    step = jax.jit(match_and_update)
+    for i in range(10):
+        box = np.asarray([_box(0.05 + 0.08 * i, 0.1)], np.float32)
+        feat = rng.normal(size=(1, f_n)).astype(np.float32)
+        first[i * 2000] = (box[0], feat[0])
+        log.spill(m, eviction_mask(m, 1))
+        m = step(m, jnp.asarray(box), jnp.asarray(feat), jnp.ones((1,), bool),
+                 jnp.int32(0), jnp.int32(i * 2000), jnp.int32(0)).new_state
+    rows = log.as_arrays()
+    assert len(log) == 10 - cap
+    assert rows["boxes"].shape == (len(log), 4)
+    assert rows["feats"].shape == (len(log), f_n)
+    for j, frame in enumerate(rows["frame"]):
+        np.testing.assert_array_equal(rows["boxes"][j], first[int(frame)][0])
+        np.testing.assert_array_equal(rows["feats"][j], first[int(frame)][1])
+    assert sorted(rows["frame"]) == [i * 2000 for i in range(10 - cap)]
