@@ -20,9 +20,10 @@ The detector is the seeded oracle of ``sim/oracle.py``.
     Wilson–Hilferty argmax on the same normals.
   * Phase C — the service: 4 tenants submitted and drained over dashcam.
   * ``--four-chips`` — 8 queries × 4 shards through the composed
-    ``multi_sharded`` lowering against the 8 solo ``sharded`` runs on the
-    same mesh: per query, step and results must be bit-identical
-    (DESIGN.md §10), and nothing else runs.
+    ``multi_sharded`` lowering against 8 single-query mesh plans on the
+    same mesh, each the same lowering at Q = 1: per query, step and
+    results must be bit-identical, since sharing never changes a query's
+    values (DESIGN.md §10), and nothing else runs.
 
 Exits non-zero, printing no result line, when JAX finds no TPU (there is
 no CPU fallback) or when any check fails.  Wall and compile seconds and
@@ -324,7 +325,8 @@ def phase_c() -> None:
 
 
 def phase_four_chips() -> None:
-    """Q=8 × S=4 composed lowering vs 8 solo sharded runs on one mesh."""
+    """Q=8 × S=4 composed lowering vs 8 single-query mesh plans (the same
+    lowering at Q = 1) on one mesh."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -399,17 +401,18 @@ def phase_four_chips() -> None:
             res.steps[q], res.results[q])
         same &= q_same
         print(f"  query {q}: composed {res.steps[q]} steps / "
-              f"{res.results[q]} results, solo sharded {solo.steps[0]} "
+              f"{res.results[q]} results, alone {solo.steps[0]} "
               f"steps / {solo.results[0]} results; sampler statistics "
               f"{'equal' if stats_same else 'DIFFER'}")
     check(same, "per query, composed step and results are bit-identical "
-                "to the solo sharded run")
+                "to the query's own mesh plan")
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the 4-chip composed-vs-solo sharded phase")
+                    help="run only the 4-chip composed-vs-single-query "
+                         "mesh phase")
     args = ap.parse_args()
 
     import jax

@@ -39,7 +39,6 @@ from repro.core.exsample import (
     exsample_batch_step,
     run_search,
     run_search_scan,
-    run_search_sharded,
     run_search_multi,
 )
 from repro.core.plan import (
@@ -49,11 +48,7 @@ from repro.core.plan import (
     PlanValueError,
     SearchPlan,
 )
-from repro.core.runtime import (
-    AsyncMultiSearchDriver,
-    AsyncSearchDriver,
-    MatcherRingOverflow,
-)
+from repro.core.runtime import AsyncMultiSearchDriver
 from repro.core.executor import (
     LoweredPlan,
     SearchResult,
@@ -70,10 +65,10 @@ __all__ = [
     "MatcherState", "init_matcher", "init_matcher_multi", "match_and_update",
     "merge_matcher", "merge_matcher_checked", "pairwise_iou",
     "ResultLog", "eviction_mask",
-    "AsyncSearchDriver", "AsyncMultiSearchDriver", "MatcherRingOverflow",
+    "AsyncMultiSearchDriver",
     "ExSampleCarry", "init_carry", "init_carry_multi", "stack_carries",
     "exsample_step", "exsample_batch_step",
-    "run_search", "run_search_scan", "run_search_sharded", "run_search_multi",
+    "run_search", "run_search_scan", "run_search_multi",
     "SearchPlan", "Execution", "PlanError", "PlanValueError",
     "PlanCompatibilityError", "LoweredPlan", "SearchResult", "SearchStats",
     "lower", "run_search_multi_sharded",

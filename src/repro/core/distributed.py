@@ -43,9 +43,8 @@ def shard_sampler_state(state: SamplerState, mesh: Mesh, axis: str = "data"):
 def pad_chunks(state: SamplerState, multiple: int) -> SamplerState:
     """Pad chunk arrays to a multiple of the shard count with exhausted
     dummy chunks (frames=0 ⇒ never selected).  Pads the LAST axis, so the
-    same helper serves the solo sharded driver ([M] stats) and the
-    composed multi-query driver ([Q, M] stats) — one fill-value contract
-    for both (the composed bit-parity tests pin it)."""
+    same helper serves ``distributed_choose`` ([M] stats) and the mesh
+    search loop ([Q, M] stats) — one fill-value contract for both."""
     m = state.n1.shape[-1]
     pad = (-m) % multiple
     if pad == 0:
@@ -63,55 +62,6 @@ def pad_chunks(state: SamplerState, multiple: int) -> SamplerState:
     )
 
 
-def local_cohort_winners(
-    key: jax.Array,
-    alpha_l: jax.Array,      # f32[local_m] — this shard's slice
-    beta_l: jax.Array,       # f32[local_m]
-    exhausted_l: jax.Array,  # bool[local_m]
-    n_l: jax.Array,          # f32[local_m] — samples drawn per local chunk
-    *,
-    axis: str,
-    cohorts: int,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-shard body of the globally-consistent Thompson choice — called
-    INSIDE ``shard_map`` (by ``distributed_choose`` and by the sharded
-    search driver's resident loop, which cannot nest another shard_map).
-
-    Every shard draws WH-approximate gamma scores for its local chunks and
-    reduces to its per-cohort local winner; the (score, global index,
-    winner's n) triples are all-gathered and the global argmax is computed
-    redundantly on all shards (deterministic).  Collective volume is
-    O(cohorts × |shards|) scalars.  Returns replicated
-    (i32[cohorts] global chunk ids, f32[cohorts] winning scores — −inf iff
-    every chunk everywhere is exhausted, f32[cohorts] the owning shard's
-    sample count for each winner — the random+ rank base).
-    """
-    local_m = alpha_l.shape[0]
-    shard_id = jax.lax.axis_index(axis)
-    # decorrelate shards; fold_in is cheap and deterministic
-    k = jax.random.fold_in(key, shard_id)
-    z = jax.random.normal(k, (cohorts, local_m), alpha_l.dtype)
-    scores = wilson_hilferty(alpha_l[None, :], z) / beta_l[None, :]
-    scores = jnp.where(exhausted_l[None, :], -jnp.inf, scores)
-    local_best = jnp.argmax(scores, axis=-1)                    # [C]
-    local_score = jnp.take_along_axis(
-        scores, local_best[:, None], axis=-1
-    )[:, 0]                                                     # [C]
-    global_idx = shard_id * local_m + local_best
-    local_n = n_l[local_best]
-    # gather winners from every shard: [shards, C]
-    all_scores = jax.lax.all_gather(local_score, axis)
-    all_idx = jax.lax.all_gather(global_idx, axis)
-    all_n = jax.lax.all_gather(local_n, axis)
-    win = jnp.argmax(all_scores, axis=0)                        # [C]
-    pick = lambda a: jnp.take_along_axis(a, win[None, :], axis=0)[0]
-    return (
-        pick(all_idx).astype(jnp.int32),
-        pick(all_scores),
-        pick(all_n),
-    )
-
-
 def local_cohort_winners_batched(
     keys: jax.Array,         # key[Q] — one PRNG key per query
     alpha_l: jax.Array,      # f32[Q, local_m] — this shard's slice, per query
@@ -122,17 +72,22 @@ def local_cohort_winners_batched(
     axis: str,
     cohorts: int,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Leading-[Q] ``local_cohort_winners`` for the composed multi-query ×
-    sharded driver (DESIGN.md §10): Q queries' globally-consistent Thompson
-    choices in ONE pass of collectives — the all-gathers carry [S, Q, C]
-    instead of vmapping a collective per query.
+    """Per-shard body of the globally-consistent Thompson choice, for Q
+    queries at once — called INSIDE ``shard_map`` (by ``distributed_choose``
+    at Q = 1 and by the mesh search loop, which cannot nest another
+    shard_map).
 
-    Contract: row q is bit-identical to ``local_cohort_winners(keys[q],
-    alpha_l[q], …)`` — same per-query fold_in(key, shard_id) decorrelation,
-    same WH draw shapes, same replicated global argmax — which is what
-    makes the composed driver's per-query parity with
-    the solo sharded driver testable.  Returns replicated
-    (i32[Q, cohorts], f32[Q, cohorts] scores, f32[Q, cohorts] rank bases).
+    Every shard draws WH-approximate gamma scores for its local chunks
+    (keys decorrelated per shard by ``fold_in(key, shard_id)``) and reduces
+    to its per-cohort local winner; the (score, global index, winner's n)
+    triples are all-gathered — ONE pass of collectives carrying
+    ``[S, Q, C]`` — and the global argmax is computed redundantly on all
+    shards (deterministic).  Row q depends only on ``keys[q]`` and row q
+    of the statistics, which is what makes a query's mesh trajectory
+    independent of the other queries in the batch.  Returns replicated
+    (i32[Q, cohorts] global chunk ids, f32[Q, cohorts] winning scores —
+    −inf iff every chunk everywhere is exhausted, f32[Q, cohorts] the
+    owning shard's sample count for each winner — the random+ rank base).
     """
     local_m = alpha_l.shape[-1]
     shard_id = jax.lax.axis_index(axis)
@@ -171,8 +126,9 @@ def distributed_choose(
     axis: str = "data",
 ) -> jax.Array:
     """Globally-consistent batched Thompson choice over sharded stats
-    (the standalone shard_map wrapper around ``local_cohort_winners``).
-    Returns replicated i32[cohorts] of *global* chunk ids.
+    (the standalone shard_map wrapper around
+    ``local_cohort_winners_batched`` at Q = 1).  Returns replicated
+    i32[cohorts] of *global* chunk ids.
     """
     num_shards = mesh.shape[axis]
     m = state.num_chunks
@@ -182,10 +138,11 @@ def distributed_choose(
     exhausted = state.exhausted()
 
     def local_choice(key, alpha_l, beta_l, exhausted_l, n_l):
-        idx, _, _ = local_cohort_winners(
-            key, alpha_l, beta_l, exhausted_l, n_l, axis=axis, cohorts=cohorts
+        idx, _, _ = local_cohort_winners_batched(
+            key[None], alpha_l[None], beta_l[None], exhausted_l[None],
+            n_l[None], axis=axis, cohorts=cohorts,
         )
-        return idx
+        return idx[0]
 
     specs = P(axis)
     choice = jax.shard_map(
@@ -218,15 +175,3 @@ def merge_deltas(
     d1 = jnp.atleast_2d(delta_n1).sum(axis=0)
     dn = jnp.atleast_2d(delta_n).sum(axis=0)
     return _dc.replace(state, n1=state.n1 + d1, n=state.n + dn)
-
-
-def straggler_robust_rounds(
-    worker_latencies: jnp.ndarray, sync_every: int, round_time: float
-) -> jnp.ndarray:
-    """Analytic model used by tests/benchmarks: with barrier-per-round, the
-    round time is max(latencies); with commutative async merge the effective
-    round time is mean(latencies) + sync cost amortized over sync_every.
-    Returns (barrier_time, async_time) per round."""
-    barrier = jnp.max(worker_latencies)
-    async_ = jnp.mean(worker_latencies) + round_time / max(sync_every, 1)
-    return jnp.stack([barrier, async_])

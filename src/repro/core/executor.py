@@ -1,19 +1,20 @@
 """SearchPlan lowering + execution (DESIGN.md §10).
 
 ``lower(plan)`` resolves a declarative :class:`~repro.core.plan.SearchPlan`
-to ONE driver (host | scan | async | sharded | multi | multi_sharded |
-async_multi) and ``LoweredPlan.run`` executes it, returning a structured
+to ONE driver (host | scan | multi | multi_sharded | async_multi) and
+``LoweredPlan.run`` executes it, returning a structured
 :class:`SearchResult` — per-query step/results/trace plus uniform
 :class:`SearchStats` (detector invocations, cache hit rate, matcher merge
 high-water / overflow, async scheduling counters) instead of the raw carry
 tuples and ad-hoc stats dicts the legacy ``run_search_*`` entry points
-returned.
+returned.  A single-query plan that resolves onto a Q-axis lowering (a
+mesh or async plan without ``queries_axis``) runs it at Q = 1: ``run``
+stacks its carry to ``[1]`` on the way in and un-stacks it on the way out.
 
-The module also owns the one lowering the legacy API could not express:
-``run_search_multi_sharded`` — the §9 leading-[Q] multi-query carry lifted
-into the §8 ``shard_map`` loop, so Q queries AND M-sharded Thompson
-statistics share one deduplicated (and per-shard cached) detector pass per
-round across the mesh.
+The module also owns the mesh lowering, ``run_search_multi_sharded`` — the
+§9 leading-[Q] multi-query carry in the §8 ``shard_map`` loop, so Q
+queries AND M-sharded Thompson statistics share one deduplicated (and
+per-shard cached) detector pass per round across the mesh.
 """
 from __future__ import annotations
 
@@ -33,7 +34,7 @@ from repro.core.exsample import (
     _host_search,
     _multi_search,
     _scan_search,
-    _sharded_search,
+    stack_carries,
 )
 from repro.core.matcher import MatcherState, match_and_update, merge_matcher
 from repro.core.plan import PlanError, SearchPlan
@@ -47,17 +48,18 @@ class SearchStats:
 
     * ``detector_invocations`` / ``cache_hits`` — detector economics: the
       Q-axis lowerings count unique, uncached frames actually detected;
-      single-query lowerings pay one invocation per sampled frame.
+      ``host`` and ``scan`` pay one invocation per sampled frame.
     * ``detector_lanes`` — lanes the detector evaluated on the device,
       padding and already-resolved frames included: rounds × Q × C on the
-      multi lowerings, slot batches × ``slots_per_batch`` × C on the async
-      one, one per cohort slot on the single-query lowerings.
+      multi lowerings (a lifted single-query plan counts Q = 1), slot
+      batches × ``slots_per_batch`` × C on the async one, one per cohort
+      slot on ``host`` and ``scan``.
     * ``rounds`` — synchronized choose→detect rounds (Q-axis lowerings).
     * ``frames_sampled`` — Σ per-query steps (what sequential runs pay).
     * ``merge_high_water`` / ``merge_overflow`` — matcher ring pressure
       from ``merge_matcher_checked`` semantics: the largest number of
       insertions folded in a single merge window, and whether any window
-      reached ring capacity (sharded + composed syncs, async merges).
+      reached ring capacity (mesh syncs, async merges).
     * ``merges`` / ``reissues`` / ``duplicate_drops`` — async scheduler
       counters (DESIGN.md §5/§11).
     * ``results_spilled`` — ring-evicted results drained to the host
@@ -166,6 +168,14 @@ class LoweredPlan:
     kind: str
     method: str
 
+    @property
+    def q_axis(self) -> bool:
+        """True when ``run`` takes and returns a leading-[Q] carry: the plan
+        asks for the Q axis (``queries > 1`` or ``queries_axis``).  Without
+        it a plan takes a single-query carry, also where it resolved onto
+        a Q-axis lowering (a mesh or async plan), which then runs at Q = 1."""
+        return self.plan.queries > 1 or self.plan.execution.queries_axis
+
     def run(
         self,
         carry: ExSampleCarry,
@@ -177,27 +187,32 @@ class LoweredPlan:
         index=None,
     ) -> SearchResult:
         p, ex = self.plan, self.plan.execution
-        multi = self.kind in ("multi", "multi_sharded", "async_multi")
+        q_axis = self.q_axis
         ndim = jnp.ndim(carry.step)
-        if multi and ndim != 1:
+        if q_axis and ndim != 1:
             raise PlanError(
                 f"the {self.kind!r} lowering needs a leading-[Q] carry "
                 "(init_carry_multi / stack_carries); got a single-query "
                 "carry", field="queries")
-        if multi and int(carry.step.shape[0]) != p.queries:
+        if q_axis and int(carry.step.shape[0]) != p.queries:
             raise PlanError(
                 f"carry has {int(carry.step.shape[0])} queries but the plan "
                 f"declares queries={p.queries}", field="queries")
-        if not multi and ndim != 0:
+        if not q_axis and ndim != 0:
             raise PlanError(
-                f"the {self.kind!r} lowering is single-query but the carry "
-                "has a leading axis; set queries/queries_axis on the plan",
-                field="queries")
-        if select is not None and not multi:
+                f"the plan is single-query ({self.kind!r} lowering) but the "
+                "carry has a leading axis; set queries/queries_axis on the "
+                "plan", field="queries")
+        if select is not None and not q_axis:
             raise PlanError(
                 "select predicates ride on the shared Q-axis detector pass; "
-                "this plan lowers to the single-query "
-                f"{self.kind!r} driver", field="queries")
+                "this plan takes a single-query carry (set "
+                "queries_axis=True, valid at queries=1)", field="queries")
+        # the one lift: a single-query plan on a Q-axis lowering runs it at
+        # Q = 1 and hands back a single-query carry
+        lift = not q_axis and self.kind not in ("host", "scan")
+        if lift:
+            carry = stack_carries([carry])
         cache = ex.cache
         if cache == -1:
             cache = chunks.total_frames
@@ -274,6 +289,8 @@ class LoweredPlan:
                     persisted_detections=int(persisted),
                     warm_rounds_saved=warm_rounds_saved,
                 )
+            if lift:
+                out = jax.tree.map(lambda x: x[0], out)
             return self._package(out, traces, stats)
 
         if self.kind in ("host", "scan"):
@@ -289,28 +306,6 @@ class LoweredPlan:
                 frames_sampled=step, **_matcher_totals(out),
             )
             return finish(out, [trace], stats)
-
-        if self.kind == "async":
-            from repro.core.runtime import AsyncSearchDriver
-
-            driver = AsyncSearchDriver(
-                carry, chunks, detector, cohort_size=p.cohorts,
-                num_workers=ex.async_workers, result_limit=limit0,
-                max_frames=p.max_steps,
-            )
-            out = driver.run()
-            step = int(out.step)
-            stats = SearchStats(
-                detector_invocations=step, detector_lanes=step,
-                frames_sampled=step,
-                merge_high_water=int(driver.stats["merge_high_water"]),
-                merges=int(driver.stats["merges"]),
-                reissues=int(driver.stats["reissues"]),
-                duplicate_drops=int(driver.stats["duplicate_drops"]),
-                results_spilled=int(driver.stats["spilled"]),
-                **_matcher_totals(out),
-            )
-            return finish(out, [[(step, int(out.results))]], stats)
 
         if self.kind == "async_multi":
             from repro.core.runtime import AsyncMultiSearchDriver
@@ -359,24 +354,6 @@ class LoweredPlan:
                     f"{ex.shards} {ex.axis!r} shards — the validated "
                     "cohorts/shards geometry must match what executes",
                     field="shards")
-
-        if self.kind == "sharded":
-            out, trace, sh = _sharded_search(
-                carry, chunks, mesh=mesh, detector=detector,
-                result_limit=limit0, max_steps=p.max_steps,
-                cohorts=p.cohorts, sync_every=ex.sync_every, axis=ex.axis,
-            )
-            step = int(out.step)
-            stats = SearchStats(
-                detector_invocations=step,
-                detector_lanes=sh["merges"] * ex.sync_every * p.cohorts,
-                frames_sampled=step,
-                merge_high_water=sh["merge_high_water"],
-                merge_overflow=sh["merge_overflow"],
-                merges=sh["merges"],
-                **_matcher_totals(out),
-            )
-            return finish(out, [trace], stats)
 
         limits_arr = jnp.asarray([int(v) for v in limits], jnp.int32)
         if self.kind == "multi":
@@ -491,8 +468,8 @@ def _search_multi_sharded_device(
 
     Parity contract (tests/test_plan_parity.py): with a deterministic
     detector, query q's trajectory — (step, results), trace, sampler
-    statistics, final key — is bit-identical to its own solo
-    ``run_search_sharded`` run on the same mesh with the same key, at ANY
+    statistics, final key — is bit-identical to its own single-query mesh
+    plan (this loop at Q = 1) on the same mesh with the same key, at ANY
     Q: cross-query dedup and caching change WHICH detector invocations
     happen, never the values a query consumes.
     """
@@ -901,8 +878,9 @@ def run_search_multi_sharded(
     GLOBAL per-round batch (default: one frame per shard) and must divide
     over the mesh; chunk statistics are padded to the shard count with
     exhausted dummies and trimmed on the way out.  Returns
-    ``(carries', traces, stats)`` with the same per-query trace semantics
-    as the solo sharded driver and §9-style sharing stats.
+    ``(carries', traces, stats)``: one trace entry per sync window per
+    query (plus a final one where the window trace would miss the end
+    state) and §9-style sharing stats.
 
     ``cache`` overrides internal cache construction (a repository-index
     preload, DESIGN.md §13); ``warm_tag`` — the preload's tag snapshot —

@@ -5,18 +5,16 @@ pytree so it can be (a) jitted and scanned for simulation-scale benchmarks,
 (b) driven frame-by-frame from the host around a real serving stack, and
 (c) sharded (see ``repro.core.distributed``).
 
-Four driver implementations share the step/process machinery (DESIGN.md
-§7-§9): ``_host_search`` is the host reference loop (one dispatch + one
+Three driver implementations share the step/process machinery (DESIGN.md
+§7, §9): ``_host_search`` is the host reference loop (one dispatch + one
 sync per step), ``_scan_search`` is the device-resident
 ``lax.while_loop`` production driver — identical (step, results)
-trajectory, one host sync total — ``_sharded_search`` is the mesh-scale
-variant: the same resident loop under ``shard_map`` with chunk
-statistics sharded over the ``data`` axis and per-shard matchers merged
-every ``sync_every`` rounds (eventual-consistency Thompson, DESIGN.md
-§8) — and ``_multi_search`` advances Q concurrent queries (leading-[Q]
-carry) sharing one deduplicated + cached detector pass per round
-(DESIGN.md §9).  The ONE public entry point over all of them (plus the
-composed Q×shards lowering and the async runtime) is
+trajectory, one host sync total — and ``_multi_search`` advances Q
+concurrent queries (leading-[Q] carry) sharing one deduplicated + cached
+detector pass per round (DESIGN.md §9).  The mesh loop
+(``repro.core.executor.run_search_multi_sharded``, DESIGN.md §8/§10) and
+the async slot runtime (``repro.core.runtime``, DESIGN.md §11) reuse the
+Q-axis round.  The ONE public entry point over all of them is
 ``repro.core.plan.SearchPlan`` (DESIGN.md §10); the legacy
 ``run_search*`` functions at the bottom of this module are deprecated
 shims over the equivalent plans.
@@ -38,12 +36,11 @@ import numpy as np
 
 from repro.core import thompson
 from repro.core.chunks import ChunkIndex, randomplus_frame
-from repro.core.matcher import MatcherState, match_and_update, merge_matcher
+from repro.core.matcher import MatcherState, match_and_update
 from repro.core.state import (
     SamplerState,
     apply_cross_chunk_decrement,
     apply_update,
-    decrement_homes,
 )
 
 if TYPE_CHECKING:  # avoid core ↔ sim import cycle; Detections is a pytree
@@ -309,363 +306,6 @@ def _scan_search(
         buf_host = np.asarray(buf)  # the single device→host sync
         trace = [(int(s), int(r)) for s, r in buf_host[: int(n)]]
     return carry, trace
-
-
-# ---------------------------------------------------------------------------
-# Sharded device-resident driver (paper §3.7.1 distributed, DESIGN.md §8)
-# ---------------------------------------------------------------------------
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "mesh", "axis", "detector", "cohorts", "sync_every", "max_steps",
-        "alpha0", "beta0",
-    ),
-)
-def _search_sharded_device(
-    key: jax.Array,
-    step0: jax.Array,
-    results0: jax.Array,
-    n1: jax.Array,          # f32[M] — sharded over `axis` (M % shards == 0)
-    n: jax.Array,           # f32[M] — sharded
-    frames: jax.Array,      # i32[M] — sharded
-    matcher: MatcherState,  # replicated
-    chunks: ChunkIndex,     # replicated
-    result_limit: jax.Array,
-    *,
-    mesh,
-    axis: str,
-    detector: DetectorFn,
-    cohorts: int,
-    sync_every: int,
-    max_steps: int,
-    alpha0: float,
-    beta0: float,
-):
-    """Mesh-resident search loop (DESIGN.md §8).
-
-    One ``shard_map`` call contains the whole search: every shard owns an
-    M/S slice of the chunk statistics plus a full-width ``[M]`` *delta*
-    buffer of its unsynced updates (updates can target remote chunks via
-    §3.4 cross-chunk decrements and remote-cohort processing) and a
-    shard-local matcher.  Per round, the globally-consistent Thompson
-    choice (``local_cohort_winners`` — all-gather of per-shard winners
-    carrying the owner's sample count as the random+ rank base) picks
-    ``cohorts`` chunks; shard s processes cohorts
-    ``[s·C/S, (s+1)·C/S)``.  Every ``sync_every`` rounds the deltas merge
-    with one ``psum`` (additive ⇒ exact regardless of interleaving,
-    §3.7.1) and the S matcher states fold pairwise through
-    ``merge_matcher`` against the shared snapshot, which then becomes the
-    new snapshot on every shard.  Termination is evaluated at sync
-    boundaries only — the run can overshoot ``result_limit`` by at most
-    one sync window, the eventual-consistency analogue of the batching
-    caveat.  The trace records (step, results) at every sync; the host
-    syncs once, after the loop exits.
-    """
-    from repro.core.distributed import local_cohort_winners
-    from jax.sharding import PartitionSpec as P
-
-    num_shards = mesh.shape[axis]
-    m = n1.shape[0]
-    local_m = m // num_shards
-    per_shard = cohorts // num_shards
-    per_sync = cohorts * sync_every
-    # one trace entry per sync, bounded so a huge max_steps budget doesn't
-    # carry a huge buffer through the loop; past the cap, intermediate
-    # syncs drop and the final state overwrites the last slot
-    cap = min(max_steps // max(per_sync, 1) + 3, 4096)
-
-    def shard_fn(key, step0, results0, n1_l, n_l, frames_l, matcher0, chks, rlimit):
-        shard_id = jax.lax.axis_index(axis)
-        fdt = n_l.dtype
-        my_slice = lambda full: jax.lax.dynamic_slice(
-            full, (shard_id * local_m,), (local_m,)
-        )
-
-        def one_round(base_n1, base_n, rstate):
-            # base_* are the while-carry's CURRENT synced slices — closing
-            # over shard_fn's arguments instead would pin every round's
-            # view (and random+ ranks) to the initial statistics
-            key, delta_n1, delta_n, foreign, matcher, lstep, lres = rstate
-            key, k_choice, k_det = jax.random.split(key, 3)
-            # this shard's view: authoritative slice + own pending deltas
-            # (other shards' deltas become visible at the next sync)
-            view = SamplerState(
-                n1=base_n1 + my_slice(delta_n1),
-                n=base_n + my_slice(delta_n),
-                frames=frames_l,
-                alpha0=alpha0,
-                beta0=beta0,
-            )
-            with jax.named_scope("choose"):
-                a_l, b_l = thompson.gamma_params(view)
-                c_ids, c_scores, c_n = local_cohort_winners(
-                    k_choice, a_l, b_l, view.exhausted(), view.n,
-                    axis=axis, cohorts=cohorts,
-                )
-            # Within-window random+ rank dedup.  Thompson concentrates on
-            # hot chunks, so several cohorts routinely pick the SAME chunk
-            # in one round; the owner's view gives them all the same rank
-            # base, and colliding ranks resample the identical frame on
-            # different shards (duplicated results, wasted detector work).
-            # The winner list is replicated, so every shard computes the
-            # same fix redundantly: cohort g adds its within-round
-            # occurrence index, and `foreign` counts earlier-round picks
-            # by NON-owner shards (the owner's own picks are already in
-            # its view).  Every pick of a chunk inside one sync window
-            # therefore gets a distinct rank.
-            live_c = jnp.isfinite(c_scores)                      # [C]
-            owner = c_ids // local_m                             # [C]
-            pshard = jnp.arange(cohorts, dtype=jnp.int32) // per_shard
-            same_before = jnp.tril(c_ids[:, None] == c_ids[None, :], -1)
-            occ = jnp.sum(same_before & live_c[None, :], axis=1)  # [C]
-            ranks = (c_n + foreign[c_ids].astype(fdt) + occ.astype(fdt)).astype(
-                jnp.int32
-            )
-            foreign = foreign.at[c_ids].add(
-                ((pshard != owner) & live_c).astype(jnp.int32)
-            )
-
-            def proc(j, pst):
-                delta_n1, delta_n, matcher, lstep, lres = pst
-                g = shard_id * per_shard + j          # my global cohort index
-                cid = c_ids[g]
-                # −inf winner ⇔ every chunk everywhere exhausted: run the
-                # (harmless) detector but gate every state update off
-                live = live_c[g]
-                frame_id = randomplus_frame(chks, cid, ranks[g])
-                with jax.named_scope("detect"):
-                    dets = detector(jax.random.fold_in(k_det, g), frame_id)
-                mres = match_and_update(
-                    matcher,
-                    dets.boxes,
-                    dets.feats,
-                    dets.valid & live,
-                    chks.video_id[cid],
-                    frame_id,
-                    cid,
-                )
-                # §3.4: cross-chunk d₁ decrements the HOME chunk's N¹ — the
-                # home chunk may live on another shard, which is exactly why
-                # the delta buffer is full-width [M]
-                d1_local = mres.d1 - mres.cross_chunk
-                upd = live.astype(delta_n1.dtype)
-                with jax.named_scope("update"):
-                    delta_n1 = delta_n1.at[cid].add(
-                        (mres.d0 - d1_local).astype(delta_n1.dtype) * upd
-                    )
-                    delta_n = delta_n.at[cid].add(upd)
-                    delta_n1 = decrement_homes(delta_n1, mres.cross_home)
-                return (
-                    delta_n1,
-                    delta_n,
-                    mres.new_state,
-                    lstep + live.astype(jnp.int32),
-                    lres + mres.d0,
-                )
-
-            delta_n1, delta_n, matcher, lstep, lres = jax.lax.fori_loop(
-                0, per_shard, proc, (delta_n1, delta_n, matcher, lstep, lres)
-            )
-            return (key, delta_n1, delta_n, foreign, matcher, lstep, lres)
-
-        def all_exhausted(n_l):
-            exh = jnp.all(n_l >= frames_l.astype(fdt)).astype(jnp.int32)
-            return jax.lax.psum(exh, axis) == num_shards
-
-        def body(st):
-            (key, n1_l, n_l, matcher, snap, step, results, buf, tn, hw, ov,
-             windows, cont) = st
-            rst = (
-                key,
-                jnp.zeros((m,), n1_l.dtype),
-                jnp.zeros((m,), fdt),
-                jnp.zeros((m,), jnp.int32),   # foreign-pick counts, replicated
-                matcher,
-                jnp.zeros((), jnp.int32),
-                jnp.zeros((), jnp.int32),
-            )
-            key, dn1, dn, _foreign, matcher, lstep, lres = jax.lax.fori_loop(
-                0, sync_every, lambda r, s: one_round(n1_l, n_l, s), rst
-            )
-            # ---- sampler sync: one psum, exact by additivity (§3.7.1) ----
-            n1_l = n1_l + my_slice(jax.lax.psum(dn1, axis))
-            n_l = n_l + my_slice(jax.lax.psum(dn, axis))
-            # ---- matcher sync: fold every shard's matcher against the
-            # shared snapshot; all shards compute the identical merged
-            # state, which becomes the next snapshot ----
-            stacked = jax.tree.map(lambda x: jax.lax.all_gather(x, axis), matcher)
-            # Exact cross-shard d₁ dedup: the shards' matchers are replicas
-            # of the snapshot, so k shards can each fire the SAME entry's
-            # seen-once → seen-twice transition inside one window and the
-            # psum above then decremented the entry's home chunk's N¹ k
-            # times for one global transition.  Left uncorrected this
-            # drives N¹ negative repository-wide and flattens the Thompson
-            # posterior into uniform sampling.  The gathered stack is
-            # replicated, so every shard computes the identical k per
-            # snapshot entry and adds back the k−1 over-decrements.
-            same_e = (stacked.video == snap.video[None, :]) & (
-                stacked.frame == snap.frame[None, :]
-            )
-            trans = (
-                same_e
-                & (snap.times_seen[None, :] == 1)
-                & (stacked.times_seen >= 2)
-            )                                                   # [S, R]
-            k = jnp.sum(trans, axis=0)                          # [R]
-            over = jnp.maximum(k - 1, 0).astype(n1_l.dtype)
-            corr = jnp.zeros((m,), n1_l.dtype).at[
-                jnp.where(k > 0, snap.chunk, 0)
-            ].add(jnp.where(k > 0, over, jnp.zeros((), n1_l.dtype)))
-            n1_l = n1_l + my_slice(corr)
-            merged = jax.lax.fori_loop(
-                1,
-                num_shards,
-                lambda s, dst: merge_matcher(
-                    dst, jax.tree.map(lambda x: x[s], stacked), snap
-                ),
-                jax.tree.map(lambda x: x[0], stacked),
-            )
-            # ---- ring-pressure accounting (merge_matcher_checked
-            # semantics): per-shard insertions folded this window; the
-            # gathered stack is replicated so every shard agrees ----
-            inserted = stacked.total_inserted - snap.total_inserted  # [S]
-            hw = jnp.maximum(hw, jnp.max(inserted))
-            ov = ov | jnp.any(inserted >= snap.capacity)
-            # ---- counters / trace / continue flag ----
-            step = step + jax.lax.psum(lstep, axis)
-            results = results + jax.lax.psum(lres, axis)
-            entry = jnp.stack([step, results])
-            buf = buf.at[tn].set(entry, mode="drop")  # index == cap: dropped
-            tn = jnp.minimum(tn + 1, cap)
-            cont = (
-                (results < rlimit)
-                & (step < max_steps)
-                & ~all_exhausted(n_l)
-            )
-            return (key, n1_l, n_l, merged, merged, step, results, buf, tn,
-                    hw, ov, windows + 1, cont)
-
-        cont0 = (
-            (results0 < rlimit)
-            & (step0 < max_steps)
-            & ~all_exhausted(n_l)
-        )
-        init = (
-            key, n1_l, n_l, matcher0, matcher0, step0, results0,
-            jnp.zeros((cap, 2), jnp.int32), jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32), jnp.zeros((), bool),
-            jnp.zeros((), jnp.int32), cont0,
-        )
-        (key, n1_l, n_l, matcher, _snap, step, results, buf, tn, hw, ov,
-         windows, _) = jax.lax.while_loop(lambda st: st[-1], body, init)
-        # every sync already checkpointed itself; write a final entry only
-        # when the trace would otherwise miss the end state — a run whose
-        # very first continue-check failed (empty trace), or one that
-        # outran the buffer cap (overwrite the last slot)
-        idx = jnp.where(
-            (tn == 0) | (tn >= cap), jnp.minimum(tn, cap - 1), cap
-        )
-        buf = buf.at[idx].set(jnp.stack([step, results]), mode="drop")
-        tn = jnp.clip(tn, 1, cap)
-        return n1_l, n_l, matcher, key, step, results, buf, tn, hw, ov, windows
-
-    sh, rep = P(axis), P()
-    return jax.shard_map(
-        shard_fn,
-        mesh=mesh,
-        in_specs=(rep, rep, rep, sh, sh, sh, rep, rep, rep),
-        out_specs=(sh, sh, rep, rep, rep, rep, rep, rep, rep, rep, rep),
-        check_vma=False,
-    )(key, step0, results0, n1, n, frames, matcher, chunks, result_limit)
-
-
-def _sharded_search(
-    carry: ExSampleCarry,
-    chunks: ChunkIndex,
-    *,
-    mesh,
-    detector: DetectorFn,
-    result_limit: int,
-    max_steps: int,
-    cohorts: int | None = None,
-    sync_every: int = 1,
-    axis: str = "data",
-):
-    """Mesh-scale drop-in for the scanned driver (DESIGN.md §8): the full
-    choose → sample → detect → match → update loop device-resident under
-    ``shard_map``, chunk statistics sharded over ``axis``, per-shard
-    matchers merged every ``sync_every`` rounds, one host sync total.
-
-    ``cohorts`` is the GLOBAL batch size per round (default: one frame per
-    shard) and must divide evenly over the mesh's ``axis`` extent; chunk
-    statistics are padded to the shard count with exhausted dummies
-    (``pad_chunks``) and trimmed again on the way out.  The Thompson
-    choice is the Wilson–Hilferty sharded path (DESIGN.md §3) — there is
-    no ``method`` knob here because the exact-Gamma sampler never runs on
-    the resident path.  Statistics match the single-device drivers up to
-    merge staleness: with ``sync_every=1`` every round starts from fully
-    merged state and the trajectory is statistically indistinguishable
-    from ``run_search_scan`` at the same cohort size (±5% result count on
-    the paper configs — asserted by ``benchmarks/bench_sharded.py`` and
-    ``tests/test_sharded_driver.py``).
-    """
-    from repro.core.distributed import pad_chunks, shard_sampler_state
-
-    num_shards = mesh.shape[axis]
-    if cohorts is None:
-        cohorts = num_shards
-    if cohorts < num_shards or cohorts % num_shards:
-        raise ValueError(
-            f"cohorts={cohorts} must be a positive multiple of the "
-            f"{num_shards} '{axis}' shards"
-        )
-    if sync_every < 1:
-        # sync_every == 0 would make the resident while_loop spin forever
-        # (no rounds run, counters never advance, cond stays true)
-        raise ValueError(f"sync_every={sync_every} must be >= 1")
-    m0 = carry.sampler.num_chunks
-    state = pad_chunks(carry.sampler, num_shards)
-    state = shard_sampler_state(state, mesh, axis)
-
-    (n1, n, matcher, key, step, results, buf, tn, hw, ov, windows) = (
-        _search_sharded_device(
-        carry.key,
-        carry.step,
-        carry.results,
-        state.n1,
-        state.n,
-        state.frames,
-        carry.matcher,
-        chunks,
-        jnp.asarray(result_limit, jnp.int32),
-        mesh=mesh,
-        axis=axis,
-        detector=detector,
-        cohorts=cohorts,
-        sync_every=sync_every,
-        max_steps=max_steps,
-        alpha0=carry.sampler.alpha0,
-        beta0=carry.sampler.beta0,
-    ))
-    out = ExSampleCarry(
-        sampler=dataclasses.replace(
-            carry.sampler, n1=n1[:m0], n=n[:m0], frames=carry.sampler.frames
-        ),
-        matcher=matcher,
-        key=key,
-        step=step,
-        results=results,
-    )
-    buf_host = np.asarray(buf)  # the single device→host sync
-    trace = [(int(s), int(r)) for s, r in buf_host[: int(tn)]]
-    stats = {
-        "merge_high_water": int(hw),
-        "merge_overflow": bool(ov),
-        "merges": int(windows),
-    }
-    return out, trace, stats
 
 
 # ---------------------------------------------------------------------------
@@ -1109,7 +749,7 @@ def _multi_search(
 
 
 # ---------------------------------------------------------------------------
-# Deprecated shims — the five legacy entry points now lower through ONE
+# Deprecated shims — the legacy entry points now lower through ONE
 # SearchPlan (repro.core.plan, DESIGN.md §10).  Each shim builds the plan
 # whose home-config lowering is the identical driver, so results stay
 # bit-for-bit what the legacy function returned.
@@ -1171,36 +811,6 @@ def run_search_scan(
         method=method, trace_every=trace_every,
         execution=Execution(strategy="scan"),
     ).run(carry, chunks, detector=detector)
-    return res.carry, res.traces[0]
-
-
-def run_search_sharded(
-    carry: ExSampleCarry,
-    chunks: ChunkIndex,
-    *,
-    mesh,
-    detector: DetectorFn,
-    result_limit: int,
-    max_steps: int,
-    cohorts: int | None = None,
-    sync_every: int = 1,
-    axis: str = "data",
-):
-    """Deprecated shim over ``SearchPlan`` (strategy='sharded'); identical
-    semantics to the legacy mesh-resident driver (DESIGN.md §8).  The
-    caller's ``mesh`` is passed through unchanged."""
-    from repro.core.plan import Execution, SearchPlan
-
-    _warn_deprecated("run_search_sharded")
-    num_shards = mesh.shape[axis]
-    res = SearchPlan(
-        result_limit=result_limit, max_steps=max_steps,
-        cohorts=num_shards if cohorts is None else cohorts,
-        execution=Execution(
-            strategy="sharded", shards=num_shards, axis=axis,
-            sync_every=sync_every,
-        ),
-    ).run(carry, chunks, detector=detector, mesh=mesh)
     return res.carry, res.traces[0]
 
 

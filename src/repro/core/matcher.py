@@ -266,8 +266,8 @@ def merge_stats(dst: MatcherState, src: MatcherState, snap: MatcherState) -> Mer
     capacity, so an overflowing worker silently loses ``capacity·k``
     entries.  The monotone ``total_inserted`` counter makes the true
     insertion count observable — callers surface it as a high-water mark
-    and raise/flag on overflow instead of wrapping (see
-    ``repro.core.runtime.AsyncSearchDriver._merge``)."""
+    and flag overflow instead of wrapping (see the ring-pressure
+    accounting of ``repro.core.executor._search_multi_sharded_device``)."""
     cap = dst.capacity
     inserted = src.total_inserted - snap.total_inserted
     dst_slot_hit = _ring_window(dst.cursor, inserted % cap, cap)

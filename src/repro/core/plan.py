@@ -138,17 +138,16 @@ class Execution:
       (DESIGN.md §10 rules); ``"host"``/``"scan"``/``"sharded"``/``"async"``
       force a driver family.
     * ``shards`` — data-axis mesh extent; ``> 1`` selects the mesh-resident
-      §8 loop (chunk statistics sharded, delta-psum merge schedule).
+      §8 loop (chunk statistics sharded, delta-psum merge schedule), which
+      is the composed Q × shards lowering at any Q.
     * ``queries_axis`` — the carry has a leading ``[Q]`` axis and the §9
       Q-batched machinery (cross-query dedup, one detector pass per round)
       is used even at Q=1.  Implied by ``SearchPlan.queries > 1``.
     * ``sync_every`` — rounds between sampler/matcher merges on the mesh
       paths (eventual-consistency Thompson, §8).
-    * ``async_workers`` — ``> 0`` lowers to the threaded async runtime:
-      the single-query :class:`~repro.core.runtime.AsyncSearchDriver`, or
-      — composed with the Q axis — the slot-based
-      :class:`~repro.core.runtime.AsyncMultiSearchDriver` (DESIGN.md
-      §11).  Cannot combine with mesh sharding.
+    * ``async_workers`` — ``> 0`` lowers to the threaded slot runtime,
+      :class:`~repro.core.runtime.AsyncMultiSearchDriver` (DESIGN.md §11),
+      at any Q.  Cannot combine with mesh sharding.
     * ``cache`` — :class:`~repro.serve.batcher.DetectionCache` capacity:
       ``None`` disables, ``-1`` sizes it to the repository at run time,
       positive values trade memory for evictions.  Requires the Q-axis
@@ -235,10 +234,12 @@ class SearchPlan:
 
     def resolve(self) -> tuple[str, str]:
         """Validate and return ``(kind, method)``: the lowering target (one
-        of ``host | scan | async | sharded | multi | multi_sharded |
-        async_multi``) and the resolved Thompson method.  Raises typed
-        :class:`PlanError`\\ s with actionable messages on invalid or
-        incompatible options."""
+        of ``host | scan | multi | multi_sharded | async_multi``) and the
+        resolved Thompson method.  A sharded or async plan resolves onto
+        its Q-axis lowering whatever its Q; without the Q axis it still
+        takes a single-query carry, which the executor lifts to ``[1]``
+        (DESIGN.md §10).  Raises typed :class:`PlanError`\\ s with
+        actionable messages on invalid or incompatible options."""
         ex = self.execution
 
         # -- per-option value checks ---------------------------------------
@@ -357,14 +358,6 @@ class SearchPlan:
                     "strategies — pick one (shards>1 already runs "
                     "barrier-free via the §8 merge schedule)",
                     field="async_workers")
-            if self.trace_every > 0 and not multi:
-                raise PlanCompatibilityError(
-                    "async_workers>0 on a single-query carry records no "
-                    "recall trace (merges land out of order); set "
-                    "trace_every=0, or compose with queries_axis=True — "
-                    "the slot scheduler serializes per-query rounds so "
-                    "per-query traces are exact (DESIGN.md §11)",
-                    field="trace_every")
             if ex.strategy not in ("auto", "async"):
                 raise PlanCompatibilityError(
                     f"async_workers={ex.async_workers} conflicts with "
@@ -407,19 +400,17 @@ class SearchPlan:
 
         # -- lowering kind (DESIGN.md §10 table) ---------------------------
         if ex.async_workers > 0 or ex.strategy == "async":
-            kind = "async_multi" if multi else "async"
+            kind = "async_multi"
         elif ex.strategy == "host":
             kind = "host"
-        elif sharded and multi:
-            kind = "multi_sharded"
         elif sharded:
-            kind = "sharded"
+            kind = "multi_sharded"
         elif multi:
             kind = "multi"
         else:
             kind = "scan"
 
-        if kind in ("async", "async_multi") and self.method not in (
+        if kind == "async_multi" and self.method not in (
             "auto", "exact"
         ):
             raise PlanCompatibilityError(
@@ -429,7 +420,7 @@ class SearchPlan:
 
         if self.method != "auto":
             method = self.method
-        elif kind in ("sharded", "multi_sharded"):
+        elif kind == "multi_sharded":
             method = "wilson_hilferty"
         else:
             method = "exact"

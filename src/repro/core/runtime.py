@@ -3,37 +3,24 @@
 The paper sketches asynchronous distributed execution (§3.7.1: "workers
 processing a batch of frames at a time without waiting for other workers…
 all updates are commutative").  This module is that sketch made concrete,
-at two tiers:
+as a slot-based elastic scheduler over a leading-``[Q]`` carry,
+:class:`AsyncMultiSearchDriver` (DESIGN.md §11): workers check out
+per-query *cohort slots* (query id, chunk winners, rank base, key split —
+a precomputed :class:`~repro.core.exsample.RoundChoice`) instead of whole
+carries, process whichever slots are in flight through ONE shared dedup +
+:class:`DetectionCache` detector batch (``multi_round_process``), and the
+driver applies each query's delta back into its row under the
+pending-set/at-most-once discipline (DESIGN.md §5).  At most one slot per
+query is in flight, so per-query rounds serialize and every query's
+trajectory is bit-identical to its solo ``scan`` plan at ANY worker count
+(deterministic detector).  Finished queries retire their slots; new
+queries join mid-flight (``admit``) via the same finished-query masking
+machinery.  A single-query plan with ``async_workers`` runs here at Q = 1.
 
-  * :class:`AsyncSearchDriver` — the legacy single-query tier: a driver
-    owns the sampler/matcher state and a cohort queue; N workers pull
-    whole-carry cohorts, process each as a SINGLE scanned device call
-    (``_process_cohort``), and push delta statistics back whenever they
-    finish — no barriers.  The driver merges deltas commutatively
-    (`merge_deltas`), re-samples new cohorts from the freshest state,
-    monitors worker health (`HeartbeatMonitor`) and re-issues cohorts
-    from dead/straggling workers (at-most-once *effect*: a duplicated
-    frame perturbs one sample, which the estimator tolerates —
-    DESIGN.md §5).
-
-  * :class:`AsyncMultiSearchDriver` — the slot-based elastic scheduler
-    over a leading-``[Q]`` carry (DESIGN.md §11): workers check out
-    per-query *cohort slots* (query id, chunk winners, rank base, key
-    split — a precomputed :class:`~repro.core.exsample.RoundChoice`)
-    instead of whole carries, process whichever slots are in flight
-    through ONE shared dedup + :class:`DetectionCache` detector batch
-    (``multi_round_process``), and the driver applies each query's delta
-    back into its row under the pending-set/at-most-once discipline.  At
-    most one slot per query is in flight, so per-query rounds serialize
-    and every query's trajectory is bit-identical to its solo
-    ``run_search_scan`` run at ANY worker count (deterministic detector).
-    Finished queries retire their slots; new queries join mid-flight
-    (``admit``) via the same finished-query masking machinery.
-
-Both tiers spill matcher-ring evictions to an append-only host-side
-:class:`~repro.core.matcher.ResultLog` at merge boundaries, so result
-sets are unbounded while the device ring stays fixed (the ring-spill
-contract, DESIGN.md §11).
+Matcher-ring evictions spill to an append-only host-side
+:class:`~repro.core.matcher.ResultLog` at merge boundaries, so result sets
+are unbounded while the device ring stays fixed (the ring-spill contract,
+DESIGN.md §11).
 
 The runtime is deterministic under a virtual clock for testing; the
 worker pool is threads (the detector releases the GIL under jax) — on a
@@ -55,65 +42,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.chunks import ChunkIndex
-from repro.core.distributed import merge_deltas
 from repro.core.exsample import (
     ExSampleCarry,
     RoundAux,
     RoundChoice,
     SelectFn,
-    _process_frame,
     multi_round_choose,
     multi_round_process,
     stack_carries,
 )
-from repro.core.matcher import (
-    MatcherState,
-    ResultLog,
-    eviction_mask,
-    merge_matcher_checked,
-)
+from repro.core.matcher import ResultLog, eviction_mask
 from repro.core.state import SamplerState
-from repro.core.thompson import choose_chunks
 from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.serve.batcher import cache_insert, init_detection_cache
 
 # Slot rounds whose (issued, taken, done, merged) stamps the elastic driver
 # keeps for ``recent_rounds``; older rounds fall off the end.
 ROUND_HISTORY = 256
-
-
-class MatcherRingOverflow(RuntimeError):
-    """A worker inserted ≥ capacity results between snapshot and merge: the
-    SOURCE ring wrapped, entries were overwritten before they could be
-    seen, and no spill can recover them.  Raised instead of silently
-    under-counting (ROADMAP ring-wrap guard).  Evictions on the
-    *destination* side are recoverable and spill to the host
-    :class:`~repro.core.matcher.ResultLog` instead (DESIGN.md §11);
-    deployments hitting this error should size ``max_results`` above the
-    per-merge insertion bound (cohort size × detections per frame) or
-    merge more often."""
-
-
-@partial(jax.jit, static_argnames=("detector",))
-def _process_cohort(
-    carry: ExSampleCarry,
-    chunks: ChunkIndex,
-    chunk_ids: jax.Array,   # i32[B]
-    det_keys: jax.Array,    # key[B]
-    *,
-    detector: Callable,
-) -> ExSampleCarry:
-    """Process a whole cohort as ONE device call (DESIGN.md §7).
-
-    The per-frame Python loop this replaces paid one jit dispatch per
-    frame; here the B matcher-sequential frame updates fold under a
-    single ``lax.fori_loop`` so a worker's cohort costs one dispatch
-    regardless of B.
-    """
-    def body(i, c):
-        return _process_frame(c, chunks, detector, chunk_ids[i], det_keys[i])
-
-    return jax.lax.fori_loop(0, chunk_ids.shape[0], body, carry)
 
 
 @dataclasses.dataclass
@@ -163,244 +108,6 @@ def _next_result(results: queue.Queue, threads, timeout: float):
     if isinstance(res, WorkerFailure):
         res.reraise()
     return res
-
-
-@dataclasses.dataclass
-class Cohort:
-    cohort_id: int
-    chunk_ids: np.ndarray      # i64[B]
-    issue_count: int = 0       # >1 ⇒ re-issued (straggler/death)
-
-
-@dataclasses.dataclass
-class WorkerResult:
-    cohort_id: int
-    worker_id: int
-    delta_n1: jax.Array
-    delta_n: jax.Array
-    new_results: int
-    frames: int
-    matcher: Optional[MatcherState] = None       # worker's final result memory
-    snap_matcher: Optional[MatcherState] = None  # memory at the snapshot
-
-
-class AsyncSearchDriver:
-    """Cohort scheduler + state owner.  Thread-safe, barrier-free."""
-
-    def __init__(
-        self,
-        carry: ExSampleCarry,
-        chunks: ChunkIndex,
-        detector: Callable,
-        *,
-        cohort_size: int = 8,
-        num_workers: int = 4,
-        result_limit: int = 50,
-        max_frames: int = 100_000,
-        straggler_factor: float = 4.0,
-    ):
-        self.carry = carry
-        self.chunks = chunks
-        self.detector = detector
-        self.cohort_size = cohort_size
-        self.result_limit = result_limit
-        self.max_frames = max_frames
-        self.monitor = HeartbeatMonitor(straggler_factor=straggler_factor)
-        self._lock = threading.Lock()
-        self._work: "queue.Queue[Optional[Cohort]]" = queue.Queue()
-        self._results: "queue.Queue[WorkerResult]" = queue.Queue()
-        self._next_cohort = 0
-        self._inflight: dict[int, Cohort] = {}
-        self.num_workers = num_workers
-        self.result_log = ResultLog()
-        # every counter exists from construction so LoweredPlan.run() can
-        # package uniform SearchStats even for a run that never merged
-        self.stats = {
-            "cohorts": 0, "reissues": 0, "merges": 0, "duplicate_drops": 0,
-            "merge_high_water": 0, "spilled": 0,
-        }
-
-    # ---- driver side -------------------------------------------------------
-
-    def _issue_cohort(self) -> None:
-        with self._lock:
-            key = jax.random.fold_in(self.carry.key, self._next_cohort)
-            chunk_ids = np.asarray(
-                choose_chunks(key, self.carry.sampler, cohorts=self.cohort_size)
-            )
-            cohort = Cohort(self._next_cohort, chunk_ids)
-            self._next_cohort += 1
-            self._inflight[cohort.cohort_id] = cohort
-            self.stats["cohorts"] += 1
-        self._work.put(cohort)
-
-    def _merge(self, res: WorkerResult) -> None:
-        """Fold one worker result into the shared carry — sampler deltas,
-        counters AND matcher memory under a single lock acquisition.
-        The matcher is *merged* (new entries appended, seen-count bumps
-        added — ``merge_matcher``), not replaced: a concurrent merge can
-        neither double-count results nor drop another worker's matcher
-        insertions.  Cross-worker duplicate detections remain possible —
-        the at-most-once-*effect* tolerance, DESIGN.md §5.
-
-        A cohort is merged AT MOST ONCE: ``HeartbeatMonitor`` re-issues a
-        straggler's cohort, so two completions of the same cohort can
-        land; folding both double-counts sampler deltas, ``step``,
-        ``results`` and matcher insertions.  The pending set is
-        ``self._inflight`` — the first completion removes the cohort under
-        the lock, any later completion of the same cohort is dropped (and
-        counted in ``stats["duplicate_drops"]``).
-
-        Ring-spill contract (DESIGN.md §11): live destination entries the
-        append window overwrites drain to ``self.result_log`` BEFORE the
-        merge lands, so eviction loses nothing.  Only a SOURCE-ring wrap
-        (``mstats.overflow``: ≥ capacity insertions between snapshot and
-        merge, unrecoverable by construction) still raises
-        ``MatcherRingOverflow``; the per-merge insertion count is
-        surfaced as ``stats["merge_high_water"]``."""
-        with self._lock:
-            if res.cohort_id not in self._inflight:
-                self.stats["duplicate_drops"] += 1
-                return
-            del self._inflight[res.cohort_id]
-            sampler = merge_deltas(self.carry.sampler, res.delta_n1, res.delta_n)
-            matcher = self.carry.matcher
-            if res.matcher is not None:
-                inserted = int(
-                    res.matcher.total_inserted - res.snap_matcher.total_inserted
-                )
-                self.stats["merge_high_water"] = max(
-                    self.stats["merge_high_water"], inserted
-                )
-                if inserted >= matcher.capacity:
-                    raise MatcherRingOverflow(
-                        f"cohort {res.cohort_id}: {inserted} insertions "
-                        f"into a capacity-{matcher.capacity} result ring "
-                        "wrapped the source ring (unrecoverable) — size "
-                        "max_results above the per-cohort insertion bound"
-                    )
-                if inserted:
-                    self.stats["spilled"] += self.result_log.spill(
-                        matcher, eviction_mask(matcher, inserted)
-                    )
-                matcher, _mstats = merge_matcher_checked(
-                    matcher, res.matcher, res.snap_matcher
-                )
-            self.carry = dataclasses.replace(
-                self.carry,
-                sampler=sampler,
-                matcher=matcher,
-                step=self.carry.step + res.frames,
-                results=self.carry.results + res.new_results,
-            )
-            self.stats["merges"] += 1
-
-    def _reissue(self, cohort_id: int) -> None:
-        with self._lock:
-            cohort = self._inflight.get(cohort_id)
-            if cohort is None:
-                return
-            cohort.issue_count += 1
-            self.stats["reissues"] += 1
-        self._work.put(cohort)
-
-    # ---- worker side -------------------------------------------------------
-
-    def _process_one(self, wid: int, cohort: Cohort) -> WorkerResult:
-        """Process one cohort against a locked snapshot of the shared carry.
-
-        Snapshot the shared carry under the lock and compute EVERY delta
-        against that snapshot — reading self.carry again after processing
-        would race with concurrent merges (double-counted results / lost
-        matcher updates).  Pure of scheduling concerns so tests can drive
-        duplicate completions synchronously.
-        """
-        with self._lock:
-            snapshot = self.carry
-        b = len(cohort.chunk_ids)
-        # nested fold_in: unique per (cohort, frame) for ANY cohort size
-        # (a flat cohort_id*stride + i scheme collides once b > stride)
-        base = jax.random.fold_in(jax.random.PRNGKey(7), cohort.cohort_id)
-        det_keys = jax.vmap(
-            lambda i: jax.random.fold_in(base, i)
-        )(jnp.arange(b, dtype=jnp.int32))
-        local = _process_cohort(
-            snapshot,
-            self.chunks,
-            jnp.asarray(cohort.chunk_ids, jnp.int32),
-            det_keys,
-            detector=self.detector,
-        )
-        return WorkerResult(
-            cohort_id=cohort.cohort_id,
-            worker_id=wid,
-            delta_n1=local.sampler.n1 - snapshot.sampler.n1,
-            delta_n=local.sampler.n - snapshot.sampler.n,
-            new_results=int(local.results - snapshot.results),
-            frames=b,
-            matcher=local.matcher,           # merged atomically…
-            snap_matcher=snapshot.matcher,   # …against this baseline
-        )
-
-    def _worker(self, wid: int) -> None:
-        self.monitor.register(wid, now=time.monotonic())
-        while True:
-            cohort = self._work.get()
-            if cohort is None:
-                return
-            t0 = time.monotonic()
-            self.monitor.assign(wid, cohort.cohort_id, now=t0)
-            try:
-                res = self._process_one(wid, cohort)
-            except Exception as e:  # noqa: BLE001 — re-raised by run()
-                self._results.put(WorkerFailure(wid, e))
-                return
-            self._results.put(res)
-            now = time.monotonic()
-            self.monitor.heartbeat(wid, now)
-            self.monitor.record_completion(wid, now - t0, now=now)
-
-    # ---- run loop ----------------------------------------------------------
-
-    def run(self) -> ExSampleCarry:
-        """Run to the result limit or the frame budget.  A worker's
-        exception is raised from here, and so is a ``TimeoutError`` when
-        no result arrives for ``STALL_TIMEOUT_S`` seconds."""
-        threads = [
-            threading.Thread(target=self._worker, args=(w,), daemon=True)
-            for w in range(self.num_workers)
-        ]
-        for t in threads:
-            t.start()
-        # keep the pipeline full: workers+1 outstanding cohorts
-        for _ in range(self.num_workers + 1):
-            self._issue_cohort()
-        last = time.monotonic()
-        try:
-            while (
-                int(self.carry.results) < self.result_limit
-                and int(self.carry.step) < self.max_frames
-            ):
-                res = _next_result(
-                    self._results, threads, timeout=min(60.0, STALL_TIMEOUT_S)
-                )
-                if res is None:
-                    _raise_if_stalled(last, self._inflight)
-                    continue
-                last = time.monotonic()
-                self._merge(res)
-                actions = self.monitor.sweep(time.monotonic())
-                for cid in actions["reissue_cohorts"]:
-                    self._reissue(cid)
-                self._issue_cohort()
-        finally:
-            # always shut the pool down — a raising merge (e.g.
-            # MatcherRingOverflow) must not leak blocked worker threads
-            for _ in threads:
-                self._work.put(None)
-            for t in threads:
-                t.join(timeout=5.0)
-        return self.carry
 
 
 # ---------------------------------------------------------------------------
@@ -567,10 +274,10 @@ class AsyncMultiSearchDriver:
     fixed at ``slots_per_batch`` (padded with inactive lanes), so neither
     retirement nor admission recompiles anything.
 
-    The composed path cannot raise :class:`MatcherRingOverflow`: the
-    constructor rejects configurations whose per-round insertion bound
-    (cohorts × detector slots per frame) reaches the ring capacity, which
-    is the only way a source ring can wrap between issue and merge.
+    No source ring can wrap between issue and merge: the constructor
+    rejects configurations whose per-round insertion bound (cohorts ×
+    detector slots per frame) reaches the ring capacity, which is the only
+    way it could.
     """
 
     def __init__(
@@ -642,7 +349,7 @@ class AsyncMultiSearchDriver:
         # no-overflow guarantee for the composed path: a round inserts at
         # most cohorts × (detector slots per frame) entries per query, and
         # a merge window is exactly one round — keep it under capacity so
-        # the source ring can never wrap (MatcherRingOverflow-free)
+        # the source ring can never wrap
         struct = jax.eval_shape(
             detector, jax.random.PRNGKey(0), jnp.zeros((), jnp.int32)
         )

@@ -31,7 +31,7 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
 
 def make_data_mesh(num_shards: int):
     """1-D ``("data",)`` mesh over the first ``num_shards`` local devices —
-    the sharded search driver's layout (``run_search_sharded``).  Built
+    the mesh search loop's layout (``run_search_multi_sharded``).  Built
     from an explicit device subset so a search can use fewer shards than
     the host exposes (``jax.make_mesh`` insists on all of them)."""
     import numpy as np

@@ -15,12 +15,13 @@ deployment loop with resumable state.
 
 ``--plan`` takes a JSON ``SearchPlan.to_dict()`` document (or ``@file``)
 and is the canonical path: the planner validates option compatibility and
-lowers to one device-resident driver — host loop, scanned, mesh-sharded,
-Q-batched, async, or the composed Q×shards driver the legacy flags could
-never combine.  The legacy flag combinations (``--mesh/--sync-every``,
-``--queries/--cache-frames``, ``--driver``) still work but are deprecated:
-they are translated into the equivalent plan and a ``DeprecationWarning``
-is emitted.  When the plan needs more devices than the host exposes,
+lowers to one driver — host loop, scanned, Q-batched, the Q×shards mesh
+loop or the async slot runtime; mesh and async plans run their Q-axis
+lowering at any Q, so a single query is Q = 1.  The legacy flag
+combinations (``--mesh/--sync-every``, ``--queries/--cache-frames``,
+``--driver``) still work but are deprecated: they are translated into
+the equivalent plan and a ``DeprecationWarning`` is emitted.  When the
+plan needs more devices than the host exposes,
 ``main()`` re-execs into a child with virtual CPU devices if
 ``JAX_PLATFORMS=cpu`` is set, and fails otherwise
 (``launch.mesh.ensure_host_devices``).
@@ -262,7 +263,7 @@ def main() -> None:
           f"{json.dumps(plan.to_dict())}")
 
     q_n = plan.queries
-    multi = lowered.kind in ("multi", "multi_sharded", "async_multi")
+    multi = lowered.q_axis
     select = None
     if multi:
         classes = args.queries if args.queries else list(range(q_n))
@@ -297,7 +298,7 @@ def main() -> None:
         )
 
     if args.kill_worker:
-        if lowered.kind != "multi_sharded":
+        if not (multi and lowered.kind == "multi_sharded"):
             raise SystemExit(
                 "--kill-worker needs a queries_axis + shards>1 plan "
                 f"(multi_sharded lowering, got {lowered.kind})")

@@ -9,9 +9,11 @@ overlaps DIFFERENT queries' rounds.  Property tests pin the elastic
 join/retire semantics (a query admitted at round r ≡ a solo run whose
 frame budget was debited the frames it missed), the at-most-once merge
 discipline under forced straggler re-issue, and the ring-spill contract:
-a tiny device ring never raises ``MatcherRingOverflow`` on the composed
-path and never loses a result — evicted entries land in the per-query
-host ``ResultLog``.
+a tiny device ring never wraps a source ring and never loses a result —
+evicted entries land in the per-query host ``ResultLog``.  A single-query
+plan with ``async_workers`` runs this runtime at Q = 1 (its carry lifted
+to ``[1]``), so the contracts that reach ``run()`` are also checked
+through such a plan.
 """
 import dataclasses
 import warnings
@@ -76,6 +78,21 @@ def _solo(chunks, det, q, *, result_limit, max_steps, cohorts=1,
         )
 
 
+def _single_plan(chunks, det, *, workers, max_results=64, **plan_kw):
+    """Query 0 through a single-query plan with ``async_workers``; returns
+    the result with its carry stacked back to ``[1]`` so the row helpers
+    below read it like a driver's."""
+    carry = init_carry(
+        init_state(chunks.length), init_matcher(max_results=max_results),
+        _qkey(0),
+    )
+    plan = SearchPlan(**plan_kw, execution=Execution(async_workers=workers))
+    assert plan.resolve() == ("async_multi", "exact")
+    res = plan.run(carry, chunks, detector=det)
+    assert jnp.ndim(res.carry.step) == 0   # a single-query carry back
+    return dataclasses.replace(res, carry=stack_carries([res.carry]))
+
+
 def _assert_row_equals_solo(out, trace, q, solo_out, solo_trace):
     assert int(out.step[q]) == int(solo_out.step)
     assert int(out.results[q]) == int(solo_out.results)
@@ -93,40 +110,51 @@ def _assert_row_equals_solo(out, trace, q, solo_out, solo_trace):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("workers", [1, 4])
-def test_composed_bit_parity_vs_solo_scan(world, workers):
+@pytest.mark.parametrize("workers, q_n", [(1, 3), (4, 3), (4, 1)])
+def test_composed_bit_parity_vs_solo_scan(world, workers, q_n):
     """Each query through the slot scheduler ≡ its solo scanned run —
-    at ANY worker count, since per-query rounds serialize."""
+    at ANY worker count, since per-query rounds serialize.  Q = 1 runs
+    through a single-query plan."""
     _, chunks, det = world
-    q_n = 3
-    driver = AsyncMultiSearchDriver(
-        _fresh_multi(chunks, q_n), chunks, det,
-        cohorts=2, num_workers=workers, result_limits=8,
-        max_steps=1500, trace_every=25,
-    )
-    out = driver.run()
+    if q_n == 1:
+        res = _single_plan(
+            chunks, det, workers=workers, cohorts=2, result_limit=8,
+            max_steps=1500, trace_every=25,
+        )
+        out, traces = res.carry, res.traces
+    else:
+        driver = AsyncMultiSearchDriver(
+            _fresh_multi(chunks, q_n), chunks, det,
+            cohorts=2, num_workers=workers, result_limits=8,
+            max_steps=1500, trace_every=25,
+        )
+        out, traces = driver.run(), driver.traces
     for q in range(q_n):
         solo_out, solo_trace = _solo(
             chunks, det, q, result_limit=8, max_steps=1500, cohorts=2,
             trace_every=25,
         )
-        _assert_row_equals_solo(out, driver.traces[q], q, solo_out,
-                                solo_trace)
+        _assert_row_equals_solo(out, traces[q], q, solo_out, solo_trace)
 
 
-def test_composed_parity_through_search_plan(world):
-    """The async_multi lowering (async_workers>0 × queries>1) reaches the
-    same per-query fixed points through the declarative SearchPlan, with
-    uniform SearchStats populated."""
+@pytest.mark.parametrize("q_n", [4, 1])
+def test_composed_parity_through_search_plan(world, q_n):
+    """The async_multi lowering reaches the same per-query fixed points
+    through the declarative SearchPlan, with uniform SearchStats
+    populated: Q = 4 on the Q axis with a cache, and a single-query plan
+    (no Q axis, so no cache) lifted to Q = 1."""
     _, chunks, det = world
-    q_n = 4
-    plan = SearchPlan(
-        queries=q_n, cohorts=2, result_limit=8, max_steps=1500,
-        trace_every=25,
-        execution=Execution(queries_axis=True, async_workers=2, cache=-1),
-    )
-    assert plan.resolve() == ("async_multi", "exact")
-    res = plan.run(_fresh_multi(chunks, q_n), chunks, detector=det)
+    kw = dict(cohorts=2, result_limit=8, max_steps=1500, trace_every=25)
+    if q_n == 1:
+        res = _single_plan(chunks, det, workers=2, **kw)
+    else:
+        plan = SearchPlan(
+            queries=q_n, **kw,
+            execution=Execution(queries_axis=True, async_workers=2,
+                                cache=-1),
+        )
+        assert plan.resolve() == ("async_multi", "exact")
+        res = plan.run(_fresh_multi(chunks, q_n), chunks, detector=det)
     for q in range(q_n):
         solo_out, solo_trace = _solo(
             chunks, det, q, result_limit=8, max_steps=1500, cohorts=2,
@@ -243,11 +271,40 @@ def test_retired_rows_frozen_and_masked(world):
 # ---------------------------------------------------------------------------
 
 
-def test_forced_reissue_merges_at_most_once(world):
+@pytest.mark.parametrize("path", ["pump", "plan"])
+def test_forced_reissue_merges_at_most_once(world, monkeypatch, path):
     """A re-issued slot batch reprocesses the identical work item; the
     second completion is dropped by the pending set and the committed
-    state equals a single merge."""
+    state equals a single merge.  Through a single-query plan every batch
+    is re-issued before its first completion merges, and the query still
+    ends bit-identical to its solo scanned run."""
     _, chunks, det = world
+    if path == "plan":
+        merge = AsyncMultiSearchDriver._merge
+        seen = set()
+
+        def reissue_then_merge(self, res):
+            if res.batch_id not in seen:
+                seen.add(res.batch_id)
+                self._reissue(res.batch_id)
+            merge(self, res)
+
+        monkeypatch.setattr(
+            AsyncMultiSearchDriver, "_merge", reissue_then_merge
+        )
+        res = _single_plan(chunks, det, workers=1, cohorts=1,
+                           result_limit=20, max_steps=300)
+        st_ = res.stats
+        assert st_.merges == st_.rounds > 1
+        assert st_.reissues >= st_.merges
+        # one worker takes each duplicate before the next round's batch,
+        # so every duplicate but the last is completed and dropped
+        assert st_.merges - 1 <= st_.duplicate_drops <= st_.reissues
+        solo_out, solo_trace = _solo(chunks, det, 0, result_limit=20,
+                                     max_steps=300, cohorts=1)
+        _assert_row_equals_solo(res.carry, res.traces[0], 0, solo_out,
+                                solo_trace)
+        return
     driver = AsyncMultiSearchDriver(
         _fresh_multi(chunks, 2), chunks, det,
         cohorts=1, num_workers=1, result_limits=20,
@@ -280,15 +337,25 @@ def test_forced_reissue_merges_at_most_once(world):
 # ---------------------------------------------------------------------------
 
 
-def test_tiny_ring_spills_without_loss(world):
-    """With a ring far smaller than the result count the composed path
-    never raises MatcherRingOverflow and never loses a result: every
-    distinct insertion is live on-device or in the host ResultLog."""
+@pytest.mark.parametrize("q_n", [2, 1])
+def test_tiny_ring_spills_without_loss(world, q_n):
+    """With a ring far smaller than the result count the slot runtime
+    never wraps a source ring and never loses a result: every distinct
+    insertion is live on-device or in the host ResultLog.  Q = 1 runs
+    through a single-query plan, whose stats carry the spill count."""
     repo, chunks, _ = world
     det = lambda key, frame: oracle_detect(
         repo, frame, query_class=0, max_dets=4
     )
-    q_n = 2
+    if q_n == 1:
+        res = _single_plan(chunks, det, workers=2, max_results=8, cohorts=1,
+                           result_limit=40, max_steps=3000)
+        out = res.carry
+        live = int(np.sum(np.asarray(out.matcher.times_seen[0]) > 0))
+        assert res.stats.results_spilled > 0
+        assert int(out.results[0]) == live + res.stats.results_spilled
+        assert int(out.matcher.total_inserted[0]) == int(out.results[0])
+        return
     driver = AsyncMultiSearchDriver(
         _fresh_multi(chunks, q_n, max_results=8), chunks, det,
         cohorts=1, num_workers=2, result_limits=40, max_steps=3000,
@@ -395,17 +462,22 @@ def _failing_detector(det):
     return failing
 
 
-def test_worker_failure_fails_run(world):
+@pytest.mark.parametrize("q_n", [2, 1])
+def test_worker_failure_fails_run(world, q_n):
     """Regression: a worker exception used to end its daemon thread in
     silence, and ``run()`` returned the partial carry after one timeout as
-    if the search had finished.  It must raise from ``run()`` instead."""
+    if the search had finished.  It must raise from ``run()`` instead —
+    and from a single-query plan's ``run()`` at Q = 1."""
     _, chunks, det = world
-    driver = AsyncMultiSearchDriver(
-        _fresh_multi(chunks, 2), chunks, _failing_detector(det),
-        cohorts=2, num_workers=2, result_limits=8, max_steps=1500,
-    )
     with pytest.raises(RuntimeError, match="search worker .* failed"):
-        driver.run()
+        if q_n == 1:
+            _single_plan(chunks, _failing_detector(det), workers=2,
+                         cohorts=2, result_limit=8, max_steps=1500)
+        else:
+            AsyncMultiSearchDriver(
+                _fresh_multi(chunks, q_n), chunks, _failing_detector(det),
+                cohorts=2, num_workers=2, result_limits=8, max_steps=1500,
+            ).run()
 
 
 def _hanging_detector(det, release):
@@ -426,20 +498,27 @@ def _hanging_detector(det, release):
     return hanging
 
 
-def test_stuck_worker_times_out_run(world, monkeypatch):
+@pytest.mark.parametrize("q_n", [2, 1])
+def test_stuck_worker_times_out_run(world, monkeypatch, q_n):
     """A worker that never returns must not hang ``run()``: once no batch
-    completes for the stall limit it raises, naming the in-flight work."""
+    completes for the stall limit it raises, naming the in-flight work —
+    also from a single-query plan's ``run()`` at Q = 1."""
     import threading
 
     _, chunks, det = world
     release = threading.Event()
-    driver = AsyncMultiSearchDriver(
-        _fresh_multi(chunks, 2), chunks, _hanging_detector(det, release),
-        cohorts=2, num_workers=2, result_limits=8, max_steps=1500,
-    )
+    hanging = _hanging_detector(det, release)
     monkeypatch.setattr(runtime, "STALL_TIMEOUT_S", 1.0)
     try:
         with pytest.raises(TimeoutError, match=r"in flight: \[0"):
-            driver.run()
+            if q_n == 1:
+                _single_plan(chunks, hanging, workers=2, cohorts=2,
+                             result_limit=8, max_steps=1500)
+            else:
+                AsyncMultiSearchDriver(
+                    _fresh_multi(chunks, q_n), chunks, hanging,
+                    cohorts=2, num_workers=2, result_limits=8,
+                    max_steps=1500,
+                ).run()
     finally:
         release.set()

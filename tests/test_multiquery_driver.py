@@ -272,7 +272,7 @@ def test_choose_chunks_batched_parity(method):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(
     frames=st.lists(st.integers(0, 9), min_size=1, max_size=32),
     valid_bits=st.integers(0, 2**32 - 1),
@@ -298,21 +298,24 @@ def test_dedup_never_drops_never_duplicates(frames, valid_bits):
     assert is_rep.sum() == len({frames[i] for i in np.nonzero(valid)[0]})
 
 
-@settings(max_examples=10)
+_WORLD_CACHE = {}
+
+
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 2**16))
-def test_round_sampler_deltas_isolated_per_query(seed, _world_cache={}):
+def test_round_sampler_deltas_isolated_per_query(seed):
     """No detection is ever double-counted across queries: after a short
     multi-query run, each query's sampler has absorbed exactly its own
     frames (Σ n-delta == its step counter) and its trajectory equals its
     solo run — a detection leaking into another query's deltas would break
     both."""
-    if "w" not in _world_cache:
+    if "w" not in _WORLD_CACHE:
         spec = RepoSpec(
             video_lengths=[2_000] * 2, num_instances=60, chunk_frames=500,
             locality=3.0, seed=5,
         )
-        _world_cache["w"] = generate(spec)
-    repo, chunks = _world_cache["w"]
+        _WORLD_CACHE["w"] = generate(spec)
+    repo, chunks = _WORLD_CACHE["w"]
     det = lambda key, frame: oracle_detect(repo, frame, query_class=0)
     q_n, cohorts = 3, 2
     keys = jnp.stack([

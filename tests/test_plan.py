@@ -6,7 +6,6 @@ names the offending option; any VALID plan must survive
 ``from_dict(to_dict(plan)) == plan`` exactly (property-tested with
 hypothesis).
 """
-import dataclasses
 import warnings
 
 import jax
@@ -56,8 +55,6 @@ from repro.core import (
         # individually-valid options that no lowering can combine
         (SearchPlan(execution=Execution(async_workers=2, shards=4)),
          PlanCompatibilityError, "async_workers"),
-        (SearchPlan(trace_every=16, execution=Execution(async_workers=2)),
-         PlanCompatibilityError, "trace"),
         (SearchPlan(execution=Execution(strategy="async")),
          PlanCompatibilityError, "async_workers"),
         (SearchPlan(execution=Execution(cache=128)),
@@ -115,9 +112,9 @@ def test_unknown_keys_rejected():
         (SearchPlan(execution=Execution(strategy="host")), "host", "exact"),
         (SearchPlan(method="pallas"), "scan", "pallas"),
         (SearchPlan(cohorts=8, execution=Execution(shards=8)),
-         "sharded", "wilson_hilferty"),
+         "multi_sharded", "wilson_hilferty"),
         (SearchPlan(execution=Execution(strategy="sharded")),
-         "sharded", "wilson_hilferty"),
+         "multi_sharded", "wilson_hilferty"),
         (SearchPlan(queries=4), "multi", "exact"),
         (SearchPlan(execution=Execution(queries_axis=True)), "multi",
          "exact"),
@@ -128,7 +125,10 @@ def test_unknown_keys_rejected():
         (SearchPlan(execution=Execution(queries_axis=True, cache=64,
                                         strategy="sharded")),
          "multi_sharded", "wilson_hilferty"),
-        (SearchPlan(execution=Execution(async_workers=2)), "async", "exact"),
+        (SearchPlan(execution=Execution(async_workers=2)), "async_multi",
+         "exact"),
+        (SearchPlan(trace_every=16, execution=Execution(async_workers=2)),
+         "async_multi", "exact"),
         (SearchPlan(queries=4, execution=Execution(async_workers=2)),
          "async_multi", "exact"),
         (SearchPlan(execution=Execution(queries_axis=True, async_workers=1,
@@ -234,50 +234,6 @@ def test_from_dict_accepts_json_lists():
     )
 
 
-# ---------------------------------------------------------------------------
-# Benchmark registration: declared Execution requirements drive skips
-# ---------------------------------------------------------------------------
-
-
-def test_bench_registry_declares_and_skips(monkeypatch):
-    import pathlib
-    import sys
-
-    sys.path.insert(0, str(pathlib.Path(__file__).parent.parent))
-    try:
-        from benchmarks.run import SECTIONS, should_skip
-    finally:
-        sys.path.pop(0)
-    by_name = {s.name: s for s in SECTIONS}
-    assert "plan_compose(sec10)" in by_name
-    compose = by_name["plan_compose(sec10)"]
-    assert compose.execution is not None and compose.execution.shards == 8
-    # under JAX_PLATFORMS=cpu subprocess-forcing benches never skip;
-    # in-process mesh requirements skip with a logged reason when the host
-    # is short on devices
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert should_skip(compose, available_devices=1) is None  # self-forcing
-    probe = dataclasses.replace(compose, forces_devices=False)
-    reason = should_skip(probe, available_devices=1)
-    assert reason is not None and "8" in reason and "1" in reason
-    assert should_skip(probe, available_devices=8) is None
-    for s in SECTIONS:
-        if s.execution is None:
-            assert should_skip(s, available_devices=1) is None
-    # on an accelerator nothing forces virtual devices: a self-forcing
-    # bench wider than the host skips, naming the count it needs
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    reason = should_skip(compose, available_devices=1)
-    assert reason is not None and "8" in reason and "1" in reason
-    assert should_skip(compose, available_devices=8) is None
-    # the async-compose section declares its worker-thread need and only
-    # skips when the host cannot start threads (probed, not assumed)
-    assert "async_compose(sec11)" in by_name
-    async_spec = by_name["async_compose(sec11)"]
-    assert async_spec.execution.async_workers == 4
-    assert should_skip(async_spec, available_devices=1) is None
-
-
 def test_run_reconciles_mesh_with_plan_geometry():
     """A caller-supplied mesh must provide exactly the validated shards on
     the declared axis, and a non-'data' axis cannot be auto-built."""
@@ -317,7 +273,7 @@ def test_legacy_cli_flags_build_valid_plans():
         warnings.simplefilter("ignore", DeprecationWarning)
         assert build_plan(mk(sync_every=4)).resolve() == ("scan", "exact")
         assert build_plan(mk(mesh=2, sync_every=4, cohorts=4)).resolve() \
-            == ("sharded", "wilson_hilferty")
+            == ("multi_sharded", "wilson_hilferty")
         assert build_plan(mk(mesh=2, cohorts=5)).execution.shards == 2
         assert build_plan(mk(queries=[0, 1])).resolve() == ("multi", "exact")
         assert build_plan(
@@ -352,6 +308,14 @@ def test_plan_run_rejects_mismatched_carry():
         SearchPlan(queries=2).run(single, chunks, detector=det)
     with pytest.raises(PlanError, match="queries"):
         SearchPlan().run(multi, chunks, detector=det)
+    # a single-query plan on a Q-axis lowering takes a single-query carry
+    # (the executor lifts it); a plan that asks for the Q axis needs [Q]
+    with pytest.raises(PlanError, match="queries"):
+        SearchPlan(execution=Execution(async_workers=1)).run(
+            multi, chunks, detector=det)
+    with pytest.raises(PlanError, match="leading"):
+        SearchPlan(execution=Execution(queries_axis=True, async_workers=1)) \
+            .run(single, chunks, detector=det)
     with pytest.raises(PlanError, match="select"):
         SearchPlan().run(single, chunks, detector=det,
                          select=lambda q, d: d.valid)

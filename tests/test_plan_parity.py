@@ -1,27 +1,31 @@
-"""SearchPlan lowering ≡ legacy drivers, and the composed Q×shards lowering
-(DESIGN.md §10).
+"""SearchPlan lowering ≡ legacy drivers, the one lift, and the composed
+Q×shards lowering (DESIGN.md §10).
 
-Two contracts:
+Three contracts:
 
 * **Home-config parity** — a plan lowered to each legacy driver's home
-  configuration (scan Q=1, host, multi Q=4, sharded) reproduces the legacy
-  entry point bit-identically: (step, results), trace, sampler statistics,
+  configuration (scan Q=1, host, multi Q=4) reproduces the legacy entry
+  point bit-identically: (step, results), trace, sampler statistics,
   final PRNG key.  The legacy ``run_search_*`` functions are deprecated
   shims over the SAME lowering, so this also pins the shims.
+* **The lift** — a single-query mesh or async plan runs its Q-axis
+  lowering at Q = 1: it is bit-identical (carry, trace, key) to the same
+  plan with ``queries_axis=True`` on the stacked carry, and an async one
+  equals the ``scan`` plan.
 * **Composed lowering parity** — ``run_search_multi_sharded`` (plans with
   queries_axis + shards) is bit-identical PER QUERY to that query's own
-  solo ``run_search_sharded`` run on the same mesh with the same key, at
-  any Q, with a deterministic detector: cross-query dedup and the
-  per-shard detection cache change WHICH detector invocations happen,
-  never the values a query consumes.  The in-process tests run the whole
-  composed shard_map machinery on a 1-way mesh every tier-1 run; the slow
+  single-query mesh plan on the same mesh with the same key, at any Q,
+  with a deterministic detector: cross-query dedup and the per-shard
+  detection cache change WHICH detector invocations happen, never the
+  values a query consumes.  The in-process tests run the whole composed
+  shard_map machinery on a 1-way mesh every tier-1 run; the slow
   subprocess test forces 8 host devices.
 """
+import dataclasses
 import os
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +43,7 @@ from repro.core import (
     run_search_multi,
     run_search_multi_sharded,
     run_search_scan,
-    run_search_sharded,
+    stack_carries,
 )
 from repro.launch.mesh import make_data_mesh
 from repro.sim import RepoSpec, generate
@@ -146,30 +150,39 @@ def test_plan_multi_parity(world):
     assert 0.0 <= res.stats.cache_hit_rate <= 1.0
 
 
+def _lift_pair(plan, chunks, det, mesh, key):
+    """Run ``plan`` on a single-query carry and the same plan with
+    ``queries_axis=True`` on that carry stacked to ``[1]``."""
+    solo = plan.run(_fresh(chunks, key), chunks, detector=det, mesh=mesh)
+    stacked = dataclasses.replace(
+        plan,
+        execution=dataclasses.replace(plan.execution, queries_axis=True),
+    ).run(stack_carries([_fresh(chunks, key)]), chunks, detector=det,
+          mesh=mesh)
+    assert solo.kind == stacked.kind == "multi_sharded"
+    return solo, stacked
+
+
 def test_plan_sharded_parity_1way(world):
     _, chunks, det = world
-    mesh = make_data_mesh(1)
-    with pytest.warns(DeprecationWarning, match="run_search_sharded"):
-        legacy, legacy_trace = run_search_sharded(
-            _fresh(chunks, jax.random.PRNGKey(0)), chunks, mesh=mesh,
-            detector=det, result_limit=10, max_steps=400, cohorts=2,
-            sync_every=2,
-        )
-    res = SearchPlan(
-        result_limit=10, max_steps=400, cohorts=2,
-        execution=Execution(strategy="sharded", sync_every=2),
-    ).run(
-        _fresh(chunks, jax.random.PRNGKey(0)), chunks, detector=det,
-        mesh=mesh,
+    solo, stacked = _lift_pair(
+        SearchPlan(
+            result_limit=10, max_steps=400, cohorts=2,
+            execution=Execution(strategy="sharded", sync_every=2),
+        ),
+        chunks, det, make_data_mesh(1), jax.random.PRNGKey(0),
     )
-    assert res.kind == "sharded"
-    _same_carry(legacy, res.carry)
-    assert legacy_trace == res.trace
-    # merge ring pressure surfaced uniformly (was async-driver-only);
-    # every executed sync window appended one trace entry here (no cap hit)
-    assert res.stats.merges == len(res.trace)
-    assert res.stats.merge_high_water >= 1  # results were found and merged
-    assert not res.stats.merge_overflow
+    # the lift hands back a single-query carry and a single-query trace
+    assert jnp.ndim(solo.carry.step) == 0
+    _same_carry(solo.carry, stacked.carry, qb=0)
+    assert solo.trace == stacked.traces[0]
+    assert solo.stats == stacked.stats
+    # merge ring pressure surfaced uniformly; every executed sync window
+    # appended one trace entry here (no cap hit)
+    assert solo.stats.merges == len(solo.trace)
+    assert solo.stats.merge_high_water >= 1  # results were found and merged
+    assert not solo.stats.merge_overflow
+    assert solo.stats.detector_lanes == solo.stats.rounds * 2
 
 
 @pytest.mark.skipif(
@@ -177,28 +190,19 @@ def test_plan_sharded_parity_1way(world):
 )
 def test_plan_sharded_parity_2way_in_process(world):
     _, chunks, det = world
-    mesh = make_data_mesh(2)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy, legacy_trace = run_search_sharded(
-            _fresh(chunks, jax.random.PRNGKey(0)), chunks, mesh=mesh,
-            detector=det, result_limit=12, max_steps=400, cohorts=4,
-            sync_every=1,
-        )
-    res = SearchPlan(
-        result_limit=12, max_steps=400, cohorts=4,
-        execution=Execution(shards=2),
-    ).run(
-        _fresh(chunks, jax.random.PRNGKey(0)), chunks, detector=det,
-        mesh=mesh,
+    solo, stacked = _lift_pair(
+        SearchPlan(
+            result_limit=12, max_steps=400, cohorts=4,
+            execution=Execution(shards=2),
+        ),
+        chunks, det, make_data_mesh(2), jax.random.PRNGKey(0),
     )
-    assert res.kind == "sharded"
-    _same_carry(legacy, res.carry)
-    assert legacy_trace == res.trace
+    _same_carry(solo.carry, stacked.carry, qb=0)
+    assert solo.trace == stacked.traces[0]
 
 
 # ---------------------------------------------------------------------------
-# Composed lowering: per-query bit-parity with the solo sharded driver
+# Composed lowering: per-query bit-parity with the single-query mesh plan
 # ---------------------------------------------------------------------------
 
 
@@ -218,16 +222,13 @@ def test_composed_each_query_matches_solo_sharded_1way(world, cache):
         ),
     ).run(_fresh_multi(chunks, keys), chunks, detector=det, mesh=mesh)
     assert res.kind == "multi_sharded"
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        for q in range(q_n):
-            solo, solo_trace = run_search_sharded(
-                _fresh(chunks, keys[q]), chunks, mesh=mesh, detector=det,
-                result_limit=limits[q], max_steps=400, cohorts=cohorts,
-                sync_every=sync_every,
-            )
-            _same_carry(solo, res.carry, qb=q)
-            assert solo_trace == res.traces[q], f"query {q} trace diverged"
+    for q in range(q_n):
+        solo = SearchPlan(
+            result_limit=limits[q], max_steps=400, cohorts=cohorts,
+            execution=Execution(strategy="sharded", sync_every=sync_every),
+        ).run(_fresh(chunks, keys[q]), chunks, detector=det, mesh=mesh)
+        _same_carry(solo.carry, res.carry, qb=q)
+        assert solo.trace == res.traces[q], f"query {q} trace diverged"
     # sharing can only save detector work, never add any
     assert res.stats.detector_invocations <= res.stats.frames_sampled
     assert res.stats.frames_sampled == sum(res.steps)
@@ -266,31 +267,33 @@ def test_composed_rejects_bad_geometry(world):
 
 
 def test_plan_async_lowering(world):
-    """async_workers>0 lowers to the threaded AsyncSearchDriver and its
-    scheduler counters surface through the SAME SearchStats container."""
+    """async_workers>0 on a single-query carry runs the slot runtime at
+    Q = 1: it equals the scan plan's carry and trace, and its scheduler
+    counters surface through the SAME SearchStats container."""
     _, chunks, det = world
-    res = SearchPlan(
-        result_limit=12, max_steps=2_000, cohorts=4,
-        execution=Execution(async_workers=2),
-    ).run(_fresh(chunks, jax.random.PRNGKey(0)), chunks, detector=det)
-    assert res.kind == "async"
+    kw = dict(result_limit=12, max_steps=2_000, cohorts=4, trace_every=50)
+    scan = SearchPlan(**kw).run(
+        _fresh(chunks, jax.random.PRNGKey(0)), chunks, detector=det)
+    res = SearchPlan(**kw, execution=Execution(async_workers=2)).run(
+        _fresh(chunks, jax.random.PRNGKey(0)), chunks, detector=det)
+    assert res.kind == "async_multi"
+    _same_carry(scan.carry, res.carry)
+    np.testing.assert_array_equal(np.asarray(scan.carry.matcher.times_seen),
+                                  np.asarray(res.carry.matcher.times_seen))
+    assert res.trace == scan.trace
     assert res.results[0] >= 12
-    assert res.stats.merges >= 1
+    assert res.stats.merges == res.stats.rounds >= 1
     assert res.stats.merge_high_water >= 1
     assert res.stats.frames_sampled == res.steps[0]
-    assert res.trace == [(res.steps[0], res.results[0])]
 
 
 COMPOSED_8DEV_SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import warnings
-    warnings.simplefilter("ignore", DeprecationWarning)
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import (Execution, SearchPlan, init_carry,
-                            init_carry_multi, init_matcher, init_state,
-                            run_search_sharded)
+                            init_carry_multi, init_matcher, init_state)
     from repro.launch.mesh import make_data_mesh
     from repro.sim import RepoSpec, generate
     from repro.sim.oracle import oracle_detect
@@ -315,10 +318,11 @@ COMPOSED_8DEV_SCRIPT = textwrap.dedent(
     ).run(fresh_multi(keys), chunks, detector=det, mesh=mesh)
     assert res.kind == "multi_sharded"
     for q in range(q_n):
-        solo, solo_trace = run_search_sharded(
-            fresh(keys[q]), chunks, mesh=mesh, detector=det,
+        one = SearchPlan(
             result_limit=limits[q], max_steps=budget, cohorts=cohorts,
-            sync_every=sync_every)
+            execution=Execution(shards=8, sync_every=sync_every),
+        ).run(fresh(keys[q]), chunks, detector=det, mesh=mesh)
+        solo, solo_trace = one.carry, one.trace
         assert (int(solo.step), int(solo.results)) == (
             res.steps[q], res.results[q]), (q, int(solo.step), res.steps[q])
         assert solo_trace == res.traces[q], q
@@ -328,7 +332,7 @@ COMPOSED_8DEV_SCRIPT = textwrap.dedent(
             np.asarray(solo.sampler.n1), np.asarray(res.carry.sampler.n1[q]))
         np.testing.assert_array_equal(
             np.asarray(solo.key), np.asarray(res.carry.key[q]))
-        print(f"composed q={q}: bit-identical to solo sharded "
+        print(f"composed q={q}: bit-identical to its own mesh plan "
               f"({res.steps[q]} steps, {res.results[q]} results)")
     assert res.stats.detector_invocations < res.stats.frames_sampled
     print("invocations", res.stats.detector_invocations,

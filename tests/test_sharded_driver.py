@@ -1,5 +1,7 @@
-"""Sharded device-resident search driver (``run_search_sharded``, DESIGN.md §8).
+"""Single-query mesh search (``Execution(strategy="sharded")``, DESIGN.md §8).
 
+A single-query mesh plan runs the Q × shards mesh loop at Q = 1: the
+executor lifts its carry to ``[1]`` and hands back a single-query one.
 Three layers of coverage:
 
   * a 1-way mesh runs on the single tier-1 test device, so the whole
@@ -23,14 +25,30 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import (
+    Execution,
+    SearchPlan,
     init_carry,
     init_matcher,
     init_state,
-    run_search_sharded,
 )
 from repro.launch.mesh import make_data_mesh
 from repro.sim import RepoSpec, generate
 from repro.sim.oracle import oracle_detect
+
+
+def _sharded(carry, chunks, *, mesh, detector, result_limit, max_steps,
+             cohorts, sync_every):
+    """One query on ``mesh`` through a single-query sharded plan; returns
+    ``(carry', trace)``."""
+    res = SearchPlan(
+        result_limit=result_limit, max_steps=max_steps, cohorts=cohorts,
+        execution=Execution(
+            strategy="sharded", shards=mesh.shape["data"],
+            sync_every=sync_every,
+        ),
+    ).run(carry, chunks, detector=detector, mesh=mesh)
+    assert res.kind == "multi_sharded"
+    return res.carry, res.trace
 
 
 def _world(seed=3):
@@ -56,7 +74,7 @@ def test_sharded_single_shard_in_process():
         init_state(chunks.length), init_matcher(max_results=512),
         jax.random.PRNGKey(0),
     )
-    out, trace = run_search_sharded(
+    out, trace = _sharded(
         carry, chunks, mesh=make_data_mesh(1), detector=det,
         result_limit=10, max_steps=500, cohorts=2, sync_every=2,
     )
@@ -74,12 +92,12 @@ def test_sharded_rejects_indivisible_cohorts():
         jax.random.PRNGKey(0),
     )
     with pytest.raises(ValueError, match="cohorts"):
-        run_search_sharded(
+        _sharded(
             carry, chunks, mesh=make_data_mesh(1), detector=det,
             result_limit=1, max_steps=8, cohorts=0, sync_every=1,
         )
     with pytest.raises(ValueError, match="sync_every"):
-        run_search_sharded(
+        _sharded(
             carry, chunks, mesh=make_data_mesh(1), detector=det,
             result_limit=1, max_steps=8, cohorts=1, sync_every=0,
         )
@@ -94,7 +112,7 @@ def test_sharded_two_way_in_process():
         init_state(chunks.length), init_matcher(max_results=512),
         jax.random.PRNGKey(0),
     )
-    out, _ = run_search_sharded(
+    out, _ = _sharded(
         carry, chunks, mesh=make_data_mesh(2), detector=det,
         result_limit=15, max_steps=600, cohorts=4, sync_every=1,
     )
@@ -107,8 +125,8 @@ PARITY_SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
-    from repro.core import (init_carry, init_matcher, init_state,
-                            run_search_scan, run_search_sharded)
+    from repro.core import (Execution, SearchPlan, init_carry, init_matcher,
+                            init_state)
     from repro.launch.mesh import make_data_mesh
     from repro.sim import RepoSpec, generate
     from repro.sim.oracle import oracle_detect
@@ -121,15 +139,16 @@ PARITY_SCRIPT = textwrap.dedent(
                                init_matcher(max_results=2048),
                                jax.random.PRNGKey(0))
     budget = 1024
-    scan, _ = run_search_scan(fresh(), chunks, detector=det,
-                              result_limit=10**9, max_steps=budget,
-                              cohorts=8, method="wilson_hilferty")
+    scan = SearchPlan(result_limit=10**9, max_steps=budget, cohorts=8,
+                      method="wilson_hilferty").run(
+        fresh(), chunks, detector=det).carry
     assert int(scan.step) == budget
     for shards, sync_every in ((2, 1), (8, 1), (8, 4)):
-        out, trace = run_search_sharded(
-            fresh(), chunks, mesh=make_data_mesh(shards), detector=det,
+        res = SearchPlan(
             result_limit=10**9, max_steps=budget, cohorts=8,
-            sync_every=sync_every)
+            execution=Execution(shards=shards, sync_every=sync_every),
+        ).run(fresh(), chunks, detector=det, mesh=make_data_mesh(shards))
+        out, trace = res.carry, res.trace
         assert int(out.step) == budget, (shards, sync_every, int(out.step))
         assert int(out.step) == int(jnp.sum(out.sampler.n))
         occ = int(jnp.sum(out.matcher.times_seen > 0))
