@@ -34,6 +34,7 @@ from repro.core.exsample import (
     _host_search,
     _multi_search,
     _scan_search,
+    detect_needed,
     stack_carries,
 )
 from repro.core.matcher import MatcherState, match_and_update, merge_matcher
@@ -49,11 +50,15 @@ class SearchStats:
     * ``detector_invocations`` / ``cache_hits`` — detector economics: the
       Q-axis lowerings count unique, uncached frames actually detected;
       ``host`` and ``scan`` pay one invocation per sampled frame.
-    * ``detector_lanes`` — lanes the detector evaluated on the device,
-      padding and already-resolved frames included: rounds × Q × C on the
-      multi lowerings (a lifted single-query plan counts Q = 1), slot
-      batches × ``slots_per_batch`` × C on the async one, one per cohort
-      slot on ``host`` and ``scan``.
+    * ``detector_lanes`` — lanes the detector evaluated on the device.
+      The Q-axis lowerings detect only the unique, uncached, live frames,
+      compacted into chunks of ``detector_chunk(B)`` lanes (B = Q × C a
+      round, Q × C / shards a shard, ``slots_per_batch`` × C a slot
+      batch: about B/8, a multiple of 8, B itself below 8), so a round
+      counts its invocations rounded up to whole chunks, summed over
+      shards and over every processed slot batch; one per cohort slot on
+      ``host`` and ``scan``.  ``detector_invocations / detector_lanes``
+      is how full the chunks ran.
     * ``rounds`` — synchronized choose→detect rounds (Q-axis lowerings).
     * ``frames_sampled`` — Σ per-query steps (what sequential runs pay).
     * ``merge_high_water`` / ``merge_overflow`` — matcher ring pressure
@@ -375,7 +380,7 @@ class LoweredPlan:
         stats = SearchStats(
             detector_invocations=ms["detector_invocations"],
             cache_hits=ms["cache_hits"],
-            detector_lanes=ms["rounds"] * p.queries * p.cohorts,
+            detector_lanes=ms["detector_lanes"],
             rounds=ms["rounds"],
             frames_sampled=ms["frames_sampled"],
             merge_high_water=ms.get("merge_high_water", 0),
@@ -458,7 +463,8 @@ def _search_multi_sharded_device(
     ``[s·C/S, (s+1)·C/S)`` of EVERY query, whose Q·C/S frames dedup — and
     miss-check the HASH-SHARDED :class:`DetectionCache` (frame f homed on
     shard ``f % S``, DESIGN.md §14; lookups and inserts route over
-    ``all_to_all``) — into one detector batch.  With no ``cache`` and
+    ``all_to_all``) — into one detector batch of the needed lanes only
+    (``exsample.detect_needed``).  With no ``cache`` and
     ``empty = (layout, slots)`` each shard builds its own empty part of
     the cache (``slots`` of them) inside the program, so no device ever
     holds the whole logical cache.  Per-query liveness is evaluated at
@@ -515,7 +521,7 @@ def _search_multi_sharded_device(
 
         def one_round(base_n1, base_n, active, rstate):
             keys, delta_n1, delta_n, foreign, matcher, cache, lstep, lres, \
-                lcalls, lhits, lihits = rstate
+                lcalls, lhits, lihits, llanes = rstate
             ks = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
             key_next, k_choice, k_det = ks[:, 0], ks[:, 1], ks[:, 2]
             # per-query view: authoritative slice + own pending deltas (the
@@ -573,8 +579,6 @@ def _search_multi_sharded_device(
             det_keys_flat = det_keys.reshape((b,) + det_keys.shape[2:])
             first_idx = dedup_first_index(flat_frames, flat_live)
             is_rep = (first_idx == jnp.arange(b, dtype=jnp.int32)) & flat_live
-            with jax.named_scope("detect"):
-                fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
             if has_cache:
                 # Hash-sharded cache routing (DESIGN.md §14): frame f lives
                 # ONLY on shard f % S.  Requests are free — the replicated
@@ -600,6 +604,12 @@ def _search_multi_sharded_device(
                 )
                 bi = jnp.arange(b, dtype=jnp.int32)
                 hit = a_hit[home, bi]
+                need = is_rep & ~hit
+                # the detector waits on the hit flags only; the rows'
+                # all_to_all is free to overlap it
+                fresh, lanes = detect_needed(
+                    detector, det_keys_flat, flat_frames, need
+                )
                 with jax.named_scope("dedup_cache"):
                     cached = layout.unpack(a_rows[home, bi])
                     expand = lambda mk, x: mk.reshape(
@@ -610,7 +620,6 @@ def _search_multi_sharded_device(
                         cached, fresh,
                     )
                     fresh_rows = layout.pack(fresh)              # [b, W]
-                need = is_rep & ~hit
                 # route fresh detections to their home shards; flattening
                 # the received rows requester-major reproduces the exact
                 # u-major batch order the replica design's gathered insert
@@ -637,9 +646,12 @@ def _search_multi_sharded_device(
                 )
             else:
                 hit = jnp.zeros((b,), bool)
-                resolved = fresh
                 need = is_rep
+                resolved, lanes = detect_needed(
+                    detector, det_keys_flat, flat_frames, need
+                )
             dets_flat = jax.tree.map(lambda x: x[first_idx], resolved)
+            llanes = llanes + lanes
             lcalls = lcalls + jnp.sum(need).astype(jnp.int32)
             lhits = lhits + jnp.sum(is_rep & hit).astype(jnp.int32)
             if wtag is not None:
@@ -697,11 +709,11 @@ def _search_multi_sharded_device(
                 key_next, keys,
             )
             return (keys, delta_n1, delta_n, foreign, matcher, cache,
-                    lstep, lres, lcalls, lhits, lihits)
+                    lstep, lres, lcalls, lhits, lihits, llanes)
 
         def body(st):
             (keys, n1_l, n_l, matcher, snap, cache, step, results, buf, tn,
-             wcalls, whits, wihits, hw, ov, windows, _cont) = st
+             wcalls, whits, wihits, wlanes, hw, ov, windows, _cont) = st
             active = live_mask(step, results, n_l)               # [Q]
             rst = (
                 keys,
@@ -715,9 +727,10 @@ def _search_multi_sharded_device(
                 wcalls,
                 whits,
                 wihits,
+                wlanes,
             )
             keys, dn1, dn, _foreign, matcher, cache, lstep, lres, wcalls, \
-                whits, wihits = jax.lax.fori_loop(
+                whits, wihits, wlanes = jax.lax.fori_loop(
                     0, sync_every, lambda r, s: one_round(n1_l, n_l, active, s),
                     rst,
                 )
@@ -771,8 +784,8 @@ def _search_multi_sharded_device(
                 windows + 1 < wlimit
             )
             return (keys, n1_l, n_l, merged, merged, cache, step, results,
-                    buf, tn, wcalls, whits, wihits, hw, ov, windows + 1,
-                    cont)
+                    buf, tn, wcalls, whits, wihits, wlanes, hw, ov,
+                    windows + 1, cont)
 
         cont0 = jnp.any(live_mask(step0, results0, n_l)) & (wlimit > 0)
         init = (
@@ -780,12 +793,13 @@ def _search_multi_sharded_device(
             jnp.zeros((q_n, cap, 2), jnp.int32),
             jnp.zeros((q_n,), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32),
+            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.zeros((), bool),
             jnp.zeros((), jnp.int32), cont0,
         )
         (keys, n1_l, n_l, matcher, _snap, cache_f, step, results, buf, tn,
-         wcalls, whits, wihits, hw, ov, windows, _c) = jax.lax.while_loop(
+         wcalls, whits, wihits, wlanes, hw, ov, windows,
+         _c) = jax.lax.while_loop(
             lambda st: st[-1], body, init
         )
         # final per-query checkpoint only where the trace would otherwise
@@ -801,8 +815,9 @@ def _search_multi_sharded_device(
             calls = jax.lax.psum(wcalls, axis)
             hits = jax.lax.psum(whits, axis)
             ihits = jax.lax.psum(wihits, axis)
+            lanes = jax.lax.psum(wlanes, axis)
         outs = (n1_l, n_l, matcher, keys, step, results, buf, tn, calls,
-                hits, ihits, hw, ov, windows)
+                hits, ihits, hw, ov, windows, lanes)
         if cache_f is not None:
             # each shard returns only its 1/S of the hash-sharded logical
             # cache; concatenating over the sharded out-spec gives the
@@ -813,7 +828,7 @@ def _search_multi_sharded_device(
     sh1, sh2, rep = P(axis), P(None, axis), P()
     out_specs = (
         sh2, sh2, rep, rep, rep, rep, rep, rep, rep, rep, rep, rep, rep,
-        rep,
+        rep, rep,
     )
     cache_spec = rep if cache is None else sh1
     if has_cache:
@@ -963,8 +978,8 @@ def run_search_multi_sharded(
         empty=empty,
     )
     (n1_out, n_out, matcher, keys, step, results, buf, tn, calls, hits,
-     ihits, hw, ov, windows) = outs[:14]
-    final_cache = outs[14] if len(outs) > 14 else None
+     ihits, hw, ov, windows, lanes) = outs[:15]
+    final_cache = outs[15] if len(outs) > 15 else None
     out = ExSampleCarry(
         sampler=dataclasses.replace(
             carries.sampler,
@@ -987,6 +1002,7 @@ def run_search_multi_sharded(
         "detector_invocations": int(calls),
         "cache_hits": int(hits),
         "index_hits": int(ihits),
+        "detector_lanes": int(lanes),
         "rounds": int(windows) * sync_every,
         "frames_sampled": int(np.asarray(out.step).sum()),
         "merge_high_water": int(hw),

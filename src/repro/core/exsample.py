@@ -364,8 +364,67 @@ class RoundAux(NamedTuple):
 
     flat_frames: jax.Array   # i32[Q*C]
     need: jax.Array          # bool[Q*C]
-    fresh: "Detections"      # detector output, leading [Q*C]
+    fresh: "Detections"      # detector output on ``need`` lanes, 0 elsewhere
     rep_hit: jax.Array       # bool[Q*C] — representatives served by the cache
+    lanes: jax.Array         # i32[] — lanes the detector evaluated
+
+
+def detector_chunk(b: int) -> int:
+    """Lanes per detector trip for a batch of ``b`` lanes: about b/8, a
+    multiple of 8 sublanes, ``b`` itself below 8."""
+    return min(b, 8 * -(-b // 64))
+
+
+def detect_needed(
+    detector: DetectorFn,
+    keys: jax.Array,         # key[B] — each lane's own detector key
+    frames: jax.Array,       # i32[B]
+    need: jax.Array,         # bool[B] — lanes whose detections are consumed
+):
+    """Run ``detector`` on the ``need`` lanes only (DESIGN.md §9).
+
+    The needed lanes, in lane order, are compacted into chunks of
+    ``detector_chunk(B)`` lanes; a ``while_loop`` runs one vmapped detector
+    call a chunk, ``ceil(count / s)`` trips, and the outputs scatter back
+    to lane order.  Each needed lane keeps its own key, so its detections
+    are the full batch's bit for bit; every other lane reads 0.  Returns
+    ``(fresh, lanes)``: detections with a leading ``[B]`` and the lanes
+    the detector evaluated, ``trips · s``."""
+    b = frames.shape[0]
+    s = detector_chunk(b)
+    nb = -(-b // s) * s
+    with jax.named_scope("detect"):
+        pos = jnp.cumsum(need.astype(jnp.int32)) - 1   # rank among needed
+        trips = (pos[-1] + s) // s
+        src = jnp.zeros((nb,), jnp.int32).at[jnp.where(need, pos, nb)].set(
+            jnp.arange(b, dtype=jnp.int32), mode="drop"
+        )
+        det = jax.vmap(detector)
+        struct = jax.eval_shape(det, keys[:s], frames[:s])
+        buf = jax.tree.map(
+            lambda x: jnp.zeros((nb,) + x.shape[1:], x.dtype), struct
+        )
+
+        def trip(i, buf):
+            lanes = jax.lax.dynamic_slice_in_dim(src, i * s, s)
+            out = det(keys[lanes], frames[lanes])
+            return jax.tree.map(
+                lambda bx, ox: jax.lax.dynamic_update_slice_in_dim(
+                    bx, ox, i * s, 0
+                ),
+                buf, out,
+            )
+
+        buf = jax.lax.fori_loop(0, trips, trip, buf)
+        at = jnp.maximum(pos, 0)
+        fresh = jax.tree.map(
+            lambda x: jnp.where(
+                need.reshape((b,) + (1,) * (x.ndim - 1)), x[at],
+                jnp.zeros((), x.dtype),
+            ),
+            buf,
+        )
+    return fresh, trips * s
 
 
 def multi_round_choose(
@@ -421,8 +480,9 @@ def multi_round_process(
 ):
     """Process phase of one multi-query round: dedup the union of the Q·C
     chosen frames, resolve them through the shared ``DetectionCache``, run
-    one detector batch and fold each query's slots sequentially into its
-    own matcher/sampler.  ``query_ids`` carries the GLOBAL query index of
+    the detector on the unique, uncached, live frames only
+    (:func:`detect_needed`) and fold each query's slots sequentially into
+    its own matcher/sampler.  ``query_ids`` carries the GLOBAL query index of
     each carry row into ``select`` (the async scheduler processes gathered
     row subsets, whose positional index is not the query id; the resident
     loop passes ``arange(Q)`` implicitly).
@@ -447,22 +507,23 @@ def multi_round_process(
     # ---- cross-query dedup + cache: one detector batch for the union ----
     first_idx = dedup_first_index(flat_frames, flat_valid)
     is_rep = (first_idx == jnp.arange(b, dtype=jnp.int32)) & flat_valid
-    with jax.named_scope("detect"):
-        fresh = jax.vmap(detector)(det_keys_flat, flat_frames)
     with jax.named_scope("dedup_cache"):
         if cache is not None:
             hit, cached = cache_lookup(cache, flat_frames)
+        else:
+            hit = jnp.zeros((b,), bool)
+        need = is_rep & ~hit
+    fresh, lanes = detect_needed(detector, det_keys_flat, flat_frames, need)
+    with jax.named_scope("dedup_cache"):
+        if cache is not None:
             expand = lambda m, x: m.reshape(m.shape + (1,) * (x.ndim - 1))
             resolved = jax.tree.map(
                 lambda cv, fv: jnp.where(expand(hit, fv), cv, fv),
                 cached, fresh,
             )
-            need = is_rep & ~hit
             cache = cache_insert(cache, flat_frames, fresh, need)
         else:
-            hit = jnp.zeros((b,), bool)
             resolved = fresh
-            need = is_rep
         # scatter-back: every slot gathers its representative's detections,
         # so each query consumes detections of exactly the frame it sampled
         dets_flat = jax.tree.map(lambda x: x[first_idx], resolved)
@@ -510,7 +571,7 @@ def multi_round_process(
     )
     aux = RoundAux(
         flat_frames=flat_frames, need=need, fresh=fresh,
-        rep_hit=is_rep & hit,
+        rep_hit=is_rep & hit, lanes=lanes,
     )
     return mc, cache, fresh_calls, cache_hits, aux
 
@@ -548,12 +609,14 @@ def _multi_round(
     resident loop and the async workers share one round body.
 
     Returns ``(mc', cache', fresh_detections i32[], cache_hits i32[],
-    aux)`` — ``fresh_detections`` counts what a real deployment would
-    actually send through the detector this round (unique, uncached, live
-    frames); the simulator still evaluates the full padded batch for
-    static shapes.  ``aux`` is the round's :class:`RoundAux` (the resident
-    loop uses it to attribute cache hits to a warm repository-index
-    preload, DESIGN.md §13).
+    aux)`` — ``fresh_detections`` counts the frames sent through the
+    detector this round (unique, uncached, live).  Only those run: the
+    needed lanes are compacted into chunks of ``detector_chunk(Q·C)``
+    lanes (about Q·C/8, a multiple of 8), so the detector evaluates
+    ``ceil(fresh / s) · s`` lanes, ``aux.lanes``, and none in a round the
+    cache serves whole.  ``aux`` is the round's :class:`RoundAux` (the
+    resident loop also uses it to attribute cache hits to a warm
+    repository-index preload, DESIGN.md §13).
     """
     choice = multi_round_choose(mc, chunks, cohorts=cohorts, method=method)
     mc, cache, fresh_calls, cache_hits, aux = multi_round_process(
@@ -620,7 +683,7 @@ def _search_multi_device(
         return jnp.any(live_mask(state[0]))
 
     def body(state):
-        c, cache, buf, n, calls, hits, ihits, rounds = state
+        c, cache, buf, n, calls, hits, ihits, lanes, rounds = state
         active = live_mask(c)
         c2, cache, fresh, hit, aux = _multi_round(
             c, cache, chunks, active,
@@ -638,17 +701,18 @@ def _search_multi_device(
                 buf, idx, entry
             )
             n = n + crossed.astype(jnp.int32)
-        return c2, cache, buf, n, calls + fresh, hits + hit, ihits, rounds + 1
+        return (c2, cache, buf, n, calls + fresh, hits + hit, ihits,
+                lanes + aux.lanes, rounds + 1)
 
-    c, cache, buf, n, calls, hits, ihits, rounds = jax.lax.while_loop(
-        cond, body, (mc, cache, buf0, n0, z32, z32, z32, z32)
+    c, cache, buf, n, calls, hits, ihits, lanes, rounds = jax.lax.while_loop(
+        cond, body, (mc, cache, buf0, n0, z32, z32, z32, z32, z32)
     )
     final = jnp.stack([c.step, c.results], axis=-1)
     buf = jax.vmap(lambda bq, i, e: bq.at[i].set(e, mode="drop"))(
         buf, jnp.minimum(n, cap - 1), final
     )
     n = jnp.minimum(n + 1, cap)
-    return c, cache, buf, n, calls, hits, ihits, rounds
+    return c, cache, buf, n, calls, hits, ihits, lanes, rounds
 
 
 def _multi_search(
@@ -674,7 +738,8 @@ def _multi_search(
     sampler statistics, matcher memory, PRNG key, result counter and
     ``result_limits[q]``.  Per round the union of the Q cohorts' frames is
     deduplicated (plus an optional cross-round ``DetectionCache`` of
-    ``cache_frames`` slots) into one detector batch; each query then
+    ``cache_frames`` slots) into one compacted detector batch
+    (:func:`detect_needed`); each query then
     matches and updates against its own cohort's slots only.  Queries that
     hit their limit / the step budget / exhaustion mask out of
     choose/sample but stay shape-stable until every query finishes.
@@ -693,8 +758,10 @@ def _multi_search(
     Returns ``(carries', traces, stats)``: per-query recall traces (same
     semantics as ``run_search_scan``) and accounting —
     ``detector_invocations`` (unique, uncached frames actually detected),
-    ``cache_hits``, ``rounds``, ``frames_sampled`` (Σ per-query steps,
-    what Q sequential runs would have paid).
+    ``cache_hits``, ``detector_lanes`` (lanes the detector evaluated: the
+    invocations rounded up to whole chunks, :func:`detect_needed`),
+    ``rounds``, ``frames_sampled`` (Σ per-query steps, what Q sequential
+    runs would have paid).
 
     ``cache`` overrides internal cache construction (a repository-index
     preload, DESIGN.md §13) and ``warm_tag`` — the preloaded cache's tag
@@ -716,7 +783,8 @@ def _multi_search(
             )
             empty = (RowLayout.of(struct), cache_frames)
     with jax.profiler.TraceAnnotation("exsample.dispatch"):
-        out, cache, buf, n, calls, hits, ihits, rounds = _search_multi_device(
+        (out, cache, buf, n, calls, hits, ihits, lanes,
+         rounds) = _search_multi_device(
             carries,
             chunks,
             limits,
@@ -741,6 +809,7 @@ def _multi_search(
             "detector_invocations": int(calls),
             "cache_hits": int(hits),
             "index_hits": int(ihits),
+            "detector_lanes": int(lanes),
             "rounds": int(rounds),
             "frames_sampled": int(np.asarray(out.step).sum()),
             "final_cache": cache,

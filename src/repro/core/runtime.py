@@ -392,8 +392,9 @@ class AsyncMultiSearchDriver:
             # over slot lanes): how many lanes of each emitted SlotBatch
             # carried a live query vs sentinel padding
             "lanes_issued": 0, "lanes_padded": 0,
-            # lanes the detector evaluated: slots_per_batch × cohorts for
-            # every processed batch, duplicates and padding included
+            # lanes the detector evaluated in every processed batch,
+            # duplicate completions included: the batch's fresh calls
+            # rounded up to whole chunks (exsample.detect_needed)
             "detector_lanes": 0,
         }
         # (issued_s, taken_s, done_s, merged_s) of the latest merged rounds
@@ -603,7 +604,7 @@ class AsyncMultiSearchDriver:
         with jax.profiler.TraceAnnotation(
             "exsample.merge", batch=res.batch_id, lanes=self.slots_per_batch
         ), self._lock:
-            self.stats["detector_lanes"] += self.slots_per_batch * self.cohorts
+            self.stats["detector_lanes"] += int(res.aux.lanes)
             batch = self._inflight.pop(res.batch_id, None)
             if batch is None:
                 self.stats["duplicate_drops"] += 1
@@ -931,7 +932,8 @@ class ElasticShardedRunner:
         self.traces: list[list] = [[] for _ in range(q_n)]
         self.stats = {
             "detector_invocations": 0, "cache_hits": 0, "index_hits": 0,
-            "rounds": 0, "merges": 0, "merge_high_water": 0,
+            "detector_lanes": 0, "rounds": 0, "merges": 0,
+            "merge_high_water": 0,
             "merge_overflow": False, "frames_sampled": 0,
             "reshard_events": [], "final_cache": None,
         }
@@ -1046,6 +1048,7 @@ class ElasticShardedRunner:
         self.stats["detector_invocations"] += stats["detector_invocations"]
         self.stats["cache_hits"] += stats["cache_hits"]
         self.stats["index_hits"] += stats["index_hits"]
+        self.stats["detector_lanes"] += stats["detector_lanes"]
         self.stats["rounds"] += stats["rounds"]
         self.stats["merges"] += stats["merges"]
         self.stats["merge_high_water"] = max(

@@ -419,14 +419,24 @@ def test_stats_keys_exist_at_construction(world):
 def test_round_stamps_ordered_and_history_bounded(world, monkeypatch, pump):
     """Every merged round leaves its ``time.monotonic`` stamps in order —
     issued ≤ taken ≤ done ≤ merged — and the driver keeps only the latest
-    ``ROUND_HISTORY`` of them.  ``detector_lanes`` counts every lane of
-    every processed batch, padding included."""
+    ``ROUND_HISTORY`` of them.  ``detector_lanes`` counts the lanes the
+    detector evaluated in every processed batch: its needed lanes rounded
+    up to a whole chunk, which is the batch's 4 lanes here."""
     _, chunks, det = world
     monkeypatch.setattr(runtime, "ROUND_HISTORY", 3)
     driver = AsyncMultiSearchDriver(
         _fresh_multi(chunks, 3), chunks, det, cohorts=2, num_workers=2,
         result_limits=5, max_steps=120, slots_per_batch=2,
     )
+    needs = []
+    process = driver._process_batch
+
+    def spy(wid, batch):
+        res = process(wid, batch)
+        needs.append(int(np.asarray(res.aux.need).sum()))
+        return res
+
+    driver._process_batch = spy
     if pump == "synchronous":
         _pump_to_completion(driver)
     else:
@@ -441,7 +451,8 @@ def test_round_stamps_ordered_and_history_bounded(world, monkeypatch, pump):
     assert summary["round_p50_s"] > 0.0 and summary["slot_wait_p90_s"] >= 0.0
     assert runtime.round_summary([])["round_p50_s"] is None
     processed = driver.stats["merges"] + driver.stats["duplicate_drops"]
-    assert driver.stats["detector_lanes"] == processed * 2 * 2
+    assert len(needs) == processed
+    assert driver.stats["detector_lanes"] == sum(-(-n // 4) * 4 for n in needs)
 
 
 def _failing_detector(det):

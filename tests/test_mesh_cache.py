@@ -129,6 +129,57 @@ SCRIPT = textwrap.dedent(
         "shards": st_e["final_cache"].shards,
     }
 
+    # ---- the compacted detector batch against the full batch -----------
+    from repro.core import exsample, executor
+    from repro.sim.oracle import noisy_detect
+
+    needs = []
+    compacted = exsample.detect_needed
+
+    def spy(detector, keys_, frames, need):
+        jax.debug.callback(lambda n: needs.append(int(n)), jnp.sum(need))
+        return compacted(detector, keys_, frames, need)
+
+    def full_batch(detector, keys_, frames, need):
+        return jax.vmap(detector)(keys_, frames), jnp.int32(frames.shape[0])
+
+    noisy = lambda key, frame: noisy_detect(key, repo, frame, query_class=0)
+
+    def both(base, qs, cohorts_, cache=None):
+        ks = jnp.stack([jax.random.fold_in(jax.random.PRNGKey(1), q)
+                        for q in range(qs)])
+        runs = []
+        for fn in (spy, full_batch):
+            executor.detect_needed = fn
+            runs.append(run_search_multi_sharded(
+                init_carry_multi(init_state(chunks.length),
+                                 init_matcher(max_results=2048), ks),
+                chunks, mesh=mesh, detector=lambda k, f: base(k, f),
+                result_limits=limit, max_steps=budget, cohorts=cohorts_,
+                cache_frames=0 if cache is not None else cap, cache=cache))
+            jax.effects_barrier()
+        executor.detect_needed = compacted
+        (out, tr, st), (ref, tr_r, st_r) = runs
+        return {
+            "carry": same_carry(out, ref), "traces": tr == tr_r,
+            "cache": same_cache(st["final_cache"], st_r["final_cache"]),
+            "counts": [[st[k], st_r[k]] for k in (
+                "detector_invocations", "cache_hits", "rounds")],
+            "lanes": [st["detector_lanes"], st_r["detector_lanes"]],
+            "needs": list(needs), "batch": qs * cohorts_ // 4,
+        }
+
+    report["compaction"] = {}
+    for name, base, qs, cohorts_, cache in (
+        ("no_needed_lane", det, q_n, cohorts, fc),
+        ("narrow_batch", det, q_n, cohorts, None),
+        ("every_lane_needed", det, 1, 64, None),
+        ("padded_chunk", det, q_n, 16, None),
+        ("key_dependent", noisy, q_n, cohorts, None),
+    ):
+        del needs[:]
+        report["compaction"][name] = both(base, qs, cohorts_, cache)
+
     # ---- the program's collective and cache_init scopes ----------------
     c = fresh()
     padded = pad_chunks(c.sampler, 4)
@@ -211,3 +262,38 @@ def test_mesh_program_names_its_collectives_and_cache_build(report):
     s = report["scopes"]
     assert {"all_gather", "all_to_all", "psum"} <= set(s["collective"])
     assert "broadcast_in_dim" in s["cache_init"]
+
+
+# case: lanes a chunk for the shard's batch of Q · C / 4 lanes
+CHUNK = {
+    "no_needed_lane": 6, "narrow_batch": 6, "every_lane_needed": 8,
+    "padded_chunk": 8, "key_dependent": 6,
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK))
+def test_compacted_detect_on_four_shards_matches_full_batch(report, case):
+    """Each shard detects only its round's unique, uncached, live lanes, in
+    chunks, and every value a query consumes is the full batch's, bit for
+    bit: final carry, traces, the sharded final cache and the detector
+    counters.  The lanes the detector evaluated are the needed lanes
+    rounded up to whole chunks, summed over rounds and shards: none where
+    a warm cache serves every round, the shard's 6 lanes in one chunk
+    below 8, two full chunks of 8 where a single query needs all 16, a
+    padded second chunk where a shard of 12 needs more than 8."""
+    c = report["compaction"][case]
+    width = CHUNK[case]
+    assert c["carry"] and c["traces"] and c["cache"]
+    assert all(a == b for a, b in c["counts"]), c["counts"]
+    calls, hits, rounds = (a for a, _ in c["counts"])
+    needs = c["needs"]
+    assert len(needs) == 4 * rounds > 0 and sum(needs) == calls
+    lanes, full_lanes = c["lanes"]
+    assert lanes == sum(-(-n // width) * width for n in needs)
+    assert full_lanes == 4 * rounds * c["batch"]
+    if case == "no_needed_lane":
+        assert lanes == 0 and hits > 0
+    elif case == "every_lane_needed":
+        assert set(needs) == {16}
+    elif case == "padded_chunk":
+        assert any(width < n < 2 * width for n in needs)

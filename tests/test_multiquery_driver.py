@@ -34,7 +34,7 @@ from repro.serve.batcher import (
     init_detection_cache,
 )
 from repro.sim import RepoSpec, generate
-from repro.sim.oracle import oracle_detect
+from repro.sim.oracle import noisy_detect, oracle_detect
 
 
 @pytest.fixture(scope="module")
@@ -457,9 +457,10 @@ def test_lowered_round_programs_name_every_stage(world, program):
 
 
 @pytest.mark.parametrize("cache", [None, -1])
-def test_detector_lanes_count_every_evaluated_lane(world, cache):
-    """``detector_lanes`` is rounds × Q × C on the resident lowering: the
-    detector runs on every lane, so the fresh calls are a share of it."""
+def test_detector_lanes_count_every_evaluated_lane(world, cache, round_needs):
+    """``detector_lanes`` on the resident lowering is what the detector
+    evaluated: each round's needed lanes rounded up to whole chunks, 8
+    lanes a chunk for Q × C = 12, and no lane in a round that needs none."""
     _, chunks, det = world
     plan = SearchPlan(
         queries=3, result_limit=10, max_steps=400, cohorts=4,
@@ -467,9 +468,111 @@ def test_detector_lanes_count_every_evaluated_lane(world, cache):
     )
     res = plan.run(
         _fresh_multi(chunks, jax.vmap(_qkey)(jnp.arange(3))), chunks,
-        detector=det,
+        detector=lambda key, frame: det(key, frame),
     )
+    jax.effects_barrier()
     st = res.stats
-    assert res.kind == "multi" and st.rounds > 0
-    assert st.detector_lanes == st.rounds * 3 * 4
+    assert res.kind == "multi" and len(round_needs) == st.rounds > 0
+    assert sum(round_needs) == st.detector_invocations
+    assert st.detector_lanes == sum(-(-n // 8) * 8 for n in round_needs)
     assert 0 < st.detector_invocations <= st.detector_lanes
+
+
+# ---------------------------------------------------------------------------
+# The compacted detector batch: only the round's needed lanes are detected
+# ---------------------------------------------------------------------------
+
+
+def _full_batch(detector, keys, frames, need):
+    """The round's detector batch before compaction: every lane detected."""
+    return jax.vmap(detector)(keys, frames), jnp.int32(frames.shape[0])
+
+
+# case: (queries, cohorts, lanes a chunk, detector, cache preloaded by a
+# cold run of the same search)
+DETECT_EDGES = {
+    "no_needed_lane": (3, 4, 8, "oracle", True),
+    "every_lane_needed": (1, 12, 8, "oracle", False),
+    "exact_chunk_multiple": (1, 16, 8, "oracle", False),
+    "narrow_batch": (2, 3, 6, "oracle", False),
+    "key_dependent": (3, 4, 8, "noisy", False),
+}
+
+
+@pytest.mark.parametrize("case", list(DETECT_EDGES))
+def test_compacted_detect_matches_full_batch(
+    world, case, round_needs, monkeypatch
+):
+    """Detecting only the unique, uncached, live lanes, in chunks, leaves
+    every value a query consumes as the full batch left it: the final
+    carry, the traces, the final cache and the detector counters equal,
+    bit for bit, those of the same search with the detector on every lane,
+    and, for the deterministic detector, each query's own solo run.  The
+    detector evaluates each round's needed lanes rounded up to whole
+    chunks: none in a round the cache serves whole, one chunk of B lanes
+    below 8 lanes, a full and a padded chunk where 12 lanes are needed,
+    two full chunks where 16 are."""
+    from repro.core import exsample
+
+    repo, chunks, _ = world
+    q_n, cohorts, width, kind, warm = DETECT_EDGES[case]
+    if kind == "oracle":
+        base = lambda key, frame: oracle_detect(repo, frame, query_class=0)
+    else:
+        base = lambda key, frame: noisy_detect(
+            key, repo, frame, query_class=0
+        )
+    keys = jnp.stack([_qkey(q) for q in range(q_n)])
+    kw = dict(
+        result_limits=10, max_steps=400, cohorts=cohorts, trace_every=25,
+        cache_frames=chunks.total_frames,
+    )
+
+    def search(cache):
+        return exsample._multi_search(
+            _fresh_multi(chunks, keys), chunks,
+            detector=lambda key, frame: base(key, frame), cache=cache, **kw,
+        )
+
+    cache = search(None)[2]["final_cache"] if warm else None
+    jax.effects_barrier()
+    del round_needs[:]
+    out, traces, st = search(cache)
+    jax.effects_barrier()
+    needs = list(round_needs)
+    monkeypatch.setattr(exsample, "detect_needed", _full_batch)
+    ref, ref_traces, ref_st = search(cache)
+
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert traces == ref_traces
+    for a, b in zip(
+        jax.tree.leaves(st["final_cache"]), jax.tree.leaves(ref_st["final_cache"])
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for k in ("detector_invocations", "cache_hits", "rounds"):
+        assert st[k] == ref_st[k], k
+    assert len(needs) == st["rounds"] > 0
+    assert sum(needs) == st["detector_invocations"]
+    assert st["detector_lanes"] == sum(-(-n // width) * width for n in needs)
+    assert ref_st["detector_lanes"] == st["rounds"] * q_n * cohorts
+
+    if case == "no_needed_lane":
+        assert max(needs) == 0 and st["detector_lanes"] == 0
+        assert st["cache_hits"] > 0
+    elif case in ("every_lane_needed", "exact_chunk_multiple"):
+        # one query never samples a frame twice: an empty cache serves
+        # none of its lanes
+        assert needs[0] == q_n * cohorts
+        assert (needs[0] % width == 0) == (case == "exact_chunk_multiple")
+    if kind == "oracle":
+        solo_det = lambda key, frame: base(key, frame)
+        for q in range(q_n):
+            solo, solo_trace = run_search_scan(
+                _fresh(chunks, _qkey(q)), chunks, detector=solo_det,
+                result_limit=10, max_steps=400, cohorts=cohorts,
+                trace_every=25,
+            )
+            assert solo_trace == traces[q]
+            for a, b in zip(jax.tree.leaves(solo), jax.tree.leaves(out)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[q])

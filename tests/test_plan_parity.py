@@ -163,14 +163,15 @@ def _lift_pair(plan, chunks, det, mesh, key):
     return solo, stacked
 
 
-def test_plan_sharded_parity_1way(world):
+def test_plan_sharded_parity_1way(world, round_needs):
     _, chunks, det = world
     solo, stacked = _lift_pair(
         SearchPlan(
             result_limit=10, max_steps=400, cohorts=2,
             execution=Execution(strategy="sharded", sync_every=2),
         ),
-        chunks, det, make_data_mesh(1), jax.random.PRNGKey(0),
+        chunks, lambda key, frame: det(key, frame), make_data_mesh(1),
+        jax.random.PRNGKey(0),
     )
     # the lift hands back a single-query carry and a single-query trace
     assert jnp.ndim(solo.carry.step) == 0
@@ -182,7 +183,14 @@ def test_plan_sharded_parity_1way(world):
     assert solo.stats.merges == len(solo.trace)
     assert solo.stats.merge_high_water >= 1  # results were found and merged
     assert not solo.stats.merge_overflow
-    assert solo.stats.detector_lanes == solo.stats.rounds * 2
+    # the detector evaluates each round's needed lanes rounded up to a
+    # whole chunk, here the shard's whole batch of 2 lanes; both runs
+    # went through the one compiled program, so each round counted twice
+    jax.effects_barrier()
+    assert len(round_needs) == 2 * solo.stats.rounds
+    assert 2 * solo.stats.detector_lanes == sum(
+        -(-n // 2) * 2 for n in round_needs
+    )
 
 
 @pytest.mark.skipif(
