@@ -6,8 +6,9 @@ The control is the plain reference put in the program's place with its
 Thompson scores computed in bfloat16, the precision below the float32 the
 configurations state.  For each seed it takes as many of the cell's
 queries as a run compares (same repository, keys and classes as the
-cell's window would give them), replays each with the float32 reference
-and with the control, and applies the run's own check to the control:
+cell's window would give them, and the detector the configuration
+names), replays each with the float32 reference and with the control,
+and applies the run's own check to the control:
 ``queries_differing`` has to come out above its limit of 0.  The
 benchmark's runs never run this.
 """
@@ -27,9 +28,9 @@ def control_pairs(cfg: dict, mix: dict, seed: int):
     import jax
     import numpy as np
 
-    from bench import load, reference
+    from bench import detectors, load, reference
 
-    plan, m, s = mix["plan"], cfg["matcher"], cfg["sampler"]
+    plan, m, s, det = mix["plan"], cfg["matcher"], cfg["sampler"], cfg["detector"]
     k, ncls = mix["compare"], int(cfg["repository"]["num_classes"])
     if mix["mode"] == "service":
         classes, tseeds = load.tenant_set(k, ncls, mix["zipf_s"], seed)
@@ -53,24 +54,26 @@ def control_pairs(cfg: dict, mix: dict, seed: int):
             key=keys[i], query_class=int(classes[i]), cohorts=cohorts,
             result_limit=int(plan["result_limit"]), max_steps=int(plan["max_steps"]),
             method=method, all_classes=all_classes,
-            max_dets=cfg["detector"]["max_dets"], iou_thresh=m["iou_thresh"],
-            time_gate=m["time_gate"], alpha0=s["alpha0"], beta0=s["beta0"],
-            shards=shards, sync_every=sync_every)
+            max_dets=det["max_dets"], iou_thresh=m["iou_thresh"],
+            time_gate=m["time_gate"], feat_thresh=m.get("feat_thresh", -1.0),
+            alpha0=s["alpha0"], beta0=s["beta0"], shards=shards, sync_every=sync_every,
+            detector=detectors.kind(det), detector_cfg=det)
         for i in range(k)
     ]
 
 
-def control_check(arrays, queries) -> dict:
+def control_check(arrays, queries, root: str | None = None) -> dict:
     """The run's check with the bfloat16 control as the program."""
     from bench import reference
 
     differing = undecided = 0
+    det = reference.detector(arrays, queries[0], root) if queries else None
     for q in queries:
-        ref = reference.replay(arrays, q)
+        ref = reference.replay(arrays, q, det=det)
         if ref.ambiguous:
             undecided += 1
             continue
-        ctl = reference.replay(arrays, q, precision="bfloat16")
+        ctl = reference.replay(arrays, q, precision="bfloat16", det=det)
         prog = {"step": ctl.step, "results": ctl.results, "n": ctl.n, "n1": ctl.n1}
         differing += bool(reference.differences(prog, ref))
     return {"differing": differing, "undecided": undecided, "compared": len(queries)}

@@ -3,7 +3,9 @@
 Everything that belongs to one configuration, traffic mix or metric is
 found by name: the cell's entry in ``BENCHMARK.json`` names its
 configuration (``bench/configs/<config>.json``) and traffic
-(``bench/traffic/<traffic>.json``); each metric is computed by
+(``bench/traffic/<traffic>.json``); the configuration's ``detector``
+section names its detector (``bench/detectors/<kind>.py``, the contract
+in ``bench/detectors/__init__.py``); each metric is computed by
 ``bench/metrics/<metric>.py``, a module with ``value(ctx)`` that returns a
 number or None (nothing to read, and the metric is left out of the line).
 A run with ``--trace 0`` reports the cell's end-to-end metrics, one with
@@ -23,6 +25,7 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACE_SECONDS = 4.0   # the traced slice: the window's last seconds
+CHECK_TRIPS = 4       # detector chunks a module's ``check`` compares
 
 
 class NoChip(RuntimeError):
@@ -125,13 +128,14 @@ class Tracer:
             jax.profiler.stop_trace()
 
 
-def compare(arrays, pairs) -> dict:
+def compare(arrays, pairs, root: str = ROOT) -> dict:
     """Replay each sampled query and compare it with what the program gave."""
     from bench import reference
 
     rows, differing, undecided = [], 0, 0
+    det = reference.detector(arrays, pairs[0][0], root) if pairs else None
     for q, prog in pairs:
-        ref = reference.replay(arrays, q)
+        ref = reference.replay(arrays, q, det=det)
         if ref.ambiguous:
             undecided += 1
             rows.append(f"class {q.query_class}: undecided ({ref.ambiguous})")
@@ -143,6 +147,25 @@ def compare(arrays, pairs) -> dict:
                     f"{ref.results}" + (f"; DIFFER in {', '.join(diff)}" if diff else ""))
     return {"rows": rows, "differing": differing, "undecided": undecided,
             "compared": len(pairs)}
+
+
+def check_frames(arrays, pairs, width: int, seed: int):
+    """int32 ``[k, width]``, k at most ``CHECK_TRIPS``: frames the compared
+    queries sampled (each chunk's first n in random+ order), drawn from
+    the seed; where they do not fill the last row, repeated."""
+    import numpy as np
+
+    from bench import reference
+
+    seen = set()
+    for _, prog in pairs:
+        for c in np.flatnonzero(prog["n"]):
+            seen.update(reference.randomplus_frame(arrays, int(c), k)
+                        for k in range(int(prog["n"][c])))
+    k = min(CHECK_TRIPS, -(-len(seen) // width))
+    rng = np.random.default_rng([seed, 5])
+    take = rng.choice(sorted(seen), size=min(len(seen), k * width), replace=False)
+    return np.resize(np.asarray(take, np.int32), (k, width))
 
 
 def run(argv=None, *, t_start: float | None = None, allow_cpu: bool = False,
@@ -177,7 +200,7 @@ def run(argv=None, *, t_start: float | None = None, allow_cpu: bool = False,
         print(f"compile cache: {enable_compile_cache()}", file=err, flush=True)
     clk = clock.Clock()
     arrays = repository.generate(cfg["repository"])
-    driver = load.LOADS[mix["mode"]](cfg, mix, arrays, args.seed)
+    driver = load.LOADS[mix["mode"]](cfg, mix, arrays, args.seed, root=root)
     used = devs[: cell["chips"]]
     tdir = None
     try:
@@ -212,7 +235,13 @@ def run(argv=None, *, t_start: float | None = None, allow_cpu: bool = False,
     finally:
         driver.close()
     t_ref = time.monotonic()
-    verdict = compare(arrays, pairs)
+    verdict = compare(arrays, pairs, root)
+    frames_bad = None
+    if hasattr(driver.det_mod, "check"):
+        frames = check_frames(arrays, pairs, driver.detector_width, args.seed)
+        frames_bad = int(driver.det_mod.check(
+            cfg["detector"], driver.inst, arrays, driver.check_detector(), frames))
+        print(f"detector check: {frames.size} frames", file=err)
     ref_s = time.monotonic() - t_ref
     for row in verdict["rows"]:
         print(f"  {row}", file=err)
@@ -242,6 +271,8 @@ def run(argv=None, *, t_start: float | None = None, allow_cpu: bool = False,
         "queries_undecided": {"value": verdict["undecided"], "limit": limit_undecided},
         "queries_unfinished": {"value": counters["failed"], "limit": 0},
     }
+    if frames_bad is not None:
+        checks["frames_differing"] = {"value": frames_bad, "limit": 0}
     correct = all(c["value"] <= c["limit"] for c in checks.values()) and bool(pairs)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": peak}

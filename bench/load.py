@@ -9,7 +9,8 @@ A mix (``bench/traffic/<name>.json``) names its ``mode``:
   from the seed, a new order for each pass through the pool.
   ``detector`` is ``all_classes`` (one class-agnostic detector, each
   query picks its class through ``select``) or ``own_class`` (one
-  detector per class, single-query plans).
+  detector per class, single-query plans).  The detector itself is the
+  one the configuration names (``bench/detectors``).
 * ``service`` -- an open loop of tenants submitted to a ``SearchService``
   through ``launch/serve_search.handle_request`` at ``rate_per_s``.  The
   tenants are one fixed sequence: classes in Zipf(``zipf_s``) proportions,
@@ -31,8 +32,8 @@ import time
 
 import numpy as np
 
-from bench import reference
-from bench.data import oracle, repository
+from bench import detectors, reference
+from bench.data import repository
 
 
 POOL_KEY, WARM_KEY = 0, 1   # PRNG roots of the query pool and the warm-up
@@ -52,6 +53,33 @@ def _span(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+class _Detector:
+    """The configuration's detector module, as both loads hold it."""
+
+    def _init_detector(self, root: str) -> None:
+        self.det_cfg = self.cfg["detector"]
+        self.det_kind = detectors.kind(self.det_cfg)
+        self.det_mod = detectors.module(self.det_kind, root)
+
+    def _matcher(self):
+        from repro.core import init_matcher
+
+        m = self.cfg["matcher"]
+        return init_matcher(
+            max_results=self.mix["max_results"],
+            feat_dim=detectors.feat_dim(self.det_mod, self.det_cfg, self.cfg["repository"]),
+            iou_thresh=m["iou_thresh"], time_gate=m["time_gate"],
+            feat_thresh=m.get("feat_thresh", -1.0))
+
+    def _query(self, **kw) -> reference.Query:
+        m, s = self.cfg["matcher"], self.cfg["sampler"]
+        return reference.Query(
+            max_dets=self.det_cfg["max_dets"], iou_thresh=m["iou_thresh"],
+            time_gate=m["time_gate"], feat_thresh=m.get("feat_thresh", -1.0),
+            alpha0=s["alpha0"], beta0=s["beta0"], detector=self.det_kind,
+            detector_cfg=self.det_cfg, **kw)
+
+
 def _pick(rng, outcomes, k: int):
     """The longest query and ``k - 1`` others drawn by ``rng``."""
     if not outcomes:
@@ -62,13 +90,15 @@ def _pick(rng, outcomes, k: int):
     return [outcomes[longest]] + [outcomes[rest[int(i)]] for i in take]
 
 
-class BatchLoad:
+class BatchLoad(_Detector):
     """Closed loop of batch ``SearchPlan`` runs."""
 
-    def __init__(self, cfg: dict, mix: dict, arrays, seed: int):
+    def __init__(self, cfg: dict, mix: dict, arrays, seed: int,
+                 root: str = detectors.ROOT):
         import jax
 
         from repro.core import SearchPlan
+        from repro.core.exsample import detector_chunk
 
         self.cfg, self.mix, self.arrays, self.seed = cfg, mix, arrays, seed
         self.inst, self.chunks = repository.to_device(arrays)
@@ -78,7 +108,10 @@ class BatchLoad:
         self.multi = self.kind in ("multi", "multi_sharded", "async_multi")
         self.num_classes = int(cfg["repository"]["num_classes"])
         self.all_classes = mix["detector"] == "all_classes"
+        self._init_detector(root)
         self._dets, self._sels = {}, {}
+        lanes = self.q_n * self.plan.cohorts // self.plan.execution.shards
+        self.detector_width = detector_chunk(lanes)
         self._fold = jax.jit(jax.vmap(jax.random.fold_in, (None, 0)))
         self.pool = int(mix["pool_per_class"])
         self.window_key = jax.random.PRNGKey(POOL_KEY)
@@ -89,30 +122,30 @@ class BatchLoad:
     def _classes(self, first: int):
         return tuple((first + q) % self.num_classes for q in range(self.q_n))
 
-    def _detector(self, cls: int):
-        det_cfg = self.cfg["detector"]
+    def _detector(self, cls):
         c = None if self.all_classes else cls
         if c not in self._dets:
-            self._dets[c] = oracle.make_detector(
-                self.inst, query_class=c, max_dets=det_cfg["max_dets"])
+            self._dets[c] = self.det_mod.program(self.det_cfg, self.inst, query_class=c)
         return self._dets[c]
+
+    def check_detector(self):
+        """The class-agnostic program-side detector, for the module's check."""
+        return self._detector(None) if self.all_classes else self.det_mod.program(
+            self.det_cfg, self.inst, query_class=None)
 
     def _select(self, classes):
         if not (self.all_classes and self.multi):
             return None
         if classes not in self._sels:
-            self._sels[classes] = oracle.class_select(self.inst, classes)
+            self._sels[classes] = self.det_mod.program_select(self.det_cfg, self.inst, classes)
         return self._sels[classes]
 
     def _carry(self, keys):
-        from repro.core import (init_carry, init_carry_multi, init_matcher,
-                                init_state)
+        from repro.core import init_carry, init_carry_multi, init_state
 
-        s, m = self.cfg["sampler"], self.cfg["matcher"]
+        s = self.cfg["sampler"]
         state = init_state(self.chunks.length, alpha0=s["alpha0"], beta0=s["beta0"])
-        matcher = init_matcher(
-            max_results=self.mix["max_results"], feat_dim=self.cfg["repository"]["feat_dim"],
-            iou_thresh=m["iou_thresh"], time_gate=m["time_gate"])
+        matcher = self._matcher()
         if self.multi:
             return init_carry_multi(state, matcher, keys)
         return init_carry(state, matcher, keys[0])
@@ -180,6 +213,7 @@ class BatchLoad:
             "frames_sampled": sum(s.frames_sampled for s in st),
             "results": sum(sum(r["results"]) for r in self.records),
             "detector_invocations": sum(s.detector_invocations for s in st),
+            "detector_lanes": sum(s.detector_lanes for s in st),
             "cache_hits": sum(s.cache_hits for s in st),
         }
 
@@ -202,16 +236,13 @@ class BatchLoad:
         self.records = []
         keys = np.asarray(self._fold(self.window_key, np.asarray(
             [self._key_index(p["index"]) for p in picked], np.uint32)))
-        m, det = self.cfg["matcher"], self.cfg["detector"]
-        s, ex = self.cfg["sampler"], self.plan.execution
+        ex = self.plan.execution
         return [
-            (reference.Query(
+            (self._query(
                 key=keys[j], query_class=p["cls"], cohorts=self.plan.cohorts,
                 result_limit=int(self.plan.result_limit),
                 max_steps=self.plan.max_steps, method=self.method,
-                all_classes=self.all_classes, max_dets=det["max_dets"],
-                iou_thresh=m["iou_thresh"], time_gate=m["time_gate"],
-                alpha0=s["alpha0"], beta0=s["beta0"],
+                all_classes=self.all_classes,
                 shards=ex.shards, sync_every=ex.sync_every), p)
             for j, p in enumerate(picked)
         ]
@@ -255,37 +286,43 @@ def arrival_offsets(n: int, seconds: float, seed: int) -> np.ndarray:
     return np.cumsum(np.roll(gaps, -_rotation(n, seed)))
 
 
-class ServiceLoad:
+class ServiceLoad(_Detector):
     """Open loop of tenants through the search service's request handler."""
 
-    def __init__(self, cfg: dict, mix: dict, arrays, seed: int):
+    def __init__(self, cfg: dict, mix: dict, arrays, seed: int,
+                 root: str = detectors.ROOT):
+        from repro.core.exsample import detector_chunk
+
         self.cfg, self.mix, self.arrays, self.seed = cfg, mix, arrays, seed
         self.inst, self.chunks = repository.to_device(arrays)
         self.num_classes = int(cfg["repository"]["num_classes"])
+        self._init_detector(root)
+        sv = mix["service"]
+        self.detector_width = detector_chunk(sv["slots_per_batch"] * sv["cohorts"])
         self.tenants = []
-        self.service = None
+        self.service = self.det = None
         self.window_end = 0.0
+        self._driver0 = {}
 
     def setup(self) -> None:
         import jax
         import jax.numpy as jnp
 
-        from repro.core import init_carry_multi, init_matcher, init_state
+        from repro.core import init_carry_multi, init_state
         from repro.serve.service import SearchService
         from repro.sim.costmodel import CostRates
 
-        sv, s, m = self.mix["service"], self.cfg["sampler"], self.cfg["matcher"]
-        det = oracle.make_detector(self.inst, max_dets=self.cfg["detector"]["max_dets"])
-        select = oracle.class_select(self.inst, list(range(self.num_classes)))
+        sv, s = self.mix["service"], self.cfg["sampler"]
+        self.det = self.det_mod.program(self.det_cfg, self.inst, query_class=None)
+        select = self.det_mod.program_select(
+            self.det_cfg, self.inst, list(range(self.num_classes)))
         proto = init_carry_multi(
             init_state(self.chunks.length, alpha0=s["alpha0"], beta0=s["beta0"]),
-            init_matcher(max_results=self.mix["max_results"],
-                         feat_dim=self.cfg["repository"]["feat_dim"],
-                         iou_thresh=m["iou_thresh"], time_gate=m["time_gate"]),
+            self._matcher(),
             jnp.stack([jax.random.PRNGKey(0)]),
         )
         self.service = SearchService(
-            proto, self.chunks, det, select=select,
+            proto, self.chunks, self.det, select=select,
             budget_s=float("inf"), rates=CostRates(),
             cohorts=sv["cohorts"], num_workers=sv["workers"],
             max_steps=sv["max_steps"],
@@ -326,6 +363,7 @@ class ServiceLoad:
         n = int(round(rate * seconds))
         offs = arrival_offsets(n, seconds, self.seed)
         classes, tseeds = tenant_set(n, self.num_classes, self.mix["zipf_s"], self.seed)
+        self._driver0 = self._detector_stats()
         t0 = time.monotonic()
         for i in range(n):
             due = t0 + float(offs[i])
@@ -353,6 +391,13 @@ class ServiceLoad:
         """Wait, after the window, for the tenants still running."""
         self._wait([t["name"] for t in self.tenants], deadline_s)
 
+    def _detector_stats(self) -> dict:
+        st = self.service.driver.stats
+        return {k: int(st[k]) for k in ("detector_invocations", "detector_lanes")}
+
+    def check_detector(self):
+        return self.det
+
     def counters(self) -> dict:
         lat, wait, lag, failed = [], [], [], 0
         for t in self.tenants:
@@ -364,10 +409,12 @@ class ServiceLoad:
                 continue
             lat.append(row.finished_s - t["due"])
             wait.append(row.admitted_s - t["due"])
+        since = {k: v - self._driver0[k] for k, v in self._detector_stats().items()}
         return {
             "window_s": self.run_s, "attempted": len(self.tenants),
             "failed": failed, "queries_done": len(lat),
             "latency_s": lat, "admission_wait_s": wait, "arrival_lag_s": lag,
+            **since,
         }
 
     def sample(self, k: int):
@@ -384,16 +431,13 @@ class ServiceLoad:
         picked = _pick(rng, outcomes, k)
         import jax
 
-        plan, m, s = self.mix["plan"], self.cfg["matcher"], self.cfg["sampler"]
+        plan = self.mix["plan"]
         return [
-            (reference.Query(
+            (self._query(
                 key=np.asarray(jax.random.PRNGKey(p["tseed"])), query_class=p["cls"],
                 cohorts=self.mix["service"]["cohorts"],
                 result_limit=int(plan["result_limit"]), max_steps=int(plan["max_steps"]),
-                method="exact", all_classes=True,
-                max_dets=self.cfg["detector"]["max_dets"],
-                iou_thresh=m["iou_thresh"], time_gate=m["time_gate"],
-                alpha0=s["alpha0"], beta0=s["beta0"]), p)
+                method="exact", all_classes=True), p)
             for p in picked
         ]
 

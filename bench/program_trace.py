@@ -348,7 +348,7 @@ def run(argv=None, *, allow_cpu: bool = False, root: str | None = None) -> int:
         harness.enable_compile_cache()
     tdir = args.keep or tempfile.mkdtemp(prefix="program-trace-")
     driver = load.LOADS[mix["mode"]](cfg, mix, repository.generate(cfg["repository"]),
-                                     args.seed)
+                                     args.seed, root=root)
     try:
         driver.setup()
         tracer = harness.Tracer(tdir, args.seconds, harness.TRACE_SECONDS)
@@ -366,10 +366,6 @@ def run(argv=None, *, allow_cpu: bool = False, root: str | None = None) -> int:
         if mix["mode"] == "service":
             driver.drain(deadline_s=mix["drain_s"])
         counters = driver.counters()
-        records = getattr(driver, "records", [])
-        lanes = [getattr(r["stats"], "detector_lanes", 0) for r in records]
-        if records and all(lanes):
-            counters["detector_lanes"] = sum(lanes)
         red = reduce(extract(trace.xplane_file(tdir)))
         # the cell's end-to-end numbers in this traced run, read as
         # bench/run.py reads them, for what tracing costs

@@ -3,16 +3,19 @@
 The paper's Algorithm 1 as the program defines it, written out again in
 numpy, one frame at a time, with no batching, dedup, cache, kernel or
 sharding: per round, Thompson-sample ``cohorts`` chunks from round-start
-statistics, take each chunk's next frame in random+ order, detect it from
-the ground truth, match the detections against the query's results
-(same video, within ``time_gate`` frames, IoU at least ``iou_thresh``,
-best IoU first), and update N1 and n per chunk (paper §3.4 cross-chunk
-rule included).  Only the random numbers come from JAX (``jax.random``
+statistics, take each chunk's next frame in random+ order, detect it with
+the reference side of the configuration's detector (``bench/detectors``),
+match the detections against the query's results (same video, within
+``time_gate`` frames, IoU at least ``iou_thresh`` or, where
+``feat_thresh`` is above -1, feature cosine at least that; best score
+first), and update N1 and n per chunk (paper §3.4 cross-chunk rule
+included).  Only the random numbers come from JAX (``jax.random``
 on the same keys), because the keys are part of the query.
 
 Two float32 computations of the same score may order two chunks
 differently when they lie within rounding of each other, and so may an
-IoU within rounding of the threshold or of another entry's.  The replay
+IoU or a cosine within rounding of its threshold or of another entry's,
+or a detector's decision that its reference side reports.  The replay
 marks a query *ambiguous* at the first such step and stops: an ambiguous
 query is left out of the comparison rather than guessed at.  ``TIE`` is
 that rounding margin.
@@ -59,6 +62,9 @@ class Query:
     beta0: float = 1.0
     shards: int = 1          # chips the statistics are sharded over (section 8)
     sync_every: int = 1      # rounds between two merges of the shards
+    feat_thresh: float = -1.0  # feature cosine that matches; -1 is off
+    detector: str = "oracle"   # bench/detectors/<detector>.py
+    detector_cfg: dict = dataclasses.field(default_factory=dict, hash=False)
 
 
 @dataclasses.dataclass
@@ -149,32 +155,32 @@ def _iou(a, b):
     return inter / np.maximum(union, np.float32(1e-9))
 
 
+def _cosine(a, b):
+    """float32 cosine similarity [D, R], the program matcher's operations."""
+    eps = np.float32(1e-9)
+    an = a / np.maximum(np.linalg.norm(a, axis=-1, keepdims=True), eps)
+    bn = b / np.maximum(np.linalg.norm(b, axis=-1, keepdims=True), eps)
+    return an @ bn.T
+
+
 class _Results:
-    """The query's result memory: first sightings with their counts."""
+    """The query's result memory: first sightings with their counts
+    (features only where the matcher uses them)."""
 
     def __init__(self):
         self.boxes = np.zeros((0, 4), np.float32)
+        self.feats = np.zeros((0, 0), np.float32)
         self.video = np.zeros(0, np.int64)
         self.frame = np.zeros(0, np.int64)
         self.chunk = np.zeros(0, np.int64)
         self.seen = np.zeros(0, np.int64)
 
 
-def _detect(a, q: Query, f: int):
-    vis = (a.inst_start <= f) & (f < a.inst_end)
-    if not q.all_classes:
-        vis &= a.inst_class == q.query_class
-    ids = np.flatnonzero(vis)[: q.max_dets]
-    if q.all_classes:
-        ids = ids[a.inst_class[ids] == q.query_class]
-    t = (f - a.inst_start[ids]).astype(np.float32)[:, None]
-    return a.inst_box[ids] + t * a.inst_drift[ids]
-
-
-def _match(q: Query, mem: _Results, boxes, video: int, f: int, c: int):
+def _match(q: Query, mem: _Results, boxes, feats, video: int, f: int, c: int):
     """Match one frame; returns (d0, d1 local, cross homes, why ambiguous)."""
     r = mem.boxes.shape[0]
     best = np.full(boxes.shape[0], -1)
+    by_feat = q.feat_thresh > -1.0
     if r and boxes.shape[0]:
         iou = _iou(boxes, mem.boxes)
         near = (mem.video == video) & (np.abs(mem.frame - f) <= q.time_gate)
@@ -182,15 +188,23 @@ def _match(q: Query, mem: _Results, boxes, video: int, f: int, c: int):
         if np.any(near[None, :] & (np.abs(iou - thr) <= TIE * thr)):
             return 0, 0, [], f"IoU within rounding of the threshold at frame {f}"
         ok = near[None, :] & (iou >= thr)
+        score = iou
+        if by_feat:
+            sim = _cosine(feats, mem.feats)
+            fthr = np.float32(q.feat_thresh)
+            if np.any(near[None, :] & (np.abs(sim - fthr) <= TIE)):
+                return 0, 0, [], f"cosine within rounding of the threshold at frame {f}"
+            ok |= near[None, :] & (sim >= fthr)
+            score = np.maximum(iou, sim)
         for d in range(boxes.shape[0]):
             cand = np.flatnonzero(ok[d])
             if cand.size == 0:
                 continue
-            vals = iou[d, cand]
+            vals = score[d, cand]
             top = cand[np.argmax(vals)]
             if cand.size > 1:
                 two = np.sort(vals)[-2:]
-                if two[1] - two[0] <= TIE * two[1]:
+                if two[1] - two[0] <= TIE * abs(two[1]):
                     return 0, 0, [], f"two results within rounding at frame {f}"
             best[d] = top
     matched = best >= 0
@@ -202,6 +216,8 @@ def _match(q: Query, mem: _Results, boxes, video: int, f: int, c: int):
     k = new.size
     if k:
         mem.boxes = np.concatenate([mem.boxes, boxes[new]])
+        nf = feats[new] if by_feat else np.zeros((k, 0), np.float32)
+        mem.feats = np.concatenate([mem.feats.reshape(r, nf.shape[1]), nf])
         mem.video = np.concatenate([mem.video, np.full(k, video)])
         mem.frame = np.concatenate([mem.frame, np.full(k, f)])
         mem.chunk = np.concatenate([mem.chunk, np.full(k, c)])
@@ -210,10 +226,25 @@ def _match(q: Query, mem: _Results, boxes, video: int, f: int, c: int):
     return k, d1_local, list(mem.chunk[np.flatnonzero(crossed)]), ""
 
 
-def replay(a, q: Query, precision: str = "float32") -> Outcome:
-    """Run query ``q`` over repository ``a`` (``data.repository.Arrays``)."""
+def detector(a, q: Query, root: str | None = None):
+    """The reference side of ``q``'s detector over repository ``a``."""
+    from bench import detectors
+
+    mod = detectors.module(q.detector, root or detectors.ROOT)
+    return mod.reference(q.detector_cfg, a)
+
+
+def _prefetch(det, frames) -> None:
+    if frames and hasattr(det, "prefetch"):
+        det.prefetch(frames)
+
+
+def replay(a, q: Query, precision: str = "float32", det=None) -> Outcome:
+    """Run query ``q`` over repository ``a`` (``data.repository.Arrays``),
+    detecting with ``det`` (``detector(a, q)`` where not given)."""
+    det = detector(a, q) if det is None else det
     if q.shards > 1:
-        return _replay_mesh(a, q, precision)
+        return _replay_mesh(a, q, precision, det)
     m = a.num_chunks
     frames = a.chunk_length.astype(np.int64)
     n1 = np.zeros(m, np.int64)
@@ -242,12 +273,18 @@ def replay(a, q: Query, precision: str = "float32") -> Outcome:
             close = top2[:, 1] - top2[:, 0] <= TIE * np.abs(top2[:, 1])
             if np.any(close):
                 return out(f"Thompson draws within rounding in round at step {step}")
-        for c in choice:
+        picks = []
+        for g, c in enumerate(choice):
             c = int(c)
-            f = randomplus_frame(a, c, int(n[c]))
-            boxes = _detect(a, q, f)
+            occ = int(np.sum(choice[:g] == c))
+            picks.append((c, randomplus_frame(a, c, int(n[c]) + occ)))
+        _prefetch(det, [f for _, f in picks])
+        for c, f in picks:
+            boxes, feats, why = det.detect(f, q)
+            if why:
+                return out(why)
             d0, d1_local, homes, why = _match(
-                q, mem, boxes, int(a.chunk_video[c]), f, c
+                q, mem, boxes, feats, int(a.chunk_video[c]), f, c
             )
             if why:
                 return out(why)
@@ -282,6 +319,9 @@ def _merge(snap: _Results, mems) -> _Results:
     for name in ("boxes", "video", "frame", "chunk"):
         setattr(out, name, np.concatenate(
             [getattr(snap, name)] + [getattr(mem, name)[r0:] for mem in mems]))
+    f = max(mem.feats.shape[1] for mem in mems)
+    out.feats = np.concatenate([x.reshape(x.shape[0], f) for x in
+                                [snap.feats] + [mem.feats[r0:] for mem in mems]])
     return out
 
 
@@ -301,7 +341,7 @@ def _rival(q: Query, n1, n, draws, s, choice, live) -> bool:
     return bool(np.any(close[live] & ~same[live]))
 
 
-def _replay_mesh(a, q: Query, precision: str) -> Outcome:
+def _replay_mesh(a, q: Query, precision: str, det) -> Outcome:
     """``replay`` under the section 8 merge schedule on ``q.shards`` shards.
 
     The chunks are padded with exhausted ones to a multiple of the shard
@@ -361,15 +401,19 @@ def _replay_mesh(a, q: Query, precision: str) -> Outcome:
             for g, c in enumerate(choice):
                 if live[g] and g // per != owner[c]:
                     foreign[c] += 1
+            picked = {g: randomplus_frame(a, int(choice[g]), int(rank[g]))
+                      for g in range(q.cohorts) if live[g]}
+            _prefetch(det, list(picked.values()))
             for sh in range(shards):
                 for g in range(sh * per, (sh + 1) * per):
                     if not live[g]:
                         continue
-                    c = int(choice[g])
-                    f = randomplus_frame(a, c, int(rank[g]))
-                    boxes = _detect(a, q, f)
+                    c, f = int(choice[g]), picked[g]
+                    boxes, feats, why = det.detect(f, q)
+                    if why:
+                        return out(why)
                     d0, d1_local, homes, why = _match(
-                        q, mems[sh], boxes, int(a.chunk_video[c]), f, c
+                        q, mems[sh], boxes, feats, int(a.chunk_video[c]), f, c
                     )
                     if why:
                         return out(why)

@@ -22,9 +22,11 @@ FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A benchmark checkout of its own holding one extra cell, ``tiny.q4``,
-    made only of new files (a configuration, a traffic mix, a per-layer
-    metric) and new entries in its BENCHMARK.json."""
+    """A benchmark checkout of its own holding two extra cells made only
+    of new files and new entries in its BENCHMARK.json: ``tiny.q4`` (a
+    configuration, a traffic mix, a per-layer metric) and ``tiny_nn.q4``
+    (the same traffic on a configuration that names a detector module of
+    its own, ``tiny_detector``)."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -34,16 +36,22 @@ def tiny_root(tmp_path):
     shutil.copy(os.path.join(FIXTURES, "tiny_traffic.json"), root / "bench/traffic/tiny_q4.json")
     shutil.copy(os.path.join(FIXTURES, "tiny_metric.py"),
                 root / "bench/metrics/results_per_query.py")
-    spec["configs"].append({"name": "tiny", "source": "https://arxiv.org/abs/2005.09141",
-                            "file": "bench/configs/tiny.json", "reduced": ["videos"],
-                            "why": "test fixture"})
-    spec["workloads"].append({"name": "tiny.q4", "config": "tiny", "traffic": "tiny_q4",
-                              "chips": 1, "why": "test fixture"})
-    spec["end_to_end"][0]["workloads"].append("tiny.q4")
+    shutil.copy(os.path.join(FIXTURES, "tiny_nn_config.json"),
+                root / "bench/configs/tiny_nn.json")
+    shutil.copy(os.path.join(FIXTURES, "tiny_detector.py"),
+                root / "bench/detectors/tiny_detector.py")
+    cells = ["tiny.q4", "tiny_nn.q4"]
+    for name in ("tiny", "tiny_nn"):
+        spec["configs"].append({"name": name, "source": "https://arxiv.org/abs/2005.09141",
+                                "file": f"bench/configs/{name}.json", "reduced": ["videos"],
+                                "why": "test fixture"})
+        spec["workloads"].append({"name": f"{name}.q4", "config": name,
+                                  "traffic": "tiny_q4", "chips": 1, "why": "test fixture"})
+    spec["end_to_end"][0]["workloads"].extend(cells)
     spec["per_layer"].append({"name": "results_per_query", "unit": "results",
                               "better": "higher", "source": "program_counter",
                               "layer": "matcher", "moves": "queries_per_s",
-                              "workloads": ["tiny.q4"]})
+                              "workloads": cells})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return str(root)
 

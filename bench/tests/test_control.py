@@ -8,22 +8,25 @@ import jax
 import pytest
 from conftest import run_cell
 
-from bench import control
+from bench import control, detectors
 from bench.data import repository
 
 
-def _tiny(tiny_root):
-    cfg = json.load(open(os.path.join(tiny_root, "bench/configs/tiny.json")))
+def _tiny(tiny_root, config="tiny"):
+    cfg = json.load(open(os.path.join(tiny_root, f"bench/configs/{config}.json")))
     mix = json.load(open(os.path.join(tiny_root, "bench/traffic/tiny_q4.json")))
     return cfg, mix
 
 
-def test_bfloat16_control_is_not_correct(tiny_root):
-    cfg, mix = _tiny(tiny_root)
+@pytest.mark.parametrize("config", ["tiny", "tiny_nn"])
+def test_bfloat16_control_is_not_correct(tiny_root, config):
+    cfg, mix = _tiny(tiny_root, config)
     mix = dict(mix, compare=8)
     for seed in (1, 2, 3):
         arrays = repository.generate(cfg["repository"])
-        r = control.control_check(arrays, control.control_pairs(cfg, mix, seed))
+        qs = control.control_pairs(cfg, mix, seed)
+        assert all(q.detector == detectors.kind(cfg["detector"]) for q in qs)
+        r = control.control_check(arrays, qs, root=tiny_root)
         decided = r["compared"] - r["undecided"]
         assert decided >= 4 and r["differing"] > 0, r   # limit 0: not correct
 
@@ -93,5 +96,6 @@ def test_control_pairs_follow_each_cell(name):
     _, cfg, mix = harness.lookup(spec, name)
     qs = control.control_pairs(cfg, mix, 5)
     assert len(qs) == mix["compare"]
+    assert all(q.detector == "oracle" and q.detector_cfg is cfg["detector"] for q in qs)
     if mix["mode"] == "batch":
         assert [q.query_class for q in qs[:8]] == list(range(8))

@@ -180,8 +180,9 @@ def test_runs_a_cell_and_reads_the_program(tiny_root, capsys):
 
 
 def test_runs_a_service_cell_and_reads_its_rounds(tiny_root, capsys):
-    # a tiny open loop of tenants on the CPU: the pump's spans and the
-    # rounds' stamps are read, nothing of a device that is not there
+    # a tiny open loop of tenants on the CPU: the pump's spans, the
+    # rounds' stamps and the service's lane counter are read, nothing of
+    # a device that is not there
     import json
 
     mix = {"mode": "service", "rate_per_s": 3.0, "zipf_s": 1.0,
@@ -208,4 +209,4 @@ def test_runs_a_service_cell_and_reads_its_rounds(tiny_root, capsys):
     assert rc == 0 and m["round_p50_s"] > 0 and m["slot_wait_p90_s"] >= 0
     assert out["end_to_end"]["query_p50_s"] > 0
     assert 0 < m["pump_busy_share"] <= 100
-    assert "detector_lane_use" not in m and not out["stages_s"]
+    assert 0 < m["detector_lane_use"] <= 100 and not out["stages_s"]
